@@ -455,3 +455,4 @@ scale-proof:
 
 clean:
 	$(MAKE) -C native/metadata_store clean
+	rm -rf .jax_cache

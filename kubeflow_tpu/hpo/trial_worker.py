@@ -58,10 +58,6 @@ def main() -> int:
     phases: dict = {}
     _phase(phases, "proc_start")
     import jax
-
-    if os.environ.get("KFT_FORCE_PLATFORM"):
-        jax.config.update("jax_platforms", os.environ["KFT_FORCE_PLATFORM"])
-
     import jax.numpy as jnp
 
     from kubeflow_tpu.parallel.depot import (

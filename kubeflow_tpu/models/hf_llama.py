@@ -42,6 +42,9 @@ except ImportError:  # pragma: no cover - env always has it; keep import soft
     safe_open = None
     _st_save = None
 
+# save_pretrained's default cap on one weights file (HF: max_shard_size)
+MAX_SHARD_BYTES = 1 << 30
+
 
 # ---------------------------------------------------------------------------
 # config.json <-> LlamaConfig
@@ -292,9 +295,13 @@ def _placer(cfg, mesh, rules, dtype):
     return put
 
 
-def save_pretrained(model_dir: str, cfg: llama.LlamaConfig, params) -> None:
-    """Write the pytree back out in HF layout (config.json +
-    model.safetensors) — the export path, and the fixture-maker for tests."""
+def save_pretrained(model_dir: str, cfg: llama.LlamaConfig, params, *,
+                    max_shard_bytes: int = MAX_SHARD_BYTES) -> None:
+    """Write the pytree back out in HF layout — the export path, and the
+    fixture-maker for tests. What fits ``max_shard_bytes`` is one
+    model.safetensors; more is split the HF way, whole tensors into
+    model-0000x-of-0000y.safetensors + model.safetensors.index.json (a
+    tensor larger than the cap gets a file of its own)."""
     if _st_save is None:  # pragma: no cover
         raise RuntimeError("safetensors is required to save HF checkpoints")
     os.makedirs(model_dir, exist_ok=True)
@@ -328,7 +335,27 @@ def save_pretrained(model_dir: str, cfg: llama.LlamaConfig, params) -> None:
             flat[p + "mlp.up_proj.weight"] = lp["w_up"][i].T
             flat[p + "mlp.down_proj.weight"] = lp["w_down"][i].T
     flat = {k: jnp.asarray(v) for k, v in flat.items()}
-    _st_save(flat, os.path.join(model_dir, "model.safetensors"))
+    shards: list[dict[str, jax.Array]] = [{}]
+    room = max_shard_bytes
+    for name, x in flat.items():
+        if shards[-1] and x.nbytes > room:
+            shards.append({})
+            room = max_shard_bytes
+        shards[-1][name] = x
+        room -= x.nbytes
+    if len(shards) == 1:
+        _st_save(flat, os.path.join(model_dir, "model.safetensors"))
+        return
+    weight_map: dict[str, str] = {}
+    for i, shard in enumerate(shards, 1):
+        fname = f"model-{i:05d}-of-{len(shards):05d}.safetensors"
+        _st_save(shard, os.path.join(model_dir, fname))
+        weight_map.update(dict.fromkeys(shard, fname))
+    with open(os.path.join(model_dir,
+                           "model.safetensors.index.json"), "w") as f:
+        json.dump({"metadata": {"total_size": sum(
+            x.nbytes for x in flat.values())},
+            "weight_map": weight_map}, f, indent=1)
 
 
 def load_pretrained(model_dir: str, *, dtype=jnp.bfloat16, mesh=None,

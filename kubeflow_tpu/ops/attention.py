@@ -109,27 +109,13 @@ def _attention_jit(
 
 
 def _ambient_mesh():
-    """The mesh in context at trace time: `with mesh:` populates the
-    thread-resource env (what with_sharding_constraint resolves against);
-    newer `jax.sharding.use_mesh` populates the abstract mesh instead —
-    accept either. Version-tolerant: ``jax.sharding.get_abstract_mesh``
-    only exists on newer jax (0.5+); older eras (0.4.x) have no abstract
-    mesh at all, so the thread-resource fallback below is the whole
-    story there."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        abstract = get_abstract()
-        if abstract is not None and abstract.axis_names:
-            return abstract
-    try:
-        from jax._src.mesh import thread_resources
+    """The mesh in context at trace time: ``with mesh:`` (how Trainer and
+    the AOT proofs enter one) populates the thread-resource env, which is
+    also what with_sharding_constraint resolves against."""
+    from jax._src.mesh import thread_resources
 
-        physical = thread_resources.env.physical_mesh
-        if physical.axis_names:
-            return physical
-    except Exception:
-        pass
-    return None
+    physical = thread_resources.env.physical_mesh
+    return physical if physical.axis_names else None
 
 
 def _shard_mapped(kernel, q, k, v):
@@ -141,7 +127,7 @@ def _shard_mapped(kernel, q, k, v):
     stays local — context parallelism is ring/Ulysses attention's job
     (parallel/ring_attention.py), never this kernel's."""
     mesh = _ambient_mesh()
-    if mesh is None or not mesh.axis_names:
+    if mesh is None:
         return kernel(q, k, v)
     have = set(mesh.axis_names)
     batch_axes = tuple(a for a in ("data", "fsdp")
@@ -153,20 +139,11 @@ def _shard_mapped(kernel, q, k, v):
     from jax.sharding import PartitionSpec as P
 
     spec = P(batch_axes or None, None, head_axis, None)
-    try:
-        # check_vma=False: pallas_call's out_shape ShapeDtypeStructs carry
-        # no varying-mesh-axes annotation, which strict vma checking rejects
-        wrapped = jax.shard_map(
-            kernel, mesh=mesh, in_specs=(spec, spec, spec),
-            out_specs=spec, check_vma=False)
-    except (TypeError, AttributeError):   # older jax: no check_vma / no jax.shard_map
-        # jax 0.4.x spells the same escape hatch check_rep=False (pallas
-        # has no replication rule on that era either)
-        from jax.experimental.shard_map import shard_map as _old_shard_map
-
-        wrapped = _old_shard_map(kernel, mesh=mesh,
-                                 in_specs=(spec, spec, spec),
-                                 out_specs=spec, check_rep=False)
+    # check_vma=False: pallas_call's out_shape ShapeDtypeStructs carry no
+    # varying-mesh-axes annotation, which strict vma checking rejects
+    wrapped = jax.shard_map(
+        kernel, mesh=mesh, in_specs=(spec, spec, spec),
+        out_specs=spec, check_vma=False)
     return wrapped(q, k, v)
 
 

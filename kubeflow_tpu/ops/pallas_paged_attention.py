@@ -67,7 +67,7 @@ def _decode_kernel(kvlen_ref, tables_ref, q_ref, k_ref, v_ref, *rest,
                    quantized=False):
     if quantized:
         # quantized pools ride with per-block per-kv-head scale tiles
-        # ([1, KV_H] f32, same index-map clipping as the pool blocks)
+        # ([1, 1, KV_H] f32, same index-map clipping as the pool blocks)
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
@@ -103,7 +103,7 @@ def _decode_kernel(kvlen_ref, tables_ref, q_ref, k_ref, v_ref, *rest,
                 # per-kv-head scale between DMA and the MXU — the exact
                 # per-element pipeline the gather oracle runs, so
                 # kernel-vs-oracle parity stays bit-for-bit in f32
-                kh = kh * ks_ref[0, h]
+                kh = kh * ks_ref[0, 0, h]
             rows.append(jax.lax.dot_general(
                 qh, kh, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32))         # [G, block]
@@ -125,7 +125,7 @@ def _decode_kernel(kvlen_ref, tables_ref, q_ref, k_ref, v_ref, *rest,
             vh = v_ref[0, :, h * head_dim:(h + 1) * head_dim].astype(
                 jnp.float32)
             if vs_ref is not None:
-                vh = vh * vs_ref[0, h]
+                vh = vh * vs_ref[0, 0, h]
             rows.append(jax.lax.dot_general(
                 ph, vh, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32))         # [G, D]
@@ -183,7 +183,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, kv_len, *,
     def scale_map(bi, j, kvlen_ref, tables_ref):
         n_live = pl.cdiv(kvlen_ref[bi], block_size)
         jc = jnp.clip(jnp.minimum(j, n_live - 1), 0, n_tables - 1)
-        return (tables_ref[bi, jc], 0)
+        return (tables_ref[bi, jc], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, h, d), lambda bi, j, *_: (bi, 0, 0)),
@@ -195,9 +195,13 @@ def paged_decode_attention(q, k_pool, v_pool, tables, kv_len, *,
         if k_scale.shape != (num_blocks, kvh):
             raise ValueError(f"k_scale shape {k_scale.shape} != "
                              f"{(num_blocks, kvh)}")
-        in_specs += [pl.BlockSpec((1, kvh), scale_map),
-                     pl.BlockSpec((1, kvh), scale_map)]
-        args += (k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
+        # [NB, 1, KV_H]: Mosaic wants a block's last two dims to be (8, 128)
+        # multiples or the array's own, and one block's (1, KV_H) row is
+        # neither inside [NB, KV_H]
+        in_specs += [pl.BlockSpec((1, 1, kvh), scale_map),
+                     pl.BlockSpec((1, 1, kvh), scale_map)]
+        args += tuple(s.astype(jnp.float32).reshape(num_blocks, 1, kvh)
+                      for s in (k_scale, v_scale))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -241,17 +245,11 @@ def shard_unsupported_reason(mesh, n_kv_heads: int,
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: Mosaic calls have no replication /
-    varying-mesh-axes rule, so the check must be off (the specs here are
-    correct by construction — per-KV-head groups are independent)."""
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (TypeError, AttributeError):
-        from jax.experimental.shard_map import shard_map as _old
-
-        return _old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=False)
+    """Mosaic calls have no varying-mesh-axes rule, so the check must be
+    off (the specs here are correct by construction — per-KV-head groups
+    are independent)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def paged_decode_attention_sharded(q, k_pool, v_pool, tables, kv_len, *,

@@ -608,18 +608,10 @@ def scale_proofs(quick: bool = False,
       joined over DCN (dcn_data=2 × fsdp=32) — the multi-slice shape.
     """
     # persistent compile cache: the three proofs cost ~12 min of XLA:TPU
-    # compile cold; a later run on the same machine (e.g. the driver's
-    # bench after CI already proved them) reuses what it can. Per-user
-    # default dir; an explicitly configured cache is never clobbered.
-    import os
+    # compile cold; a later run on the same machine reuses what it can
+    from kubeflow_tpu.utils import compile_cache
 
-    if jax.config.jax_compilation_cache_dir is None:
-        cache = os.environ.get(
-            "KFT_COMPILE_CACHE",
-            f"/tmp/kft-xla-cache-{os.getuid()}")
-        os.makedirs(cache, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    compile_cache.ensure()
 
     out = []
     out.append(aot_serve_proof(

@@ -1766,10 +1766,7 @@ def _worker_main() -> int:
 
     phases: dict = {}
     _phase(phases, "proc_start")
-    import jax
-
-    if os.environ.get("KFT_FORCE_PLATFORM"):
-        jax.config.update("jax_platforms", os.environ["KFT_FORCE_PLATFORM"])
+    import jax  # noqa: F401  (the import the imports_done stamp times)
 
     from kubeflow_tpu.obs.trace import SpanCollector
     from kubeflow_tpu.rendezvous.bootstrap import (
@@ -2026,10 +2023,6 @@ def _oracle_main() -> int:
     oracle for the env-described config and write its losses to
     KFT_MPMD_REPORT_DIR/oracle.json (the bench's parity reference).
     Needs XLA_FLAGS=--xla_force_host_platform_device_count >= stages."""
-    import jax
-
-    if os.environ.get("KFT_FORCE_PLATFORM"):
-        jax.config.update("jax_platforms", os.environ["KFT_FORCE_PLATFORM"])
     cfg = PipelineRunConfig.from_env()
     if os.environ.get("KFT_MPMD_MODEL", "mlp") == "llama":
         from kubeflow_tpu.parallel.pipeline_llama import (

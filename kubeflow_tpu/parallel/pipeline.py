@@ -21,12 +21,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map                       # jax >= 0.8
-except ImportError:                                 # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 
 def stack_stage_params(per_stage_params: list) -> Any:
@@ -129,27 +125,10 @@ def pipeline_apply(
     kwargs = dict(mesh=mesh, in_specs=(P(axis), batch_spec),
                   out_specs=out_specs)
     if partial_manual:
-        # jax >= 0.9: axis_names = the manual subset; the rest stays auto
-        try:
-            return shard_map(impl, axis_names=frozenset({axis}),
-                             check_vma=False, **kwargs)
-        except TypeError:
-            pass
-        # jax 0.4.x spells the same thing inside-out: auto = the NON-
-        # manual axes (check_rep off — the replication checker predates
-        # per-axis tracking and rejects the scanned stage body)
-        try:
-            return shard_map(
-                impl, auto=frozenset(mesh.axis_names) - {axis},
-                check_rep=False, **kwargs)
-        except TypeError as e:
-            raise RuntimeError(
-                "partial_manual pipeline_apply needs shard_map with "
-                "axis_names (jax>=0.9) or auto= (jax 0.4.x)") from e
-    try:
-        return shard_map(impl, check_vma=False, **kwargs)   # jax >= 0.8
-    except TypeError:
-        return shard_map(impl, check_rep=False, **kwargs)
+        # axis_names = the manual subset; the rest stays auto
+        return shard_map(impl, axis_names=frozenset({axis}),
+                         check_vma=False, **kwargs)
+    return shard_map(impl, check_vma=False, **kwargs)
 
 
 def pipeline_loss_fn(

@@ -28,22 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.8
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        # check_rep=False: the 0.4-era replication checker has no pcast
-        # to align constant-initialized scan carries with the varying
-        # inputs (the jax>=0.8 path matches them explicitly via pcast)
-        return _old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=False)
-
-
 NEG_INF = -1e30
 
 
@@ -72,19 +56,9 @@ def _block_attn_update(q, k, v, q_pos, k_pos, o, m, l, causal):
     return o_new, m_new, l_new
 
 
-def _axis_size(axis_name: str, static_size):
-    """Version-tolerant static axis size: ``jax.lax.axis_size`` only
-    exists on newer jax; older eras get the size from the caller's mesh
-    (it must be a static int — the ring permutation is built in Python)."""
-    if static_size is not None:
-        return int(static_size)
-    return jax.lax.axis_size(axis_name)
-
-
-def _ring_attention_shard(q, k, v, axis_name: str, causal: bool,
-                          axis_size=None):
+def _ring_attention_shard(q, k, v, axis_name: str, causal: bool):
     """Per-shard ring attention. q:[B,Sl,H,D] k,v:[B,Sl,KV,D] (local blocks)."""
-    n = _axis_size(axis_name, axis_size)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, sl, h, d = q.shape
     kvh = k.shape[2]
@@ -99,16 +73,15 @@ def _ring_attention_shard(q, k, v, axis_name: str, causal: bool,
     m = jnp.full((b, kvh, g, sl), NEG_INF, jnp.float32)
     l = jnp.zeros((b, kvh, g, sl), jnp.float32)
     # constant-initialized carries must be marked device-varying for scan
-    # under shard_map's varying-manual-axes checks (jax >= 0.8); match qf's
-    # varying set so carry-in and carry-out types agree.
-    if hasattr(jax.lax, "pcast"):
-        vma = set(getattr(jax.typeof(qf), "vma", ()))
+    # under shard_map's varying-manual-axes checks; match qf's varying set
+    # so carry-in and carry-out types agree.
+    vma = set(jax.typeof(qf).vma)
 
-        def _match_vma(x):
-            missing = tuple(vma - set(getattr(jax.typeof(x), "vma", ())))
-            return jax.lax.pcast(x, missing, to="varying") if missing else x
+    def _match_vma(x):
+        missing = tuple(vma - set(jax.typeof(x).vma))
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
 
-        o, m, l = (_match_vma(x) for x in (o, m, l))
+    o, m, l = (_match_vma(x) for x in (o, m, l))
 
     perm = [(j, (j + 1) % n) for j in range(n)]
 
@@ -142,23 +115,21 @@ def ring_attention(
     """
     qspec = P(batch_axes, axis, head_axis, None)
     kspec = P(batch_axes, axis, head_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_attention_shard, axis_name=axis,
-                          causal=causal, axis_size=mesh.shape[axis]),
-        mesh,
+                          causal=causal),
+        mesh=mesh,
         in_specs=(qspec, kspec, kspec),
         out_specs=qspec,
     )
     return fn(q, k, v)
 
 
-def _ulysses_shard(q, k, v, axis_name: str, causal: bool,
-                   axis_size=None):
+def _ulysses_shard(q, k, v, axis_name: str, causal: bool):
     """Per-shard Ulysses: all_to_all seq-shard -> head-shard, full attention,
     reverse. q:[B,Sl,H,D] k,v:[B,Sl,KV,D]; requires KV % axis_size == 0."""
     from kubeflow_tpu.ops.attention import _xla_attention
 
-    n = _axis_size(axis_name, axis_size)  # noqa: F841  (layout contract)
     # [B,Sl,H,D] -> gather seq, scatter heads -> [B,S,H/n,D]
     qg = jax.lax.all_to_all(q, axis_name, split_axis=2, concat_axis=1, tiled=True)
     kg = jax.lax.all_to_all(k, axis_name, split_axis=2, concat_axis=1, tiled=True)
@@ -179,10 +150,10 @@ def ulysses_attention(
             f"mesh axis {axis!r} ({mesh.shape[axis]}); use ring_attention"
         )
     qspec = P(batch_axes, axis, head_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ulysses_shard, axis_name=axis,
-                          causal=causal, axis_size=mesh.shape[axis]),
-        mesh,
+                          causal=causal),
+        mesh=mesh,
         in_specs=(qspec, qspec, qspec),
         out_specs=qspec,
     )

@@ -160,15 +160,12 @@ def initialize(env: Optional[dict] = None, timeout_s: float = 300.0):
     """
     import jax
 
-    # persistent XLA compile cache (same contract as serving's
-    # KFT_COMPILE_CACHE): a restarted or resubmitted job's first-step
-    # compile becomes a cache read — the dominant submit→first-step phase
-    # on anything but a brand-new program (BASELINE.md row 2)
-    cache = (env or os.environ).get("KFT_COMPILE_CACHE")
-    if cache:
-        os.makedirs(cache, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    from kubeflow_tpu.utils import compile_cache
+
+    # a restarted or resubmitted job's first-step compile becomes a cache
+    # read — the dominant submit→first-step phase on anything but a
+    # brand-new program (BASELINE.md row 2)
+    compile_cache.ensure()
 
     world = world_from_env(env)
     if world.num_processes > 1:

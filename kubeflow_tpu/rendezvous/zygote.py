@@ -14,8 +14,9 @@ Protocol (one connection per pod, held open for its life):
   zygote -> daemon: {"pid": N}            after the fork
   zygote -> daemon: {"exit": code}        when the child exits
 
-The child applies the pod env (backends are uninitialized, so XLA_FLAGS /
-JAX_PLATFORMS / KFT_FORCE_PLATFORM all still take effect), points
+The child applies the pod env (backends are uninitialized, so XLA_FLAGS
+still takes effect; what jax read at import — JAX_PLATFORMS,
+JAX_COMPILATION_CACHE_DIR — is re-applied through jax.config), points
 stdout/stderr at the pod log (omitting ``log`` inherits the zygote's own
 stdout — the pod log, for the in-pod kube form), and runs ``argv`` —
 which must be the ``[sys.executable, "-m", module, *args]`` form
@@ -117,11 +118,15 @@ def _run_child(req: dict) -> None:
         os.close(fd)
     # no "log": inherit the zygote's own stdout/stderr — in the standby-pod
     # form that IS the pod log, which is where the worker should write
-    if os.environ.get("KFT_FORCE_PLATFORM"):
-        import jax
+    # jax was imported before the fork, so it read the ZYGOTE's values of
+    # these at import; the pod's must be applied by hand
+    import jax
 
-        jax.config.update("jax_platforms",
-                          os.environ["KFT_FORCE_PLATFORM"])
+    for var, option in (("JAX_PLATFORMS", "jax_platforms"),
+                        ("JAX_COMPILATION_CACHE_DIR",
+                         "jax_compilation_cache_dir")):
+        if env.get(var):
+            jax.config.update(option, str(env[var]))
     # [python, -m, module, *args] — validated by the daemon before routing
     module = argv[2]
     sys.argv = [argv[0]] + argv[3:]

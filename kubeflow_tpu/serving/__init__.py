@@ -4,9 +4,7 @@ from kubeflow_tpu.serving.controller import (
     Autoscaler, CanaryGate, RuntimeRegistry, ServingController,
     ServingTicker,
 )
-from kubeflow_tpu.serving.jax_model import (
-    JAXModel, LLMModel, enable_compile_cache,
-)
+from kubeflow_tpu.serving.jax_model import JAXModel, LLMModel
 from kubeflow_tpu.serving.llm import GenRequest, LLMEngine, SamplingParams
 from kubeflow_tpu.serving.model import (
     Model, ModelMissing, ModelNotReady, ModelRepository,
@@ -40,5 +38,5 @@ __all__ = [
     "PredictorSpec", "RadixPrefixCache", "RuntimeRegistry", "SamplingParams",
     "SchedulerConfig", "ServingController", "ServingRuntime", "ServingTicker",
     "StepScheduler", "TrafficSplitter", "TrainedModel", "V2SocketClient",
-    "V2SocketServer", "download", "enable_compile_cache", "radix_block_key",
+    "V2SocketServer", "download", "radix_block_key",
 ]

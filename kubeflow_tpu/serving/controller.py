@@ -330,8 +330,11 @@ class ServingController:
             "KFT_MODEL_NAME": isvc.name,
             "KFT_MODEL_FORMAT": isvc.predictor.model_format.name,
             "KFT_STORAGE_URI": isvc.predictor.storage_uri or "",
-            "KFT_COMPILE_CACHE": runtime.compile_cache_dir or "",
         }
+        if runtime.compile_cache_dir:
+            # JAX's own name: the predictor's jax reads it at import
+            # (utils/compile_cache.py is the rule)
+            env["JAX_COMPILATION_CACHE_DIR"] = runtime.compile_cache_dir
         # a tier-level scheduler policy replaces the predictor-level one
         # wholesale (e.g. a bigger prefill token quota on the prefill tier)
         sp = ((tier.scheduler if tier is not None else None)

@@ -24,14 +24,7 @@ import numpy as np
 from kubeflow_tpu.serving.llm import LLMEngine, SamplingParams
 from kubeflow_tpu.serving.model import Model
 from kubeflow_tpu.serving.protocol import InferRequest, InferResponse
-
-
-def enable_compile_cache(cache_dir: str) -> None:
-    """Persistent XLA compile cache: serving cold start becomes a cache read
-    (minutes -> seconds). Safe to call more than once."""
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+from kubeflow_tpu.utils import compile_cache
 
 
 def _next_bucket(n: int, buckets: Sequence[int]) -> int:
@@ -48,21 +41,19 @@ class JAXModel(Model):
 
     def __init__(self, name: str, fn: Callable, params=None, *,
                  batch_buckets: Sequence[int] = (1, 4, 16, 64),
-                 compile_cache_dir: Optional[str] = None,
                  warmup: bool = True,
                  example_shape: Optional[Sequence[int]] = None):
         super().__init__(name)
         self.fn = fn
         self.params = params
         self.buckets = sorted(batch_buckets)
-        self.compile_cache_dir = compile_cache_dir
         self.warmup = warmup
         self.example_shape = tuple(example_shape) if example_shape else None
         self._jitted = None
 
     def load(self) -> bool:
-        if self.compile_cache_dir:
-            enable_compile_cache(self.compile_cache_dir)
+        # cold start becomes a cache read (minutes -> seconds)
+        compile_cache.ensure()
         self._jitted = jax.jit(self.fn)
         if self.warmup and self.example_shape is not None:
             for b in self.buckets:
@@ -171,7 +162,6 @@ class LLMModel(Model):
 
     def __init__(self, name: str, params, cfg, *, max_batch: int = 8,
                  max_seq: int = 1024, pad_id: int = 0,
-                 compile_cache_dir: Optional[str] = None,
                  prefill_buckets: Sequence[int] = (64, 128, 256, 512),
                  tokenizer=None, request_timeout: float = 600.0,
                  mesh=None, scheduler=None, quant=None, tier: str = ""):
@@ -184,7 +174,6 @@ class LLMModel(Model):
         self.max_batch = max_batch
         self.max_seq = max_seq
         self.pad_id = pad_id
-        self.compile_cache_dir = compile_cache_dir
         self.prefill_buckets = prefill_buckets
         self.tokenizer = tokenizer
         self.request_timeout = request_timeout
@@ -235,8 +224,7 @@ class LLMModel(Model):
     def load(self) -> bool:
         from kubeflow_tpu.parallel.depot import DepotStats, depot_from_env
 
-        if self.compile_cache_dir:
-            enable_compile_cache(self.compile_cache_dir)
+        compile_cache.ensure()
         t0 = time.perf_counter()
         self.engine = LLMEngine(
             self._params, self.cfg, max_batch=self.max_batch,

@@ -568,7 +568,7 @@ def dequant_gather_view(pool, scale, tables, cfg):
 
 def paged_insert_batch(cache, k_new, v_new, blk_ids, lengths, slots):
     """Batched prefill insert: all admitted requests' KV lands in ONE
-    scatter (admission dispatches are RTT-bound on a remote chip).
+    scatter.
 
     k_new/v_new: [L, B, T, KV, D] with T == blk_ids.shape[1] * block_size;
     blk_ids: [B, nb] pool destinations where id 0 means "skip this block"

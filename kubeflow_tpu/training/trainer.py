@@ -219,9 +219,15 @@ class Trainer:
             return params, opt_state, metrics
 
         donate_argnums = (0, 1) if donate else ()
-        # shardings propagate from the arguments (params/opt_state placed at
-        # init, batch placed by the data loader via self.batch_sharding)
-        return jax.jit(step, donate_argnums=donate_argnums)
+        # input shardings propagate from the arguments (params/opt_state
+        # placed at init, batch placed by the data loader via
+        # self.batch_sharding). The state comes OUT as it went in: left to
+        # the partitioner, XLA:TPU re-shards adafactor's factored moments
+        # over fsdp, which wastes the donation and makes step 2 of an
+        # AOT-compiled step (precompile) refuse its own outputs.
+        return jax.jit(step, donate_argnums=donate_argnums,
+                       out_shardings=(self.param_shardings,
+                                      self.opt_shardings, None))
 
     def train_step(self, batch):
         # the mesh context MUST be live at trace time: the model's logical

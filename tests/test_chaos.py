@@ -71,7 +71,7 @@ def test_real_job_survives_scheduled_chaos(tmp_path):
             "chaos-e2e", workers=2, mesh={"data": 2}, command=WORKER_CMD,
             env={"PYTHONPATH": _REPO_ROOT + ":" + os.environ.get(
                      "PYTHONPATH", ""),
-                 "KFT_FORCE_PLATFORM": "cpu",
+                 "JAX_PLATFORMS": "cpu",
                  "KFT_TRAIN_STEPS": "3",
                  "KFT_METRICS_PATH": str(tmp_path / "m.jsonl"),
                  "XLA_FLAGS": "--xla_force_host_platform_device_count=1"})

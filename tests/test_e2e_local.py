@@ -18,7 +18,7 @@ WORKER_CMD = [sys.executable, "-m", "kubeflow_tpu.rendezvous.worker_check"]
 def base_env(tmp_path):
     return {
         "PYTHONPATH": "/root/repo:" + os.environ.get("PYTHONPATH", ""),
-        "KFT_FORCE_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "KFT_METRICS_PATH": str(tmp_path / "metrics.jsonl"),
         "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
     }

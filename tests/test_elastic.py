@@ -694,7 +694,7 @@ def test_mirror_alarm_lands_condition_end_to_end(tmp_path):
                      "kubeflow_tpu.rendezvous.worker_check"],
             env={"PYTHONPATH": "/root/repo:" + os.environ.get(
                      "PYTHONPATH", ""),
-                 "KFT_FORCE_PLATFORM": "cpu",
+                 "JAX_PLATFORMS": "cpu",
                  "KFT_TRAIN_STEPS": "2",
                  "KFT_CHECKPOINT_DIR": str(tmp_path / "ckpt"),
                  "KFT_CHECKPOINT_EVERY": "1",
@@ -750,7 +750,7 @@ def test_warm_replacement_resumes_with_loss_continuity(tmp_path):
 
     def env(tag, extra=None):
         e = {"PYTHONPATH": "/root/repo:" + os.environ.get("PYTHONPATH", ""),
-             "KFT_FORCE_PLATFORM": "cpu",
+             "JAX_PLATFORMS": "cpu",
              "KFT_TRAIN_STEPS": "6",
              "KFT_METRICS_PATH": str(tmp_path / f"{tag}.jsonl"),
              "XLA_FLAGS": "--xla_force_host_platform_device_count=1"}

@@ -615,7 +615,7 @@ def test_chunked_prefill_releases_pool(tiny):
 
 def test_burst_admission_batches_prefill(tiny):
     """A burst of same-bucket requests pays ONE prefill dispatch, not one
-    per request (admission is RTT-bound on a remote chip)."""
+    per request."""
     cfg, params = tiny
     eng = LLMEngine(params, cfg, max_batch=4, max_seq=64,
                     prefill_buckets=(16,))

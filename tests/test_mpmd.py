@@ -442,7 +442,7 @@ def test_two_process_1f1b_worker_entry(tmp_path):
     ports = (free_port(), free_port())
     base = {**os.environ,
             "PYTHONPATH": repo + ":" + os.environ.get("PYTHONPATH", ""),
-            "JAX_PLATFORMS": "cpu", "KFT_FORCE_PLATFORM": "cpu",
+            "JAX_PLATFORMS": "cpu",
             "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
             "KFT_NUM_STAGES": "2",
             "KFT_MPMD_MICROBATCHES": "4", "KFT_MPMD_BATCH": "32",

@@ -95,3 +95,36 @@ def test_sharded_train_step_compiles_warning_clean(capfd):
     assert float(metrics["loss"]) > 0
     err = capfd.readouterr().err
     assert "Involuntary full rematerialization" not in err, err[-2000:]
+
+
+def test_precompiled_adafactor_step_accepts_its_own_outputs():
+    """The train state must come out of the step sharded as it went in.
+    Left to the partitioner, adafactor's factored moments (dims >= 128)
+    come back re-sharded over fsdp/tensor, and the AOT-compiled step that
+    ``precompile`` installs — strict about its input shardings, unlike
+    jit — raises on step 2. First seen on four v5e chips; same on CPU."""
+    import dataclasses
+
+    import jax
+
+    from kubeflow_tpu.models import llama
+    from kubeflow_tpu.training import (
+        Trainer, TrainerConfig, lm_loss_fn, put_batch,
+    )
+
+    cfg = dataclasses.replace(llama.llama_tiny(), dim=256, mlp_dim=512,
+                              vocab_size=512)
+    mesh = build_mesh(MeshConfig(fsdp=4, tensor=2))
+    trainer = Trainer(
+        mesh=mesh,
+        init_params_fn=lambda rng: llama.init_params(rng, cfg),
+        params_logical_axes=llama.param_logical_axes(cfg),
+        loss_fn=lm_loss_fn(llama.forward, cfg),
+        config=TrainerConfig(optimizer="adafactor", grad_accum=2),
+    )
+    trainer.init_state(jax.random.key(0))
+    batch = put_batch(mesh, {"tokens": np.ones((8, 33), np.int32)})
+    trainer.precompile(batch)
+    for _ in range(2):
+        metrics = trainer.train_step(batch)
+    assert np.isfinite(float(metrics["loss"]))

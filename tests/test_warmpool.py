@@ -30,7 +30,6 @@ from kubeflow_tpu.controller.warmpool import (
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BASE_ENV = {
     "PYTHONPATH": REPO + ":" + os.environ.get("PYTHONPATH", ""),
-    "KFT_FORCE_PLATFORM": "cpu",
     "JAX_PLATFORMS": "cpu",
     "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
 }
