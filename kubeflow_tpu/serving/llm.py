@@ -17,6 +17,7 @@ slot-based continuous-batching engine where
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import logging
@@ -373,6 +374,10 @@ class LLMEngine:
         # process collector, plus the three request-latency histograms
         # /metrics serves as kft_model_request_{ttft,itl,e2e}_seconds
         self.obs = obs or obs_trace.collector()
+        # the engine thread's timeline (_phase): the open ``engine.step``
+        # span and, inside it, the one open phase (name, span, annotation)
+        self._step_span: Optional[obs_trace.Span] = None
+        self._cur_phase: Optional[tuple] = None
         self.request_hists = {"ttft": Histogram(_REQ_LAT_BUCKETS),
                               "itl": Histogram(_REQ_LAT_BUCKETS),
                               "e2e": Histogram(_REQ_LAT_BUCKETS)}
@@ -755,6 +760,43 @@ class LLMEngine:
             attrs["trace_ids"] = tids
         return self.obs.start(name, attrs=attrs, **kw)
 
+    def _open_phase(self, name: str, attrs: Optional[dict] = None) -> None:
+        ann = jax.profiler.TraceAnnotation(name)
+        ann.__enter__()
+        self._cur_phase = (name, self.obs.start(
+            name, parent=self._step_span, attrs=attrs), ann)
+
+    def _close_phase(self) -> str:
+        name, span, ann = self._cur_phase
+        self._cur_phase = None
+        ann.__exit__(None, None, None)
+        self.obs.end(span)
+        return name
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, **attrs):
+        """One phase of ``step()`` on the engine thread's timeline: a
+        child span of the open ``engine.step`` plus a profiler annotation
+        of the same name. Phases TILE the step — entering one inside
+        another closes the outer one and reopens it (a new span of its
+        name) on the way out — so every instant, and every device idle
+        gap, lies in at most one. Rule of the names: in a phase ending in
+        ``.wait`` the host is blocked on the device; every other phase is
+        host work. Yields the span's attrs, for counts only known at the
+        end. Outside ``step()`` there is no timeline: nothing is
+        recorded."""
+        if self._step_span is None:
+            yield {}
+            return
+        outer = self._close_phase() if self._cur_phase else None
+        self._open_phase(name, attrs)
+        try:
+            yield self._cur_phase[1].attrs
+        finally:
+            self._close_phase()
+            if outer is not None:
+                self._open_phase(outer)
+
     def _note_request_latency(self, req: GenRequest, n_new: int) -> None:
         """Feed the request histograms after committing ``n_new`` tokens
         in one read-back. The first token closes TTFT; later commits
@@ -776,9 +818,7 @@ class LLMEngine:
             req.t_second_token = now
         if n_new > 0 and req.t_last_commit:
             gap = max(0.0, now - req.t_last_commit) / n_new
-            itl = self.request_hists["itl"]
-            for _ in range(n_new):
-                itl.observe(gap)
+            self.request_hists["itl"].observe(gap, n_new)
         req.t_last_commit = now
 
     def has_work(self) -> bool:
@@ -832,7 +872,36 @@ class LLMEngine:
         finished. Pipelined (default): the dispatch goes out BEFORE the
         previous chunk's tokens are fetched, so device compute overlaps
         host transfer + bookkeeping; results therefore lag one chunk.
-        Returns requests that finished this step."""
+        Returns requests that finished this step.
+
+        A step that has anything to do records one ``engine.step`` span
+        whose children (``_phase``) tile it: ``step.admit``,
+        ``prefill.wait``, ``step.dispatch``, ``step.wait``,
+        ``step.commit``; what no phase covers (the abort sweep, queued
+        control ops) is its self time."""
+        with self._lock:
+            waiting = len(self._waiting)
+            pending = bool(self._ctl or self._aborted)
+        if not (waiting or pending or self._active or self._chunked
+                or self._inflight is not None):
+            return self._step()
+        sched = self.sched
+        before = (sched.admitted + sched.chunked_started,
+                  sched.admission_stalls)
+        self._step_span = self.obs.start("engine.step", attrs={
+            "waiting": waiting, "active": len(self._active),
+            "free_slots": len(self._free)})
+        try:
+            with jax.profiler.TraceAnnotation("engine.step"):
+                return self._step()
+        finally:
+            span, self._step_span = self._step_span, None
+            self.obs.end(
+                span,
+                admitted=sched.admitted + sched.chunked_started - before[0],
+                stalled=int(sched.admission_stalls > before[1]))
+
+    def _step(self) -> list[GenRequest]:
         self.sched.note_step()
         self._drain_ctl()
         with self._lock:
@@ -858,7 +927,8 @@ class LLMEngine:
             for slot, st in list(self._chunked.items()):
                 if st.req.id in aborted:
                     self._cancel_chunked(slot)
-        self._admit()
+        with self._phase("step.admit"):
+            self._admit()
         finished_pre: list[GenRequest] = []
         if self.spec is not None and self._active:
             if all(r.sampling.temperature == 0
@@ -889,60 +959,8 @@ class LLMEngine:
                 self.sched.note_spec_fallback()
         new_inflight = None
         if self._active and self._need_dispatch():
-            active_mask = np.zeros((self.max_batch,), bool)
-            temp = np.zeros((self.max_batch,), np.float32)
-            top_k = np.zeros((self.max_batch,), np.int32)
-            top_p = np.ones((self.max_batch,), np.float32)
-            for slot, req in self._active.items():
-                active_mask[slot] = True
-                temp[slot] = req.sampling.temperature
-                top_k[slot] = req.sampling.top_k
-                top_p[slot] = req.sampling.top_p
-            if self._inflight is None or self._fresh.all():
-                token_in = jnp.asarray(self._tokens)
-            else:
-                # device carry from the in-flight chunk; fresh host tokens
-                # (admissions since that dispatch) override their slots
-                token_in = self._merge_tok(
-                    self._inflight["next"], jnp.asarray(self._tokens),
-                    jnp.asarray(self._fresh))
-            self._fresh[:] = False
-            tab = self._dispatch_tables()
-            chunk_len = self.sched.decode_chunk_len(
-                self._min_deterministic_remaining(),
-                pressure=bool(self._waiting))
-            self.sched.note_decode_dispatch(chunk_len)
-            dspan = self._dispatch_span(
-                "decode.step", [r for _, r in self._active.items()],
-                chunk_len=chunk_len, batch=len(self._active))
-            self._rng, step_rng = jax.random.split(self._rng)
-            # static: an all-greedy batch skips the per-step full-vocab
-            # sort (two compile variants total)
-            greedy_only = not bool((temp > 0).any())
-            if (self._compiled_decode is not None and greedy_only
-                    and chunk_len == self.decode_chunk):
-                # the precompile()d executable (depot fast path): same
-                # program as the jitted call below, acquired without a
-                # cold compile on a scale-up replica
-                toks, lps, next_tok, self.cache = self._compiled_decode(
-                    self.params, token_in, self.cache, jnp.asarray(tab),
-                    jnp.asarray(active_mask), jnp.asarray(temp),
-                    jnp.asarray(top_k), jnp.asarray(top_p), step_rng)
-            else:
-                toks, lps, next_tok, self.cache = self._decode(
-                    self.params, token_in, self.cache, jnp.asarray(tab),
-                    jnp.asarray(active_mask), jnp.asarray(temp),
-                    jnp.asarray(top_k), jnp.asarray(top_p), step_rng,
-                    greedy_only=greedy_only,
-                    kernel=self.kernel, chunk_len=chunk_len)
-            new_inflight = {
-                "toks": toks, "lps": lps, "next": next_tok,
-                "chunk_len": chunk_len, "span": dspan,
-                # snapshot: tokens belong to the requests active at
-                # DISPATCH time — a slot may host a new request by the
-                # time these arrays are read back
-                "snapshot": list(self._active.items()),
-            }
+            with self._phase("step.dispatch"):
+                new_inflight = self._dispatch_decode()
         prev, self._inflight = self._inflight, new_inflight
         finished = self._process_chunk(prev) if prev is not None else []
         if not self.decode_pipeline and self._inflight is not None:
@@ -950,6 +968,65 @@ class LLMEngine:
             flush, self._inflight = self._inflight, None
             finished += self._process_chunk(flush)
         return finished_pre + finished
+
+    def _dispatch_decode(self) -> dict:
+        """Build the dispatch's host arrays and launch one decode chunk
+        (asynchronous: returns once the program is enqueued). The
+        returned in-flight record is read back by _process_chunk."""
+        active_mask = np.zeros((self.max_batch,), bool)
+        temp = np.zeros((self.max_batch,), np.float32)
+        top_k = np.zeros((self.max_batch,), np.int32)
+        top_p = np.ones((self.max_batch,), np.float32)
+        for slot, req in self._active.items():
+            active_mask[slot] = True
+            temp[slot] = req.sampling.temperature
+            top_k[slot] = req.sampling.top_k
+            top_p[slot] = req.sampling.top_p
+        if self._inflight is None or self._fresh.all():
+            token_in = jnp.asarray(self._tokens)
+        else:
+            # device carry from the in-flight chunk; fresh host tokens
+            # (admissions since that dispatch) override their slots
+            token_in = self._merge_tok(
+                self._inflight["next"], jnp.asarray(self._tokens),
+                jnp.asarray(self._fresh))
+        self._fresh[:] = False
+        tab = self._dispatch_tables()
+        chunk_len = self.sched.decode_chunk_len(
+            self._min_deterministic_remaining(),
+            pressure=bool(self._waiting))
+        self.sched.note_decode_dispatch(chunk_len)
+        dspan = self._dispatch_span(
+            "decode.step", [r for _, r in self._active.items()],
+            chunk_len=chunk_len, batch=len(self._active))
+        self._rng, step_rng = jax.random.split(self._rng)
+        # static: an all-greedy batch skips the per-step full-vocab
+        # sort (two compile variants total)
+        greedy_only = not bool((temp > 0).any())
+        if (self._compiled_decode is not None and greedy_only
+                and chunk_len == self.decode_chunk):
+            # the precompile()d executable (depot fast path): same
+            # program as the jitted call below, acquired without a
+            # cold compile on a scale-up replica
+            toks, lps, next_tok, self.cache = self._compiled_decode(
+                self.params, token_in, self.cache, jnp.asarray(tab),
+                jnp.asarray(active_mask), jnp.asarray(temp),
+                jnp.asarray(top_k), jnp.asarray(top_p), step_rng)
+        else:
+            toks, lps, next_tok, self.cache = self._decode(
+                self.params, token_in, self.cache, jnp.asarray(tab),
+                jnp.asarray(active_mask), jnp.asarray(temp),
+                jnp.asarray(top_k), jnp.asarray(top_p), step_rng,
+                greedy_only=greedy_only,
+                kernel=self.kernel, chunk_len=chunk_len)
+        return {
+            "toks": toks, "lps": lps, "next": next_tok,
+            "chunk_len": chunk_len, "span": dspan,
+            # snapshot: tokens belong to the requests active at
+            # DISPATCH time — a slot may host a new request by the
+            # time these arrays are read back
+            "snapshot": list(self._active.items()),
+        }
 
     def _need_dispatch(self) -> bool:
         """Skip the next dispatch when the in-flight chunk already covers
@@ -1031,36 +1108,40 @@ class LLMEngine:
         return tab
 
     def _process_chunk(self, inflight: dict) -> list[GenRequest]:
-        toks = np.asarray(inflight["toks"])     # [chunk, B] (blocks here)
-        lps = np.asarray(inflight["lps"])
+        with self._phase("step.wait", device_steps=inflight["chunk_len"]):
+            toks = np.asarray(inflight["toks"])     # [chunk, B] (blocks here)
+            lps = np.asarray(inflight["lps"])
         self.steps += toks.shape[0]
         finished = []
         committed_total = 0
-        for slot, req in inflight["snapshot"]:
-            if req.done:
-                continue               # aborted/retired after dispatch
-            n0 = len(req.generated)
-            done = False
-            for t in range(toks.shape[0]):
-                if self._commit_token(req, slot, int(toks[t, slot]),
-                                      float(lps[t, slot])):
-                    # overshoot tokens beyond this point are trimmed (never
-                    # appended); their cache writes went to this slot's own
-                    # blocks / scratch and are ordered before any reuse
-                    done = True
-                    break
-            n_new = len(req.generated) - n0
-            committed_total += n_new
-            self._note_request_latency(req, n_new)
-            if done:
-                finished.append(req)
-                self._retire(req, slot)
-        span = inflight.get("span")
-        if span is not None:
-            # the decode span covers dispatch -> read-back (pipelined:
-            # device compute + the host overlap it bought)
-            self.obs.end(span, tokens_committed=committed_total,
-                         device_steps=int(toks.shape[0]))
+        with self._phase("step.commit") as counts:
+            for slot, req in inflight["snapshot"]:
+                if req.done:
+                    continue           # aborted/retired after dispatch
+                n0 = len(req.generated)
+                done = False
+                for t in range(toks.shape[0]):
+                    if self._commit_token(req, slot, int(toks[t, slot]),
+                                          float(lps[t, slot])):
+                        # overshoot tokens beyond this point are trimmed
+                        # (never appended); their cache writes went to this
+                        # slot's own blocks / scratch and are ordered before
+                        # any reuse
+                        done = True
+                        break
+                n_new = len(req.generated) - n0
+                committed_total += n_new
+                self._note_request_latency(req, n_new)
+                if done:
+                    finished.append(req)
+                    self._retire(req, slot)
+            counts["tokens_committed"] = committed_total
+            span = inflight.get("span")
+            if span is not None:
+                # the decode span covers dispatch -> read-back (pipelined:
+                # device compute + the host overlap it bought)
+                self.obs.end(span, tokens_committed=committed_total,
+                             device_steps=int(toks.shape[0]))
         return finished
 
     def _spec_step(self) -> list[GenRequest]:
@@ -1076,10 +1157,28 @@ class LLMEngine:
         advances host-side by the COMMITTED count only: rejected rows
         sit beyond it, invisible to attention, and the next dispatch
         rewrites them before they could ever be unmasked."""
+        with self._phase("step.dispatch"):
+            launched = self._spec_dispatch()
+        if launched is None:
+            return None           # nothing to verify: caller runs decode
+        drafts, vspan, toks, lps = launched
+        with self._phase("step.wait", device_steps=1):
+            toks = np.asarray(toks)
+            lps = np.asarray(lps)
+        self.steps += 1
+        with self._phase("step.commit") as counts:
+            finished, committed_total = self._spec_commit(drafts, toks, lps)
+            counts["tokens_committed"] = committed_total
+            self.obs.end(vspan, tokens_committed=committed_total)
+        return finished
+
+    def _spec_dispatch(self) -> Optional[tuple]:
+        """Draft per stream and launch ONE verify over all of them:
+        ``(drafts, span, toks, lps)`` with the device arrays not yet read
+        back, or None when no stream drafted anything."""
         bs = self.paged.block_size
         drafts: dict[int, list[int]] = {}
         k_max = 0
-        vspan = None
         for slot, req in self._active.items():
             # deterministic remaining budget: drafts past it can never
             # commit (the commit loop stops at max_tokens/max_seq), so
@@ -1090,7 +1189,7 @@ class LLMEngine:
             drafts[slot] = d
             k_max = max(k_max, len(d))
         if k_max == 0:
-            return None           # nothing to verify: caller runs decode
+            return None
         # pow2 verify width (input column + drafts): log2(spec_k+1)
         # compile variants, the scheduler's static chunk_len scheme
         width = ceil_pow2(1 + k_max)
@@ -1111,9 +1210,12 @@ class LLMEngine:
         toks, lps, self.cache = self._verify(
             self.params, jnp.asarray(tokens), self.cache,
             jnp.asarray(self._dispatch_tables()), jnp.asarray(limit))
-        toks = np.asarray(toks)
-        lps = np.asarray(lps)
-        self.steps += 1
+        return drafts, vspan, toks, lps
+
+    def _spec_commit(self, drafts: dict, toks, lps) -> tuple[list, int]:
+        """Accept the longest draft prefix the target's greedy chain
+        confirms, commit it plus the target's own next token, and publish
+        the committed lengths: ``(finished requests, tokens committed)``."""
         finished = []
         committed_total = 0
         new_len = np.zeros((self.max_batch,), np.int32)
@@ -1153,9 +1255,7 @@ class LLMEngine:
                 # committed length only — rejected rows stay beyond it
                 new_len[slot] = len(req.prompt) + len(req.generated) - 1
         self.cache = self._set_lens(self.cache, jnp.asarray(new_len))
-        if vspan is not None:
-            self.obs.end(vspan, tokens_committed=committed_total)
-        return finished
+        return finished, committed_total
 
     def generate(self, prompts: Sequence[Sequence[int]],
                  sampling: Optional[SamplingParams] = None,
@@ -1384,7 +1484,10 @@ class LLMEngine:
         tok, lp = self._first_sample(
             logits, rng, jnp.asarray(temp), jnp.asarray(top_k),
             jnp.asarray(top_p))
-        return np.asarray(tok), np.asarray(lp)
+        # the admission's first host read of a device value: blocks behind
+        # the prefill and whatever decode chunk was already in flight
+        with self._phase("prefill.wait"):
+            return np.asarray(tok), np.asarray(lp)
 
     def _post_admit(self, req, slot: int, first_tok: int,
                     first_lp: float) -> None:
