@@ -290,20 +290,27 @@ def leg_check(a) -> dict:
                            "are the reference's argmax")
     del params, logits
 
-    # --- each paged-decode kernel variant, directly, vs the gather oracle
+    # --- each paged-decode kernel variant, directly, vs the gather oracle:
+    # whole pools of LAYERS layers, each with its own contents, read at the
+    # last one (the kernel is addressed by layer, never handed a slice)
     n = device["count"]
     h, kvh, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     b, bs = size["max_batch"], 64 if a.size == "full" else 8
     nbp = size["max_seq"] // bs
     nb = b * nbp + 1
+    layers, layer = 3, 2
     ks = jax.random.split(jax.random.key(SEED), 6)
     q = jax.random.normal(ks[0], (b, h, d), jnp.bfloat16)
-    kp = jax.random.normal(ks[1], (nb, bs, kvh, d), jnp.bfloat16)
-    vp = jax.random.normal(ks[2], (nb, bs, kvh, d), jnp.bfloat16)
-    kq = jax.random.randint(ks[1], (nb, bs, kvh, d), -127, 127, jnp.int8)
-    vq = jax.random.randint(ks[2], (nb, bs, kvh, d), -127, 127, jnp.int8)
-    ksc = jax.random.uniform(ks[3], (nb, kvh), jnp.float32, 0.005, 0.02)
-    vsc = jax.random.uniform(ks[4], (nb, kvh), jnp.float32, 0.005, 0.02)
+    kp = jax.random.normal(ks[1], (layers, nb, bs, kvh, d), jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (layers, nb, bs, kvh, d), jnp.bfloat16)
+    kq = jax.random.randint(ks[1], (layers, nb, bs, kvh, d), -127, 127,
+                            jnp.int8)
+    vq = jax.random.randint(ks[2], (layers, nb, bs, kvh, d), -127, 127,
+                            jnp.int8)
+    ksc = jax.random.uniform(ks[3], (layers, nb, kvh), jnp.float32,
+                             0.005, 0.02)
+    vsc = jax.random.uniform(ks[4], (layers, nb, kvh), jnp.float32,
+                             0.005, 0.02)
     tables = jnp.asarray(1 + np.random.default_rng(SEED).permutation(
         nb - 1).reshape(b, nbp), jnp.int32)
     kv_len = jax.random.randint(ks[5], (b,), 1, nbp * bs + 1, jnp.int32)
@@ -312,9 +319,9 @@ def leg_check(a) -> dict:
         return decode_attention(q[:, None], k_view.reshape(b, -1, kvh, d),
                                 v_view.reshape(b, -1, kvh, d), kv_len)[:, 0]
 
-    ref_bf16 = oracle(kp[tables], vp[tables])
-    ref_int8 = oracle(dequant_gather_view(kq, ksc, tables, cfg),
-                      dequant_gather_view(vq, vsc, tables, cfg))
+    ref_bf16 = oracle(kp[layer, tables], vp[layer, tables])
+    ref_int8 = oracle(dequant_gather_view(kq, ksc, layer, tables, cfg),
+                      dequant_gather_view(vq, vsc, layer, tables, cfg))
 
     # mesh=None is the bare kernel; a 1-sized tensor axis needs no
     # partitioning either, so the wrapper really runs under shard_map only
@@ -322,8 +329,8 @@ def leg_check(a) -> dict:
     def kernel(mesh):
         def fn(q, kp, vp, k_scale=None, v_scale=None):
             return paged_decode_attention_sharded(
-                q, kp, vp, tables, kv_len, mesh=mesh, interpret=interpret,
-                k_scale=k_scale, v_scale=v_scale)
+                q, kp, vp, layer, tables, kv_len, mesh=mesh,
+                interpret=interpret, k_scale=k_scale, v_scale=v_scale)
         return jax.jit(fn)
 
     plain, sharded = kernel(None), kernel(build_mesh(MeshConfig(tensor=n)))

@@ -12,26 +12,42 @@ the first-party training flash kernel.
 
 Design (decode only — one query token per slot):
 
-- Grid ``(batch, max_blocks_per_seq)``; the block-table row and live
-  lengths ride in as **scalar-prefetch** operands, so the K/V BlockSpec
-  index maps dereference ``tables[b, j]`` — the pool block, not the
-  logical position — while the pipeline prefetches.
+- The kernel is given the WHOLE pool ``[L, num_blocks, block, KV_H, D]``
+  and a layer index, never a slice of it: the decode program carries the
+  pool through its layer loop and updates it in place
+  (``serving/paged_kv.py``), and ``pool[layer]`` handed to a custom call
+  would be a copy of a layer's pool, every layer.
+- Grid ``(batch, max_blocks_per_seq)``; the layer, the live lengths and
+  the block-table rows ride in as **scalar-prefetch** operands, so the K/V
+  BlockSpec index maps return ``(layer, tables[b, j], 0, 0, 0)`` — the
+  pool block, not the logical position — while the pipeline prefetches.
 - Iterations past a slot's live block count (``ceil(kv_len/block)``, NOT
   ``max_blocks_per_seq``) are pinned by the index map to the slot's LAST
   live block: Pallas elides the re-fetch of an unchanged block, so dead
   tail iterations issue **no DMA and no compute** (`pl.when`-guarded) —
   per-step HBM traffic is O(live tokens), the paged-attention property.
-- GQA in-kernel: query heads are grouped over KV heads (``groups = H /
-  KV_H``); each pool block is fetched ONCE per slot and every group's
-  ``[G, D] x [D, block]`` logit tile is computed from it — KV heads are
-  never repeated, and no ``[max_seq]`` view ever exists.
+- The pool is read in the layout it is stored in: a block arrives as
+  ``(block, KV_H, D)`` and is used as the ``[block * KV_H, D]`` matrix it
+  is in memory (merging leading dims of an f32 tile is free). A merged
+  ``[..., KV_H * D]`` view, whose per-head tiles would be lane slices,
+  is NOT a free reshape of the pool on the chip — both shapes are tiled
+  over their last two dims, so it is a relayout of everything reshaped
+  (PR 27: 2 of the 8 whole-pool operations a step).
+- GQA in-kernel, without per-head tiles: ONE ``[H, D] x [D, block*KV_H]``
+  product scores every query head against every (token, kv head) row of
+  the block and a mask keeps each head's own kv head (column ``c`` is kv
+  head ``c % KV_H``, query head ``h`` belongs to ``h // groups``); the
+  masked probabilities are exactly 0, so ONE ``[H, block*KV_H] x
+  [block*KV_H, D]`` product sums each head's own rows. That is KV_H times
+  the useful MXU work, and free: the kernel moves 1 byte per ~32 flop
+  against a ridge of 240, and on the chip it is as fast as the per-head
+  lane-slice form it replaces and 15% faster than per-head strided
+  sublane reads of the same block (PERF.md §6, PR 27). Each pool block is
+  fetched ONCE per slot; KV heads are never repeated, and no
+  ``[max_seq]`` view ever exists.
 - Online softmax across a slot's blocks (running max / sum / weighted
   accumulator in VMEM scratch, f32), exactly the flash recurrence the
   training kernel uses.
-- The K/V pools enter as ``[num_blocks, block, KV_H * D]`` (a free
-  reshape of the engine pool layout): per-head slices are then LANE
-  slices at multiples of D — cheap and layout-friendly — instead of
-  strided sublane gathers over a ``[block, KV_H, D]`` tile.
 
 ``interpret=True`` runs the identical kernel logic on CPU (tier-1 tests);
 the gather path in ``serving/paged_kv.py`` stays available as the
@@ -62,12 +78,13 @@ from jax.sharding import PartitionSpec as P
 NEG_INF = -1e30      # same mask value as the gather path (decode_attention)
 
 
-def _decode_kernel(kvlen_ref, tables_ref, q_ref, k_ref, v_ref, *rest,
-                   scale, block_size, kv_heads, groups, head_dim,
+def _decode_kernel(layer_ref, kvlen_ref, tables_ref, q_ref, k_ref, v_ref,
+                   *rest, scale, block_size, kv_heads, groups, head_dim,
                    quantized=False):
+    del layer_ref                   # consumed by the index maps
     if quantized:
         # quantized pools ride with per-block per-kv-head scale tiles
-        # ([1, 1, KV_H] f32, same index-map clipping as the pool blocks)
+        # ([1, 1, 1, KV_H] f32, same index-map clipping as the pool blocks)
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
@@ -76,6 +93,19 @@ def _decode_kernel(kvlen_ref, tables_ref, q_ref, k_ref, v_ref, *rest,
     j = pl.program_id(1)
     kv_len = kvlen_ref[b]
     n_live = pl.cdiv(kv_len, block_size)
+
+    def tile(ref, sc_ref):
+        """One pool block as the [block * KV_H, D] f32 matrix it is in
+        memory (row t * KV_H + g: token t, kv head g), dequantized."""
+        x = ref[0, 0].astype(jnp.float32)                    # [bs, KV_H, D]
+        if sc_ref is not None:
+            # dequant fused into the online-softmax inner loop: the
+            # int8/fp8 tile upcasts and multiplies its block's per-kv-head
+            # scale between DMA and the MXU — the exact per-element
+            # pipeline the gather oracle runs, so kernel-vs-oracle parity
+            # stays bit-for-bit in f32
+            x = x * sc_ref[0, 0, 0][None, :, None]
+        return x.reshape(block_size * kv_heads, head_dim)
 
     @pl.when(j == 0)
     def _init():
@@ -89,47 +119,31 @@ def _decode_kernel(kvlen_ref, tables_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(j < n_live)
     def _contribute():
         q = q_ref[0].astype(jnp.float32) * scale             # [H, D]
-        # logits for every query head against this block, grouped: the
-        # block is resident ONCE; each KV head's [block, D] tile is a lane
-        # slice feeding its group's [G, D] x [D, block] matmul
-        rows = []
-        for h in range(kv_heads):
-            qh = q[h * groups:(h + 1) * groups]              # [G, D]
-            kh = k_ref[0, :, h * head_dim:(h + 1) * head_dim].astype(
-                jnp.float32)
-            if ks_ref is not None:
-                # dequant fused into the online-softmax inner loop: the
-                # int8/fp8 tile upcasts and multiplies its block's
-                # per-kv-head scale between DMA and the MXU — the exact
-                # per-element pipeline the gather oracle runs, so
-                # kernel-vs-oracle parity stays bit-for-bit in f32
-                kh = kh * ks_ref[0, 0, h]
-            rows.append(jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32))         # [G, block]
-        s = jnp.concatenate(rows, axis=0)                    # [H, block]
-        kv_pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(kv_pos < kv_len, s, NEG_INF)
+        # every query head against every (token, kv head) row of the
+        # block in ONE product, then masked to the head's own kv head:
+        # the block stays in the layout it is stored in (no per-head
+        # strided read, no relayout), and the kernel is bound by memory
+        # (32 flop/byte against a ridge of 240), so the KV_H-fold of
+        # redundant MXU work is free
+        s = jax.lax.dot_general(
+            q, tile(k_ref, ks_ref), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [H, bs*KV_H]
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        own = (col % kv_heads) == (head // groups)
+        live = j * block_size + col // kv_heads < kv_len
+        s = jnp.where(own & live, s, NEG_INF)
 
         # online-softmax recurrence; m/l scratch is lane-replicated so the
         # [H, 128] tiles stay aligned (only lane 0 is meaningful)
         m_prev, l_prev = m_ref[...], l_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])                        # [H, block]
+        p = jnp.exp(s - m_new[:, :1])          # masked columns: exactly 0
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        rows = []
-        for h in range(kv_heads):
-            ph = p[h * groups:(h + 1) * groups]              # [G, block]
-            vh = v_ref[0, :, h * head_dim:(h + 1) * head_dim].astype(
-                jnp.float32)
-            if vs_ref is not None:
-                vh = vh * vs_ref[0, 0, h]
-            rows.append(jax.lax.dot_general(
-                ph, vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))         # [G, D]
-        acc = acc_ref[...] * alpha[:, :1] + jnp.concatenate(rows, axis=0)
+        acc = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
+            p, tile(v_ref, vs_ref), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [H, D]
         m_ref[...], l_ref[...], acc_ref[...] = m_new, l_new, acc
         # the output block is revisited across j (its index map ignores
         # j), so writing the normalized running state every live block
@@ -138,20 +152,27 @@ def _decode_kernel(kvlen_ref, tables_ref, q_ref, k_ref, v_ref, *rest,
             o_ref.dtype)
 
 
-def paged_decode_attention(q, k_pool, v_pool, tables, kv_len, *,
+def paged_decode_attention(q, k_pool, v_pool, layer, tables, kv_len, *,
                            interpret: bool = False,
                            k_scale=None, v_scale=None):
-    """Block-resident paged GQA decode attention.
+    """Block-resident paged GQA decode attention over ONE layer of the
+    whole pool, addressed by (layer, block).
 
     q: [B, H, D] (this step's query rows); k_pool/v_pool:
-    [num_blocks, block_size, KV_H, D] (the paged pools, current step's KV
-    row already scattered in); tables: [B, max_blocks_per_seq] int32 pool
+    [L, num_blocks, block_size, KV_H, D] (the engine's paged pools as
+    stored, current step's KV row already scattered in); layer: int32
+    scalar, the layer to read; tables: [B, max_blocks_per_seq] int32 pool
     block ids in logical order; kv_len: [B] int32 live rows per slot
     INCLUDING this step. Returns [B, H, D] in q.dtype.
 
-    k_scale/v_scale: [num_blocks, KV_H] f32 per-block per-kv-head scales
-    of an int8/fp8-quantized pool (both or neither). When given, each
-    fetched pool tile dequants (upcast * scale) inside the online-
+    The caller never slices ``pool[layer]`` (a copy of a layer's pool,
+    every layer): the layer rides as a scalar-prefetch operand beside the
+    lengths and the tables and the K/V index maps return
+    ``(layer, tables[b, j], ...)``.
+
+    k_scale/v_scale: [L, num_blocks, KV_H] f32 per-block per-kv-head
+    scales of an int8/fp8-quantized pool (both or neither). When given,
+    each fetched pool tile dequants (upcast * scale) inside the online-
     softmax inner loop — the scale tiles ride the same scalar-prefetch
     index map as the pool blocks, so dead-tail iterations elide their
     DMA too.
@@ -159,52 +180,49 @@ def paged_decode_attention(q, k_pool, v_pool, tables, kv_len, *,
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
     b, h, d = q.shape
-    num_blocks, block_size, kvh, d_k = k_pool.shape
+    n_layers, num_blocks, block_size, kvh, d_k = k_pool.shape
     if d != d_k:
         raise ValueError(f"head_dim mismatch: q has {d}, pool has {d_k}")
     if h % kvh:
         raise ValueError(f"H={h} not a multiple of KV_H={kvh}")
     groups = h // kvh
     n_tables = tables.shape[1]
-    # free reshape (contiguous): per-head tiles become lane slices
-    k2 = k_pool.reshape(num_blocks, block_size, kvh * d)
-    v2 = v_pool.reshape(num_blocks, block_size, kvh * d)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     kv_len = kv_len.astype(jnp.int32)
     tables = tables.astype(jnp.int32)
 
-    def kv_map(bi, j, kvlen_ref, tables_ref):
+    def kv_map(bi, j, layer_ref, kvlen_ref, tables_ref):
         # past the live tail, pin to the last live block: the unchanged
         # block index elides the DMA (idle slots pin to block 0, fetched
         # once)
         n_live = pl.cdiv(kvlen_ref[bi], block_size)
         jc = jnp.clip(jnp.minimum(j, n_live - 1), 0, n_tables - 1)
-        return (tables_ref[bi, jc], 0, 0)
+        return (layer_ref[0], tables_ref[bi, jc], 0, 0, 0)
 
-    def scale_map(bi, j, kvlen_ref, tables_ref):
-        n_live = pl.cdiv(kvlen_ref[bi], block_size)
-        jc = jnp.clip(jnp.minimum(j, n_live - 1), 0, n_tables - 1)
-        return (tables_ref[bi, jc], 0, 0)
+    def scale_map(*idx):
+        return kv_map(*idx)[:4]
 
     in_specs = [
         pl.BlockSpec((1, h, d), lambda bi, j, *_: (bi, 0, 0)),
-        pl.BlockSpec((1, block_size, kvh * d), kv_map),
-        pl.BlockSpec((1, block_size, kvh * d), kv_map),
+        pl.BlockSpec((1, 1, block_size, kvh, d), kv_map),
+        pl.BlockSpec((1, 1, block_size, kvh, d), kv_map),
     ]
-    args = (kv_len, tables, q, k2, v2)
+    args = (layer, kv_len, tables, q, k_pool, v_pool)
     if k_scale is not None:
-        if k_scale.shape != (num_blocks, kvh):
+        if k_scale.shape != (n_layers, num_blocks, kvh):
             raise ValueError(f"k_scale shape {k_scale.shape} != "
-                             f"{(num_blocks, kvh)}")
-        # [NB, 1, KV_H]: Mosaic wants a block's last two dims to be (8, 128)
-        # multiples or the array's own, and one block's (1, KV_H) row is
-        # neither inside [NB, KV_H]
-        in_specs += [pl.BlockSpec((1, 1, kvh), scale_map),
-                     pl.BlockSpec((1, 1, kvh), scale_map)]
-        args += tuple(s.astype(jnp.float32).reshape(num_blocks, 1, kvh)
-                      for s in (k_scale, v_scale))
+                             f"{(n_layers, num_blocks, kvh)}")
+        # [L, NB, 1, KV_H]: Mosaic wants a block's last two dims to be
+        # (8, 128) multiples or the array's own, and one block's (1, KV_H)
+        # row is neither inside [NB, KV_H]
+        in_specs += [pl.BlockSpec((1, 1, 1, kvh), scale_map),
+                     pl.BlockSpec((1, 1, 1, kvh), scale_map)]
+        args += tuple(
+            s.astype(jnp.float32).reshape(n_layers, num_blocks, 1, kvh)
+            for s in (k_scale, v_scale))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, n_tables),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, h, d), lambda bi, j, *_: (bi, 0, 0)),
@@ -252,52 +270,46 @@ def _shard_map(f, mesh, in_specs, out_specs):
                          out_specs=out_specs, check_vma=False)
 
 
-def paged_decode_attention_sharded(q, k_pool, v_pool, tables, kv_len, *,
-                                   mesh, axis: str = "tensor",
+def paged_decode_attention_sharded(q, k_pool, v_pool, layer, tables, kv_len,
+                                   *, mesh, axis: str = "tensor",
                                    interpret: bool = False,
                                    k_scale=None, v_scale=None):
     """``paged_decode_attention`` partitioned over the mesh's heads/KV
     axis with shard_map: q [B, H, D] shards on H, pools
-    [NB, bs, KV_H, D] on KV_H, block tables and lengths replicated —
-    each shard's table row names the same pool blocks, but only the
-    local kv-head slice of them is resident per chip. No collectives:
-    softmax state is private to each query-head group.
+    [L, NB, bs, KV_H, D] on KV_H, the layer, block tables and lengths
+    replicated — each shard's table row names the same pool blocks, but
+    only the local kv-head slice of them is resident per chip. No
+    collectives: softmax state is private to each query-head group.
 
-    Falls back to the unwrapped kernel when the mesh doesn't shard
-    ``axis`` (a 1-sized axis needs no partitioning); raises for
+    Falls back to the unwrapped kernel when there is no mesh or it doesn't
+    shard ``axis`` (a 1-sized axis needs no partitioning); raises for
     topologies the kernel cannot shard (see shard_unsupported_reason) —
     callers decide the gather downgrade, not this function.
 
-    Quantized pools: the [NB, KV_H] scale tables shard on their kv-head
-    dim with the pools (``P(None, axis)``) — each shard dequants its
-    local kv-head slice with its local scales, still zero collectives."""
-    kvh = k_pool.shape[2]
+    Quantized pools: the [L, NB, KV_H] scale tables shard on their
+    kv-head dim with the pools (``P(None, None, axis)``) — each shard
+    dequants its local kv-head slice with its local scales, still zero
+    collectives."""
+    kvh = k_pool.shape[3]
     reason = shard_unsupported_reason(mesh, kvh, axis)
     if reason is not None:
         raise ValueError(f"cannot shard paged attention: {reason}")
     if mesh is None or int(dict(mesh.shape).get(axis, 1)) <= 1:
-        return paged_decode_attention(q, k_pool, v_pool, tables, kv_len,
-                                      interpret=interpret,
+        return paged_decode_attention(q, k_pool, v_pool, layer, tables,
+                                      kv_len, interpret=interpret,
                                       k_scale=k_scale, v_scale=v_scale)
-    if k_scale is None:
-        kern = functools.partial(paged_decode_attention,
-                                 interpret=interpret)
-        wrapped = _shard_map(
-            kern, mesh,
-            in_specs=(P(None, axis, None), P(None, None, axis, None),
-                      P(None, None, axis, None), P(None, None), P(None)),
-            out_specs=P(None, axis, None))
-        return wrapped(q, k_pool, v_pool, tables, kv_len)
+    pool_spec = P(None, None, None, axis, None)
+    in_specs = (P(None, axis, None), pool_spec, pool_spec, P(),
+                P(None, None), P(None))
+    args = (q, k_pool, v_pool, jnp.asarray(layer, jnp.int32), tables, kv_len)
+    if k_scale is not None:
+        in_specs += (P(None, None, axis),) * 2
+        args += (k_scale, v_scale)
 
-    def kern(qs, kp, vp, t, kl, ks, vs):
-        return paged_decode_attention(qs, kp, vp, t, kl,
+    def kern(qs, kp, vp, lay, t, kl, ks=None, vs=None):
+        return paged_decode_attention(qs, kp, vp, lay, t, kl,
                                       interpret=interpret,
                                       k_scale=ks, v_scale=vs)
 
-    wrapped = _shard_map(
-        kern, mesh,
-        in_specs=(P(None, axis, None), P(None, None, axis, None),
-                  P(None, None, axis, None), P(None, None), P(None),
-                  P(None, axis), P(None, axis)),
-        out_specs=P(None, axis, None))
-    return wrapped(q, k_pool, v_pool, tables, kv_len, k_scale, v_scale)
+    return _shard_map(kern, mesh, in_specs=in_specs,
+                      out_specs=P(None, axis, None))(*args)

@@ -364,6 +364,9 @@ class LLMModel(Model):
             out["load_seconds"] = self.load_seconds
             out["precompile_seconds"] = self.precompile_seconds
             out["depot_outcome"] = eng.depot_outcome or "none"
+            if eng.decode_pool_shaped_ops is not None:
+                # 0 = the decode program updates the KV pool in place
+                out["decode_pool_shaped_ops"] = eng.decode_pool_shaped_ops
             if self._depot_stats is not None:
                 out["depot"] = self._depot_stats.snapshot()
         return out

@@ -439,6 +439,10 @@ class LLMEngine:
         # its own depot key scope
         self._compiled_prefill_chunk = None
         self.depot_outcome: Optional[str] = None
+        # pool-sized operations in the precompiled decode program
+        # (paged_kv.pool_shaped_ops): 0 for a pool updated in place, None
+        # until precompile() has read the executable's text
+        self.decode_pool_shaped_ops: Optional[int] = None
         # speculative verify: greedy target chain + chosen-token logprobs
         # for a [B, S] candidate batch in ONE dispatch. S is pow2-padded
         # by the caller, so the compile count is log2(spec_k+1) — the
@@ -563,7 +567,30 @@ class LLMEngine:
             stage=("serving-decode-tier" if tier == "decode" else None),
             extra=("serving-decode", self.quant.tag()))
         self.depot_outcome = outcome
+        self._note_pool_shaped_ops()
         return outcome
+
+    def _note_pool_shaped_ops(self) -> None:
+        """Count and log the precompiled decode program's pool-sized
+        operations, where the executable gives its text (one loaded from
+        the depot may not). The lazily jitted variants are not read: that
+        would compile them a second time."""
+        from kubeflow_tpu.serving.paged_kv import pool_shaped_ops
+
+        try:
+            text = self._compiled_decode.as_text()
+        except Exception:
+            text = None
+        if not text:
+            return
+        shapes = [self.cache[key].sharding.shard_shape(self.cache[key].shape)
+                  for key in ("k", "v")]
+        found = pool_shaped_ops(text, shapes)
+        self.decode_pool_shaped_ops = len(found)
+        log = logger.warning if found else logger.info
+        log("decode program: %d pool-shaped operations%s (a pool updated "
+            "in place has none)", len(found),
+            "".join(f"; {name} {op} {rtype}" for name, op, rtype in found))
 
     def validate_prompt(self, prompt: Sequence[int],
                         sampling: Optional[SamplingParams] = None) -> None:

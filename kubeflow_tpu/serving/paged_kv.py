@@ -15,27 +15,41 @@ host-managed numpy (the scheduler allocates blocks at admission — enough
 for prompt + max_tokens, so decode can never run out mid-flight) and ride
 into the jitted step as a plain traced argument.
 
-Decode attention has two execution paths, selected by
+The pool is updated IN PLACE. Every program that writes it (decode,
+chunked prefill, speculative verify) walks the layers with the layer index
+and that layer's weights as the scanned part and the whole pools in the
+scan's CARRY (``_scan_layers``): a step's rows land with a scatter at
+``(layer, block, offset)`` and every reader addresses the pool by
+``(layer, block)``. No program slices a layer's pool out (``xs``) or stacks
+updated layers back (``ys``) — XLA lowers those to copies of the whole pool,
+several a step — and with the cache donated the carry aliases through the
+engine's chunk loop too, so a step costs O(rows written + blocks read), not
+O(pool). ``pool_shaped_ops`` reads a compiled program's text and lists what
+would break that.
+
+Decode attention has two execution paths over that carry, selected by
 ``paged_decode_step(..., kernel=)``:
 
 - ``"gather"`` — materialize each slot's logical [max_seq] view
-  (``k_pool[tables]``) and run dense GQA attention over it. Per-step HBM
-  traffic scales with the ARENA (r5 ablation: view cost follows max_seq,
-  not live length). Retained as the reference oracle and the only path
-  that XLA can auto-partition (TP-sharded pools).
+  (``k_pool[layer, tables]``) and run dense GQA attention over it. Per-step
+  HBM traffic scales with the ARENA (r5 ablation: view cost follows
+  max_seq, not live length). Retained as the reference oracle, the CPU
+  default, and the path for meshes the kernel cannot be sharded over.
 - ``"pallas"`` — the first-party block-resident kernel
-  (``ops/pallas_paged_attention.py``): per slot, stream only the live
-  blocks named by its table row through VMEM and run grouped-query
-  attention with an online-softmax accumulator in-kernel. HBM traffic is
-  O(live tokens); no view is ever materialized. On CPU the SAME kernel
-  logic runs under the Pallas interpreter (``interpret=True``), so tier-1
-  tests exercise the exact code path that compiles for TPU.
+  (``ops/pallas_paged_attention.py``), given the whole pool and the layer:
+  per slot, stream only the live blocks named by its table row through
+  VMEM and run grouped-query attention with an online-softmax accumulator
+  in-kernel. HBM traffic is O(live tokens); no view is ever materialized.
+  On CPU the SAME kernel logic runs under the Pallas interpreter
+  (``interpret=True``), so tier-1 tests exercise the exact code path that
+  compiles for TPU.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import re
 from typing import Optional
 
 import jax
@@ -462,6 +476,75 @@ def scatter_kv_blocks(cache: dict, ids, blocks: dict) -> dict:
     return _scatter_pools(cache, jnp.asarray(idx), pay, keys)
 
 
+# ------------------------------------------- the program's own text ----
+# What says that the pool is updated in place is the compiled program: an
+# operation whose result is as large as the pool (or as one layer of it)
+# moves that many bytes every step, whatever the requests need.
+
+# "%name = type[dims]{layout} opcode(%operand, ...), attributes"; results
+# of tuple type (while, tuple) match nothing and are no buffers of their own
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+\[([\d,]*)\]\S*) ([\w\-]+)\(([^)]*)\)(.*)$")
+# names for, or views of, a buffer that exists
+_HLO_NO_DATA = ("parameter", "get-tuple-element", "bitcast")
+_HLO_WRITES = ("scatter", "dynamic-update-slice")
+
+
+def pool_shaped_ops(hlo_text: str, pool_shapes) -> list:
+    """The instructions of a compiled program (``compiled.as_text()``)
+    that produce a buffer as large as a KV pool or as one layer of it:
+    [(name, opcode, result type)], empty for a program that updates the
+    pool in place. ``pool_shapes``: the pools' shapes as one device holds
+    them, layers first, e.g. ``[cache["k"].shape]``.
+
+    Not listed: parameters, tuples and their elements, ``while``,
+    ``bitcast`` (names for a buffer that exists); a ``scatter`` or
+    ``dynamic-update-slice`` (or a fusion around one) whose ONLY
+    pool-sized operand is the buffer it updates — XLA runs those in
+    place, and one that takes a second pool-sized operand is writing a
+    layer's pool into a stacked copy; what is inside a fusion (it never
+    reaches memory). A kernel's custom call reads the pool as an operand
+    and returns a step's rows, so it is never pool-shaped either. Sizes
+    are compared as element counts, whatever the dimensions are merged
+    or split into."""
+    sizes = set()
+    for shape in pool_shapes:
+        n = int(np.prod(shape))
+        sizes.update((n, n // int(shape[0])))
+    # every pool-sized instruction by computation; the fusions' bodies
+    comps: dict[str, list] = {}
+    fused: dict[str, str] = {}              # fusion instruction -> its body
+    pool_sized, cur = set(), None
+    for line in hlo_text.splitlines():
+        if line.rstrip().endswith("{") and " = " not in line:
+            cur = line.removeprefix("ENTRY ").split()[0].lstrip("%")
+            comps[cur] = []
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None or cur is None:
+            continue
+        name, rtype, dims, opcode, operands, attrs = m.groups()
+        if opcode == "fusion":
+            fused[name] = re.search(r"calls=%([\w.\-]+)", attrs).group(1)
+        if int(np.prod([int(d) for d in dims.split(",") if d])) in sizes:
+            pool_sized.add(name)
+            comps[cur].append(
+                (name, rtype, opcode, re.findall(r"%([\w.\-]+)", operands)))
+    bodies = set(fused.values())
+    found = []
+    for cname, body in comps.items():
+        if cname in bodies:
+            continue
+        for name, rtype, opcode, operands in body:
+            if opcode in _HLO_NO_DATA:
+                continue
+            writes = opcode in _HLO_WRITES or any(
+                i[2] in _HLO_WRITES for i in comps.get(fused.get(name), ()))
+            if writes and sum(o in pool_sized for o in operands) == 1:
+                continue
+            found.append((name, opcode, rtype))
+    return found
+
+
 # ------------------------------------------------------------ jitted bodies
 
 def _layer_qkv(lp, x, positions, cfg, inv_freq):
@@ -520,15 +603,18 @@ def _kv_qmax(store_dtype) -> float:
     return 127.0 if jnp.issubdtype(store_dtype, jnp.integer) else 448.0
 
 
-def quant_scatter_rows(pool, scale, blk, off, rows):
+def quant_scatter_rows(pool, scale, layer, blk, off, rows):
     """Quantize-on-write for the per-step KV scatters (decode, chunked
-    prefill, spec verify): write ``rows`` into the quantized ``pool`` at
-    (blk, off) under the per-block per-kv-head ``scale``, growing scales
+    prefill, spec verify): write ``rows`` into layer ``layer`` of the
+    quantized ``pool`` [L, NB, bs, KV, D] at (blk, off) under the
+    per-block per-kv-head ``scale`` [L, NB, KV], growing scales
     monotonically (scatter-max) and requantizing each touched block's
     resident rows when its scale grows — so earlier rows stay decodable
     under the one scale the read path (kernel and oracle alike) applies.
     When the scale does NOT grow the requant ratio is exactly 1.0 and
-    int8 content round-trips unchanged.
+    int8 content round-trips unchanged. Both arrays are updated by
+    scatters at ``(layer, blk)``: in a loop that carries them nothing
+    pool-sized is copied.
 
     blk/off: int32, any common shape; rows: [..., KV, D]. Duplicate blk
     entries (verify writing several rows of one slot's block) are
@@ -542,28 +628,79 @@ def quant_scatter_rows(pool, scale, blk, off, rows):
     rows = rows.reshape(blk.shape[0], *rows.shape[-2:]).astype(jnp.float32)
     qmax = _kv_qmax(pool.dtype)
     amax = jnp.max(jnp.abs(rows), axis=-1)               # [N, KV]
-    old = scale[blk]                                     # [N, KV]
-    scale = scale.at[blk].max(amax / qmax)
-    new = scale[blk]
+    old = scale[layer, blk]                              # [N, KV]
+    scale = scale.at[layer, blk].max(amax / qmax)
+    new = scale[layer, blk]
     safe = jnp.maximum(new, 1e-30)
     ratio = jnp.where(new > 0, old / safe, 0.0)          # <= 1.0 always
-    resident = pool[blk].astype(jnp.float32) * ratio[:, None, :, None]
-    pool = pool.at[blk].set(_kv_store(resident, pool.dtype))
+    resident = (pool[layer, blk].astype(jnp.float32)
+                * ratio[:, None, :, None])
+    pool = pool.at[layer, blk].set(_kv_store(resident, pool.dtype))
     q = jnp.where(new[:, :, None] > 0, rows / safe[:, :, None], 0.0)
-    pool = pool.at[blk, off].set(_kv_store(q, pool.dtype))
+    pool = pool.at[layer, blk, off].set(_kv_store(q, pool.dtype))
     return pool, scale
 
 
-def dequant_gather_view(pool, scale, tables, cfg):
-    """Slot-logical [B, T, KV, D] view of a QUANTIZED pool: gather the
-    table's blocks, upcast, multiply each block's per-kv-head scale,
-    cast to the compute dtype — element-for-element the pipeline the
-    Pallas kernel fuses into its inner loop, which is what keeps the
-    kernel-vs-oracle parity tests exact under quantization."""
+def dequant_gather_view(pool, scale, layer, tables, cfg):
+    """Slot-logical [B, T, KV, D] view of layer ``layer`` of a QUANTIZED
+    pool: gather the table's blocks, upcast, multiply each block's
+    per-kv-head scale, cast to the compute dtype — element-for-element
+    the pipeline the Pallas kernel fuses into its inner loop, which is
+    what keeps the kernel-vs-oracle parity tests exact under
+    quantization."""
     b = tables.shape[0]
-    v = (pool[tables].astype(jnp.float32)
-         * scale[tables][:, :, None, :, None]).astype(cfg.dtype)
-    return v.reshape(b, -1, *pool.shape[2:])
+    v = (pool[layer, tables].astype(jnp.float32)
+         * scale[layer, tables][:, :, None, :, None]).astype(cfg.dtype)
+    return v.reshape(b, -1, *pool.shape[3:])
+
+
+def _scatter_kv_rows(pools, layer, blk, off, k, v):
+    """This step's K and V rows [..., KV, D] into layer ``layer`` of the
+    carried pools at (blk, off); quantize-on-write where the pool is
+    quantized (``k_scale`` present). Returns the updated pools dict."""
+    if "k_scale" in pools:
+        k_pool, k_sc = quant_scatter_rows(pools["k"], pools["k_scale"],
+                                          layer, blk, off, k)
+        v_pool, v_sc = quant_scatter_rows(pools["v"], pools["v_scale"],
+                                          layer, blk, off, v)
+        return {"k": k_pool, "v": v_pool, "k_scale": k_sc, "v_scale": v_sc}
+    return {"k": pools["k"].at[layer, blk, off].set(
+                k.astype(pools["k"].dtype)),
+            "v": pools["v"].at[layer, blk, off].set(
+                v.astype(pools["v"].dtype))}
+
+
+def _gather_views(pools, layer, tables, cfg):
+    """Slot-logical K and V views [B, T, KV, D] of layer ``layer``: block
+    j of a table row holds logical positions [j*bs, (j+1)*bs) — table
+    order IS sequence order. A quantized pool dequants on the way (the
+    quantized gather oracle)."""
+    if "k_scale" in pools:
+        return (dequant_gather_view(pools["k"], pools["k_scale"], layer,
+                                    tables, cfg),
+                dequant_gather_view(pools["v"], pools["v_scale"], layer,
+                                    tables, cfg))
+    b = tables.shape[0]
+    return tuple(pools[key][layer, tables].reshape(
+        b, -1, *pools[key].shape[3:]) for key in ("k", "v"))
+
+
+def _scan_layers(params, x, cache, layer_fn):
+    """Run ``layer_fn(lp, x, pools, layer) -> (x, pools)`` over the layers
+    with the layer index and the layer's weights scanned and the pools
+    (``k``, ``v`` and the scale tables of a quantized pool) in the CARRY,
+    so each layer's rows are scattered into the one buffer that came in.
+    Returns (x, pools)."""
+    pools = {key: cache[key] for key in _pool_keys(cache)}
+
+    def body(carry, xs):
+        layer, lp = xs
+        return layer_fn(lp, *carry, layer), None
+
+    (x, pools), _ = jax.lax.scan(
+        body, (x, pools),
+        (jnp.arange(cache["k"].shape[0]), params["layers"]))
+    return x, pools
 
 
 def paged_insert_batch(cache, k_new, v_new, blk_ids, lengths, slots):
@@ -670,14 +807,15 @@ def resolve_decode_kernel(kernel: str, mesh=None,
 def paged_decode_step(params, token, cfg: llama.LlamaConfig, cache, tables,
                       kernel: str = "gather", mesh=None):
     """One decode step over the paged pool. token: [B] int32; tables:
-    [B, max_blocks_per_seq] int32 -> (logits [B, V], cache). ``kernel``
-    picks the attention path (module docstring): "gather" | "pallas" |
-    "auto"; with ``mesh`` the pallas path runs shard_map'd over the
-    heads/KV tensor axis (per-shard pool blocks, replicated tables)."""
+    [B, max_blocks_per_seq] int32 -> (logits [B, V], cache). The pools
+    ride the layer loop as a carry and are updated in place (module
+    docstring): donate ``cache`` and nothing pool-sized moves. ``kernel``
+    picks the attention path: "gather" | "pallas" | "auto"; with ``mesh``
+    the pallas path runs shard_map'd over the heads/KV tensor axis
+    (per-shard pool blocks, replicated tables)."""
     kernel, _ = resolve_decode_kernel(kernel, mesh=mesh,
                                       n_kv_heads=cfg.n_kv_heads)
     interpret = jax.default_backend() == "cpu"
-    quantized = "k_scale" in cache
     b = token.shape[0]
     bs = cache["k"].shape[2]
     pos = cache["len"]                                   # [B]
@@ -692,72 +830,37 @@ def paged_decode_step(params, token, cfg: llama.LlamaConfig, cache, tables,
     blk = tables[batch, pos // bs]                       # [B] dest block
     off = pos % bs                                       # [B] row in block
 
-    def block_fn(x, xs):
-        if quantized:
-            lp, k_pool, v_pool, k_sc, v_sc = xs
-        else:
-            lp, k_pool, v_pool = xs                      # [NB, bs, KV, D]
-            k_sc = v_sc = None
+    def layer_fn(lp, x, pools, layer):
         q, k, v = _layer_qkv(lp, x, positions, cfg, inv_freq)
         # scatter this step's KV row into each slot's current block
-        if quantized:
-            k_pool, k_sc = quant_scatter_rows(k_pool, k_sc, blk, off,
-                                              k[:, 0])
-            v_pool, v_sc = quant_scatter_rows(v_pool, v_sc, blk, off,
-                                              v[:, 0])
-        else:
-            k_pool = k_pool.at[blk, off].set(k[:, 0].astype(k_pool.dtype))
-            v_pool = v_pool.at[blk, off].set(v[:, 0].astype(v_pool.dtype))
+        pools = _scatter_kv_rows(pools, layer, blk, off, k[:, 0], v[:, 0])
         if kernel == "pallas":
-            # block-resident kernel: per slot, only the live blocks named
-            # by its table row move HBM->VMEM; no [max_seq] view exists.
-            # Under a mesh the call shard_maps over the heads/KV axis —
-            # per-shard pool blocks, replicated tables, no collectives
-            # (quantized scale tables shard on kv-heads with the pool).
+            # block-resident kernel over the carried pool, addressed by
+            # (layer, block): per slot, only the live blocks named by its
+            # table row move HBM->VMEM; no [max_seq] view and no slice of
+            # the pool exists. Under a mesh the call shard_maps over the
+            # heads/KV axis — per-shard pool blocks, replicated tables, no
+            # collectives (quantized scale tables shard on kv-heads with
+            # the pool).
             from kubeflow_tpu.ops.pallas_paged_attention import (
-                paged_decode_attention, paged_decode_attention_sharded,
+                paged_decode_attention_sharded,
             )
 
-            if mesh is not None:
-                o = paged_decode_attention_sharded(
-                    q[:, 0], k_pool, v_pool, tables, pos + 1,
-                    mesh=mesh, interpret=interpret,
-                    k_scale=k_sc, v_scale=v_sc)[:, None]
-            else:
-                o = paged_decode_attention(
-                    q[:, 0], k_pool, v_pool, tables, pos + 1,
-                    interpret=interpret,
-                    k_scale=k_sc, v_scale=v_sc)[:, None]
-        elif quantized:
-            # the quantized gather oracle: dequant view, then the same
-            # dense attention — per-element identical to the kernel path
-            k_view = dequant_gather_view(k_pool, k_sc, tables, cfg)
-            v_view = dequant_gather_view(v_pool, v_sc, tables, cfg)
-            o = decode_attention(q, k_view, v_view, pos + 1)
+            o = paged_decode_attention_sharded(
+                q[:, 0], pools["k"], pools["v"], layer, tables, pos + 1,
+                mesh=mesh, interpret=interpret,
+                k_scale=pools.get("k_scale"),
+                v_scale=pools.get("v_scale"))[:, None]
         else:
-            # gather each slot's logical view: block j of slot b holds
-            # logical positions [j*bs, (j+1)*bs) — table order IS
-            # sequence order
-            k_view = k_pool[tables].reshape(b, -1, *k_pool.shape[2:])
-            v_view = v_pool[tables].reshape(b, -1, *v_pool.shape[2:])
+            k_view, v_view = _gather_views(pools, layer, tables, cfg)
             o = decode_attention(q, k_view, v_view, pos + 1)
         # idle slots hold len 0: keep their garbage rows out of MoE routing
-        out = _layer_out(lp, x, o, cfg, token_mask=(pos > 0)[:, None])
-        if quantized:
-            return out, (k_pool, v_pool, k_sc, v_sc)
-        return out, (k_pool, v_pool)
+        return _layer_out(lp, x, o, cfg,
+                          token_mask=(pos > 0)[:, None]), pools
 
-    if quantized:
-        x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-            block_fn, x, (params["layers"], cache["k"], cache["v"],
-                          cache["k_scale"], cache["v_scale"]))
-        logits = _lm_head(params, x[:, 0], cfg)
-        return logits, {"k": new_k, "v": new_v, "k_scale": new_ks,
-                        "v_scale": new_vs, "len": cache["len"] + 1}
-    x, (new_k, new_v) = jax.lax.scan(
-        block_fn, x, (params["layers"], cache["k"], cache["v"]))
+    x, pools = _scan_layers(params, x, cache, layer_fn)
     logits = _lm_head(params, x[:, 0], cfg)
-    return logits, {"k": new_k, "v": new_v, "len": cache["len"] + 1}
+    return logits, {**pools, "len": cache["len"] + 1}
 
 
 def paged_prefill_chunk(params, tokens, cfg: llama.LlamaConfig, cache,
@@ -799,47 +902,22 @@ def paged_prefill_chunk(params, tokens, cfg: llama.LlamaConfig, cache,
     off = pos % bs
     positions = pos[None, :]
     x = llama.embed_tokens(params, tokens, cfg)
-    quantized = "k_scale" in cache
 
     from kubeflow_tpu.ops.attention import _xla_attention
 
-    def block_fn(x, xs):
-        if quantized:
-            lp, k_pool, v_pool, k_sc, v_sc = xs
-        else:
-            lp, k_pool, v_pool = xs
-            k_sc = v_sc = None
+    def layer_fn(lp, x, pools, layer):
         q, k, v = _layer_qkv(lp, x, positions, cfg, inv_freq)
-        if quantized:
-            k_pool, k_sc = quant_scatter_rows(k_pool, k_sc, blk, off, k[0])
-            v_pool, v_sc = quant_scatter_rows(v_pool, v_sc, blk, off, v[0])
-            k_view = dequant_gather_view(k_pool, k_sc, tables[slot][None],
-                                         cfg)
-            v_view = dequant_gather_view(v_pool, v_sc, tables[slot][None],
-                                         cfg)
-        else:
-            k_pool = k_pool.at[blk, off].set(k[0].astype(k_pool.dtype))
-            v_pool = v_pool.at[blk, off].set(v[0].astype(v_pool.dtype))
-            k_view = k_pool[tables[slot]].reshape(1, -1, *k_pool.shape[2:])
-            v_view = v_pool[tables[slot]].reshape(1, -1, *v_pool.shape[2:])
+        pools = _scatter_kv_rows(pools, layer, blk, off, k[0], v[0])
+        k_view, v_view = _gather_views(pools, layer, tables[slot][None],
+                                       cfg)
         # the shared GQA causal kernel with traced query offset: row i
         # (absolute position offset+i) attends kv rows <= offset+i
         o = _xla_attention(q, k_view, v_view, causal=True, q_offset=offset)
-        out = _layer_out(lp, x, o, cfg, token_mask=valid[None, :])
-        if quantized:
-            return out, (k_pool, v_pool, k_sc, v_sc)
-        return out, (k_pool, v_pool)
+        return _layer_out(lp, x, o, cfg, token_mask=valid[None, :]), pools
 
     last_row = jnp.clip(length - offset - 1, 0, c - 1)
-    if quantized:
-        x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-            block_fn, x, (params["layers"], cache["k"], cache["v"],
-                          cache["k_scale"], cache["v_scale"]))
-        return x[:, last_row], {"k": new_k, "v": new_v, "k_scale": new_ks,
-                                "v_scale": new_vs, "len": cache["len"]}
-    x, (new_k, new_v) = jax.lax.scan(
-        block_fn, x, (params["layers"], cache["k"], cache["v"]))
-    return x[:, last_row], {"k": new_k, "v": new_v, "len": cache["len"]}
+    x, pools = _scan_layers(params, x, cache, layer_fn)
+    return x[:, last_row], {**pools, "len": cache["len"]}
 
 
 def paged_verify_step(params, tokens, cfg: llama.LlamaConfig, cache,
@@ -887,50 +965,23 @@ def paged_verify_step(params, tokens, cfg: llama.LlamaConfig, cache,
         0)
     off = pos % bs
     x = llama.embed_tokens(params, tokens, cfg)            # [B, S, D]
-    quantized = "k_scale" in cache
 
     from kubeflow_tpu.ops.attention import _xla_attention
 
-    def block_fn(x, xs):
-        if quantized:
-            lp, k_pool, v_pool, k_sc, v_sc = xs
-        else:
-            lp, k_pool, v_pool = xs
-            k_sc = v_sc = None
+    def layer_fn(lp, x, pools, layer):
         q, k, v = _layer_qkv(lp, x, pos, cfg, inv_freq)
-        if quantized:
-            # duplicate blk entries (several rows of one slot's block in
-            # a single verify) are safe: quant_scatter_rows folds their
-            # amaxes via scatter-max before any content write
-            k_pool, k_sc = quant_scatter_rows(k_pool, k_sc, blk, off, k)
-            v_pool, v_sc = quant_scatter_rows(v_pool, v_sc, blk, off, v)
-            k_view = dequant_gather_view(k_pool, k_sc, tables, cfg)
-            v_view = dequant_gather_view(v_pool, v_sc, tables, cfg)
-        else:
-            k_pool = k_pool.at[blk, off].set(k.astype(k_pool.dtype))
-            v_pool = v_pool.at[blk, off].set(v.astype(v_pool.dtype))
-            k_view = k_pool[tables].reshape(b, -1, *k_pool.shape[2:])
-            v_view = v_pool[tables].reshape(b, -1, *v_pool.shape[2:])
+        # duplicate blk entries (several rows of one slot's block in a
+        # single verify) are safe in a quantized pool: quant_scatter_rows
+        # folds their amaxes via scatter-max before any content write
+        pools = _scatter_kv_rows(pools, layer, blk, off, k, v)
+        k_view, v_view = _gather_views(pools, layer, tables, cfg)
         # per-slot query offsets: row s (position start[b]+s) attends kv
         # rows <= start[b]+s — this step's own earlier rows included,
         # every stale/rejected row beyond them masked
         o = _xla_attention(q, k_view, v_view, causal=True, q_offset=start)
-        out = _layer_out(lp, x, o, cfg, token_mask=valid)
-        if quantized:
-            return out, (k_pool, v_pool, k_sc, v_sc)
-        return out, (k_pool, v_pool)
+        return _layer_out(lp, x, o, cfg, token_mask=valid), pools
 
-    if quantized:
-        x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-            block_fn, x, (params["layers"], cache["k"], cache["v"],
-                          cache["k_scale"], cache["v_scale"]))
-        d = x.shape[-1]
-        logits = _lm_head(params, x.reshape(b * s, d),
-                          cfg).reshape(b, s, -1)
-        return logits, {"k": new_k, "v": new_v, "k_scale": new_ks,
-                        "v_scale": new_vs, "len": cache["len"]}
-    x, (new_k, new_v) = jax.lax.scan(
-        block_fn, x, (params["layers"], cache["k"], cache["v"]))
-    d = x.shape[-1]
-    logits = _lm_head(params, x.reshape(b * s, d), cfg).reshape(b, s, -1)
-    return logits, {"k": new_k, "v": new_v, "len": cache["len"]}
+    x, pools = _scan_layers(params, x, cache, layer_fn)
+    logits = _lm_head(params, x.reshape(b * s, x.shape[-1]),
+                      cfg).reshape(b, s, -1)
+    return logits, {**pools, "len": cache["len"]}

@@ -21,14 +21,15 @@ from kubeflow_tpu.serving import paged_kv
 
 
 def _pool_case(key, b, h, kvh, d, bs, nbp, kv_len, dtype=jnp.float32,
-               num_blocks=None):
-    """Random q/pools plus a block table assigning each slot ``nlive``
-    distinct (permuted) pool blocks for its ``kv_len`` rows."""
+               num_blocks=None, layers=1):
+    """Random q/pools [layers, nb, bs, kvh, d] (every layer its own
+    contents) plus a block table assigning each slot ``nlive`` distinct
+    (permuted) pool blocks for its ``kv_len`` rows."""
     rng = np.random.default_rng(int(jax.random.key_data(key)[-1]))
     nb = num_blocks or (b * nbp + 1)
     q = jnp.asarray(rng.standard_normal((b, h, d)), dtype)
-    kp = jnp.asarray(rng.standard_normal((nb, bs, kvh, d)), dtype)
-    vp = jnp.asarray(rng.standard_normal((nb, bs, kvh, d)), dtype)
+    kp = jnp.asarray(rng.standard_normal((layers, nb, bs, kvh, d)), dtype)
+    vp = jnp.asarray(rng.standard_normal((layers, nb, bs, kvh, d)), dtype)
     tables = np.zeros((b, nbp), np.int32)
     perm = rng.permutation(np.arange(1, nb))
     i = 0
@@ -39,19 +40,24 @@ def _pool_case(key, b, h, kvh, d, bs, nbp, kv_len, dtype=jnp.float32,
     return q, kp, vp, jnp.asarray(tables), jnp.asarray(kv_len, jnp.int32)
 
 
-def _gather_ref(q, kp, vp, tables, kv_len):
-    k_view = kp[tables].reshape(q.shape[0], -1, *kp.shape[2:])
-    v_view = vp[tables].reshape(q.shape[0], -1, *vp.shape[2:])
+def _gather_ref(q, kp, vp, layer, tables, kv_len):
+    k_view = kp[layer][tables].reshape(q.shape[0], -1, *kp.shape[3:])
+    v_view = vp[layer][tables].reshape(q.shape[0], -1, *vp.shape[3:])
     return decode_attention(q[:, None], k_view, v_view, kv_len)[:, 0]
 
 
-def _assert_parity(case, rtol=2e-5, atol=2e-5):
+def _assert_parity(case, layer=0, rtol=2e-5, atol=2e-5):
     q, kp, vp, tables, kv_len = case
-    out = paged_decode_attention(q, kp, vp, tables, kv_len, interpret=True)
-    ref = _gather_ref(q, kp, vp, tables, kv_len)
+    out = paged_decode_attention(q, kp, vp, layer, tables, kv_len,
+                                 interpret=True)
+    ref = _gather_ref(q, kp, vp, layer, tables, kv_len)
+    live = np.asarray(kv_len) > 0
+    # idle (len 0) slots are never read downstream (the engine masks
+    # them); there only defined-ness matters
+    assert bool(jnp.isfinite(out).all())
     np.testing.assert_allclose(
-        out.astype(jnp.float32), ref.astype(jnp.float32),
-        rtol=rtol, atol=atol)
+        np.asarray(out.astype(jnp.float32))[live],
+        np.asarray(ref.astype(jnp.float32))[live], rtol=rtol, atol=atol)
 
 
 def test_head_dim_64_groups_2():
@@ -78,9 +84,9 @@ def test_ragged_lengths_and_idle_slots():
     case = _pool_case(jax.random.key(2), b=8, h=4, kvh=2, d=32,
                       bs=8, nbp=3, kv_len=kv_len)
     q, kp, vp, tables, kv_len_j = case
-    out = paged_decode_attention(q, kp, vp, tables, kv_len_j,
+    out = paged_decode_attention(q, kp, vp, 0, tables, kv_len_j,
                                  interpret=True)
-    ref = _gather_ref(q, kp, vp, tables, kv_len_j)
+    ref = _gather_ref(q, kp, vp, 0, tables, kv_len_j)
     assert bool(jnp.isfinite(out).all())
     # live slots must match the oracle exactly; idle (len 0) slots are
     # never read downstream (the engine masks them), only defined-ness
@@ -105,12 +111,62 @@ def test_bf16_pool():
     q, kp, vp, tables, kv_len = _pool_case(
         jax.random.key(4), b=3, h=4, kvh=2, d=64, bs=16, nbp=2,
         kv_len=[9, 16, 30], dtype=jnp.bfloat16)
-    out = paged_decode_attention(q, kp, vp, tables, kv_len, interpret=True)
+    out = paged_decode_attention(q, kp, vp, 0, tables, kv_len,
+                                 interpret=True)
     assert out.dtype == jnp.bfloat16
-    ref = _gather_ref(q, kp, vp, tables, kv_len)
+    ref = _gather_ref(q, kp, vp, 0, tables, kv_len)
     np.testing.assert_allclose(
         out.astype(jnp.float32), ref.astype(jnp.float32),
         rtol=2e-2, atol=2e-2)
+
+
+def _quantize_pool(pool, qmax=127.0, dtype=jnp.int8):
+    """Per-block per-kv-head symmetric quantization of a full-precision
+    [L, NB, bs, KVH, D] pool -> (q pool, scale [L, NB, KVH] f32), the
+    engine pool's own scheme (tests/test_quant.py shares it)."""
+    amax = jnp.max(jnp.abs(pool.astype(jnp.float32)), axis=(2, 4))
+    scale = jnp.maximum(amax / qmax, 1e-30)
+    q = pool.astype(jnp.float32) / scale[:, :, None, :, None]
+    if jnp.issubdtype(dtype, jnp.integer):
+        q = jnp.clip(jnp.round(q), -qmax, qmax)
+    return q.astype(dtype), scale.astype(jnp.float32)
+
+
+def _dequant(pool, scale):
+    return pool.astype(jnp.float32) * scale[:, :, None, :, None]
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("pool_dtype", ["bf16", "int8"])
+def test_layer_addressed_kernel_reads_its_own_layer(pool_dtype, layer):
+    """The kernel is given the WHOLE pool and a layer index. Three layers
+    with different contents, ragged lengths, idle slots, lengths on and
+    across a block boundary: each layer's output is that layer's oracle,
+    and no other's."""
+    kv_len = [0, 1, 7, 8, 9, 24, 0, 13]
+    q, kp, vp, tables, kvl = _pool_case(
+        jax.random.key(20), b=8, h=4, kvh=2, d=32, bs=8, nbp=3,
+        kv_len=kv_len, dtype=jnp.bfloat16, layers=3)
+    scales = {}
+    if pool_dtype == "int8":
+        (kp, ks), (vp, vs) = _quantize_pool(kp), _quantize_pool(vp)
+        scales = dict(k_scale=ks, v_scale=vs)
+        kd = _dequant(kp, ks).astype(q.dtype)
+        vd = _dequant(vp, vs).astype(q.dtype)
+    else:
+        kd, vd = kp, vp
+    out = paged_decode_attention(q, kp, vp, jnp.int32(layer), tables, kvl,
+                                 interpret=True, **scales)
+    live = np.asarray(kv_len) > 0
+    got = np.asarray(out.astype(jnp.float32))[live]
+    assert np.isfinite(np.asarray(out.astype(jnp.float32))).all()
+    for other in range(3):
+        ref = np.asarray(_gather_ref(q, kd, vd, other, tables,
+                                     kvl).astype(jnp.float32))[live]
+        if other == layer:
+            np.testing.assert_allclose(got, ref, rtol=2e-2, atol=2e-2)
+        else:
+            assert np.abs(got - ref).max() > 0.1
 
 
 def test_rejects_bad_shapes():
@@ -118,10 +174,10 @@ def test_rejects_bad_shapes():
         jax.random.key(5), b=2, h=4, kvh=2, d=32, bs=8, nbp=2,
         kv_len=[4, 4])
     with pytest.raises(ValueError, match="multiple"):
-        paged_decode_attention(q[:, :3], kp, vp, tables, kv_len,
+        paged_decode_attention(q[:, :3], kp, vp, 0, tables, kv_len,
                                interpret=True)
     with pytest.raises(ValueError, match="head_dim"):
-        paged_decode_attention(q[..., :16], kp, vp, tables, kv_len,
+        paged_decode_attention(q[..., :16], kp, vp, 0, tables, kv_len,
                                interpret=True)
 
 
@@ -155,6 +211,70 @@ def test_decode_step_block_boundary_crossing():
     np.testing.assert_allclose(np.asarray(cache_g["k"]),
                                np.asarray(cache_p["k"]), rtol=1e-5,
                                atol=1e-5)
+
+
+@pytest.mark.parametrize("quant_kv", ["none", "int8"])
+@pytest.mark.parametrize("kernel", ["gather", "pallas"])
+def test_engine_decode_chunk_equals_single_steps(kernel, quant_kv):
+    """``LLMEngine._decode`` over a chunk of 8 (the pool a carry of both
+    loops, donated) gives the tokens and the pool contents of eight
+    single-step dispatches: one slot crosses a block boundary mid-chunk
+    (5 -> 13, block 8), one is idle."""
+    from kubeflow_tpu.serving.llm import LLMEngine
+    from kubeflow_tpu.serving.scheduler import QuantConfig
+
+    cfg = llama.llama_tiny(dtype=jnp.float32)
+    params = llama.init_params(jax.random.key(0), cfg, dtype=jnp.float32)
+    eng = LLMEngine(params, cfg, max_batch=3, max_seq=32,
+                    prefill_buckets=(8,), kv_block_size=8, kv_num_blocks=13,
+                    kernel=kernel, quant=QuantConfig(kv_dtype=quant_kv))
+    assert eng.paged.reserve(0, 5, 12) is not None
+    assert eng.paged.reserve(2, 3, 12) is not None      # slot 1 stays idle
+    rng = np.random.default_rng(3)
+    cache = dict(eng.cache)
+    for key in ("k", "v"):                  # something resident to attend
+        x = rng.standard_normal(cache[key].shape) * (
+            20 if quant_kv == "int8" else 1)
+        cache[key] = jnp.asarray(x, jnp.float32).astype(cache[key].dtype)
+    if quant_kv == "int8":
+        cache["k_scale"] = jnp.full_like(cache["k_scale"], 0.05)
+        cache["v_scale"] = jnp.full_like(cache["v_scale"], 0.05)
+    cache["len"] = jnp.asarray([5, 0, 3], jnp.int32)
+    b = eng.max_batch
+    args = (jnp.asarray(eng.paged.tables), jnp.asarray([True, False, True]),
+            jnp.zeros((b,), jnp.float32), jnp.zeros((b,), jnp.int32),
+            jnp.ones((b,), jnp.float32))
+    tok0 = jnp.asarray([5, 0, 9], jnp.int32)
+
+    def run(chunk_len, n):
+        c, tok, toks = jax.tree.map(jnp.copy, cache), tok0, []
+        for i in range(n):
+            t, _, tok, c = eng._decode(
+                eng.params, tok, c, *args, jax.random.key(i),
+                greedy_only=True, kernel=eng.kernel, chunk_len=chunk_len)
+            toks.append(np.asarray(t))
+        return np.concatenate(toks), c
+
+    toks8, cache8 = run(8, 1)
+    toks1, cache1 = run(1, 8)
+    live = [0, 2]
+    np.testing.assert_array_equal(toks8[:, live], toks1[:, live])
+    np.testing.assert_array_equal(np.asarray(cache8["len"]), [13, 0, 11])
+    np.testing.assert_array_equal(np.asarray(cache8["len"]),
+                                  np.asarray(cache1["len"]))
+    for key in ("k", "v"):
+        a8 = np.asarray(cache8[key], np.float32)
+        a1 = np.asarray(cache1[key], np.float32)
+        if quant_kv == "int8":          # payloads within one quant step
+            assert np.abs(a8 - a1).max() <= 1
+            np.testing.assert_allclose(
+                np.asarray(cache8[key + "_scale"]),
+                np.asarray(cache1[key + "_scale"]), rtol=1e-5)
+        else:
+            np.testing.assert_allclose(a8, a1, rtol=1e-5, atol=1e-6)
+        # the chunk wrote rows, every layer: the pool is not what came in
+        assert (a8 != np.asarray(cache[key], np.float32)).any(axis=(1, 2, 3,
+                                                                    4)).all()
 
 
 def test_kernel_resolution():
@@ -202,17 +322,15 @@ def test_kernel_resolution_under_mesh():
 
 
 def _sharded_case(key, mesh, b, h, kvh, d, bs, nbp, kv_len,
-                  dtype=jnp.float32):
+                  dtype=jnp.float32, layers=1):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     q, kp, vp, tables, kvl = _pool_case(key, b, h, kvh, d, bs, nbp, kv_len,
-                                        dtype=dtype)
+                                        dtype=dtype, layers=layers)
+    pool_sh = NamedSharding(mesh, P(None, None, None, "tensor", None))
     q = jax.device_put(q, NamedSharding(mesh, P(None, "tensor", None)))
-    kp = jax.device_put(kp, NamedSharding(mesh, P(None, None, "tensor",
-                                                  None)))
-    vp = jax.device_put(vp, NamedSharding(mesh, P(None, None, "tensor",
-                                                  None)))
-    return q, kp, vp, tables, kvl
+    return (q, jax.device_put(kp, pool_sh), jax.device_put(vp, pool_sh),
+            tables, kvl)
 
 
 def test_sharded_kernel_exact_parity_vs_sharded_gather_oracle():
@@ -230,10 +348,10 @@ def test_sharded_kernel_exact_parity_vs_sharded_gather_oracle():
         jax.random.key(6), mesh, b=6, h=8, kvh=4, d=32, bs=8, nbp=3,
         kv_len=kv_len)
     out = jax.jit(lambda *a: paged_decode_attention_sharded(
-        *a, mesh=mesh, interpret=True))(q, kp, vp, tables, kvl)
+        *a, mesh=mesh, interpret=True))(q, kp, vp, 0, tables, kvl)
     # oracle: the SAME sharded arrays through the gather path (XLA
     # auto-partitions it — historically the only mesh-partitionable path)
-    ref = jax.jit(_gather_ref)(q, kp, vp, tables, kvl)
+    ref = jax.jit(_gather_ref)(q, kp, vp, 0, tables, kvl)
     live = np.asarray(kv_len) > 0
     np.testing.assert_allclose(np.asarray(out)[live],
                                np.asarray(ref)[live],
@@ -255,10 +373,38 @@ def test_sharded_kernel_gqa_groups_parity():
         jax.random.key(7), mesh, b=3, h=8, kvh=4, d=64, bs=16, nbp=2,
         kv_len=[9, 16, 30])
     out = jax.jit(lambda *a: paged_decode_attention_sharded(
-        *a, mesh=mesh, interpret=True))(q, kp, vp, tables, kvl)
-    ref = _gather_ref(q, kp, vp, tables, kvl)
+        *a, mesh=mesh, interpret=True))(q, kp, vp, 0, tables, kvl)
+    ref = _gather_ref(q, kp, vp, 0, tables, kvl)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("tensor", [2, 4])
+def test_sharded_kernel_with_the_layer_dimension(tensor):
+    """The shard_map'd kernel over a whole 3-layer pool sharded on its
+    kv-head dim (the 8 host devices tests/conftest.py forces): the layer
+    rides replicated, each layer reads its own contents."""
+    from kubeflow_tpu.ops.pallas_paged_attention import (
+        paged_decode_attention_sharded,
+    )
+    from kubeflow_tpu.parallel import MeshConfig, build_mesh
+
+    mesh = build_mesh(MeshConfig(tensor=tensor))
+    kv_len = [0, 1, 7, 16, 17, 24]
+    q, kp, vp, tables, kvl = _sharded_case(
+        jax.random.key(9), mesh, b=6, h=8, kvh=4, d=32, bs=8, nbp=3,
+        kv_len=kv_len, layers=3)
+    assert kp.sharding.spec[3] == "tensor"
+    fn = jax.jit(lambda *a: paged_decode_attention_sharded(
+        *a, mesh=mesh, interpret=True))
+    live = np.asarray(kv_len) > 0
+    outs = [np.asarray(fn(q, kp, vp, jnp.int32(layer), tables, kvl))
+            for layer in range(3)]
+    for layer, out in enumerate(outs):
+        ref = _gather_ref(q, kp, vp, layer, tables, kvl)
+        np.testing.assert_allclose(out[live], np.asarray(ref)[live],
+                                   rtol=2e-5, atol=2e-5)
+    assert np.abs(outs[0][live] - outs[1][live]).max() > 0.1
 
 
 def test_sharded_kernel_rejects_unshardable_topology():
@@ -274,8 +420,8 @@ def test_sharded_kernel_rejects_unshardable_topology():
         jax.random.key(8), b=2, h=4, kvh=2, d=32, bs=8, nbp=2,
         kv_len=[4, 4])
     with pytest.raises(ValueError, match="n_kv_heads"):
-        paged_decode_attention_sharded(q, kp, vp, tables, kvl, mesh=mesh,
-                                       interpret=True)
+        paged_decode_attention_sharded(q, kp, vp, 0, tables, kvl,
+                                       mesh=mesh, interpret=True)
 
 
 def test_sharded_decode_step_end_to_end_parity():

@@ -4,8 +4,16 @@ The interpret-mode parity suites prove the kernels' logic on the CPU; only
 the TPU compiler says whether a chip accepts their block shapes (the int8
 paged-decode kernel passed every interpret test while its scale BlockSpec
 was one Mosaic refuses). The target is the compile-only v5e topology the
-installed libtpu provides — no hardware — at llama_1b shapes.
+installed libtpu provides — no hardware — at llama_1b shapes and at the
+two serving cells' (benchmarks/traffic), layers cut to 4.
+
+The same compiler says whether the decode program updates the KV pool in
+place: ``paged_kv.pool_shaped_ops`` must find nothing pool-sized in its
+optimized HLO, and must find the ten such instructions of the program as
+it was (``PARENT_HLO``, the lines of PR 26's program that matter).
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,11 +24,17 @@ from kubeflow_tpu.ops.pallas_attention import flash_attention
 from kubeflow_tpu.ops.pallas_paged_attention import (
     paged_decode_attention, paged_decode_attention_sharded,
 )
+from kubeflow_tpu.models import llama
 from kubeflow_tpu.parallel.aot import topology_devices
+from kubeflow_tpu.serving import paged_kv
 
 H, KVH, D = 16, 8, 128                   # llama_1b heads
 B, BS, NBP = 32, 64, 5                   # the engine at max_seq 320
 NB = B * NBP + 1
+L = 4                                    # layers in the pool
+# the serving cells' engines (benchmarks/traffic/*.json)
+CELLS = {"chat": dict(h=32, b=32, nb=545, nbp=40),
+         "offline": dict(h=16, b=36, nb=640, nbp=24)}
 
 
 @pytest.fixture(scope="module")
@@ -35,22 +49,34 @@ def _compile(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _paged_shapes(pool_dtype, b=B, nb=NB, kvh=KVH, h=H):
-    return [((b, h, D), jnp.bfloat16), ((nb, BS, kvh, D), pool_dtype),
-            ((nb, BS, kvh, D), pool_dtype), ((b, NBP), jnp.int32),
-            ((b,), jnp.int32)]
+def _paged_shapes(pool_dtype, b=B, nb=NB, kvh=KVH, h=H, nbp=NBP):
+    """q, the two whole pools, the layer, the tables, the lengths."""
+    return [((b, h, D), jnp.bfloat16), ((L, nb, BS, kvh, D), pool_dtype),
+            ((L, nb, BS, kvh, D), pool_dtype), ((), jnp.int32),
+            ((b, nbp), jnp.int32), ((b,), jnp.int32)]
 
 
 def _scales(nb=NB, kvh=KVH):
-    return [((nb, kvh), jnp.float32)] * 2
+    return [((L, nb, kvh), jnp.float32)] * 2
 
 
 def _positional(kernel, **kw):
     """The kernels take the scales by keyword; lower() wants positionals."""
-    def fn(q, kp, vp, tables, kv_len, *scales):
+    def fn(q, kp, vp, layer, tables, kv_len, *scales):
         ks, vs = scales or (None, None)
-        return kernel(q, kp, vp, tables, kv_len, k_scale=ks, v_scale=vs, **kw)
+        return kernel(q, kp, vp, layer, tables, kv_len, k_scale=ks,
+                      v_scale=vs, **kw)
     return fn
+
+
+def _tensor_shardings(mesh, quantized):
+    rep = NamedSharding(mesh, P())
+    pool = NamedSharding(mesh, P(None, None, None, "tensor", None))
+    sh = [NamedSharding(mesh, P(None, "tensor", None)), pool, pool,
+          rep, rep, rep]
+    if quantized:
+        sh += [NamedSharding(mesh, P(None, None, "tensor"))] * 2
+    return sh
 
 
 def test_flash_fwd_and_bwd(v5e):
@@ -68,7 +94,7 @@ def test_flash_fwd_and_bwd(v5e):
 
 def test_paged_decode_bf16(v5e):
     one = NamedSharding(Mesh(v5e[:1], ("x",)), P())
-    hlo = _compile(paged_decode_attention, [one] * 5,
+    hlo = _compile(paged_decode_attention, [one] * 6,
                    *_paged_shapes(jnp.bfloat16))
     assert "tpu_custom_call" in hlo
 
@@ -76,12 +102,10 @@ def test_paged_decode_bf16(v5e):
 @pytest.mark.parametrize("shape", ["1b", "8b"])
 def test_paged_decode_int8(v5e, shape):
     """The 8B serve shape too: 32/8 heads, a pool of 8 x 8192 tokens."""
-    kw = {} if shape == "1b" else dict(b=8, nb=8 * 128 + 1, h=32)
+    kw = {} if shape == "1b" else dict(b=8, nb=8 * 128 + 1, h=32, nbp=128)
     one = NamedSharding(Mesh(v5e[:1], ("x",)), P())
-    shapes = _paged_shapes(jnp.int8, **kw)
-    if shape == "8b":
-        shapes[3] = ((8, 128), jnp.int32)
-    hlo = _compile(_positional(paged_decode_attention), [one] * 7, *shapes,
+    hlo = _compile(_positional(paged_decode_attention), [one] * 8,
+                   *_paged_shapes(jnp.int8, **kw),
                    *_scales(nb=kw.get("nb", NB)))
     assert "tpu_custom_call" in hlo
 
@@ -89,15 +113,165 @@ def test_paged_decode_int8(v5e, shape):
 @pytest.mark.parametrize("quantized", [False, True])
 def test_paged_decode_sharded_over_four_chips(v5e, quantized):
     mesh = Mesh(v5e, ("tensor",))
-    rep = NamedSharding(mesh, P())
-    sh = [NamedSharding(mesh, P(None, "tensor", None)),
-          NamedSharding(mesh, P(None, None, "tensor", None)),
-          NamedSharding(mesh, P(None, None, "tensor", None)), rep, rep]
     shapes = _paged_shapes(jnp.int8 if quantized else jnp.bfloat16)
     if quantized:
-        sh += [NamedSharding(mesh, P(None, "tensor"))] * 2
         shapes += _scales()
     hlo = _compile(_positional(paged_decode_attention_sharded, mesh=mesh),
-                   sh, *shapes)
+                   _tensor_shardings(mesh, quantized), *shapes)
     assert "tpu_custom_call" in hlo
     assert "all-reduce" not in hlo and "all-gather" not in hlo
+
+
+@pytest.mark.parametrize("variant", ["bf16", "int8", "tensor4"])
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_paged_decode_at_the_cells_shapes(v5e, cell, variant):
+    """The layer-addressed kernel reads (bs, KV, D) blocks of the pool as
+    it is stored, at both serving cells' heads, batch, tables and pool."""
+    quantized = variant == "int8"
+    shapes = _paged_shapes(jnp.int8 if quantized else jnp.bfloat16,
+                           **CELLS[cell])
+    if quantized:
+        shapes += _scales(nb=CELLS[cell]["nb"])
+    if variant == "tensor4":
+        mesh = Mesh(v5e, ("tensor",))
+        fn = _positional(paged_decode_attention_sharded, mesh=mesh)
+        sh = _tensor_shardings(mesh, quantized)
+    else:
+        fn = _positional(paged_decode_attention)
+        sh = [NamedSharding(Mesh(v5e[:1], ("x",)), P())] * len(shapes)
+    hlo = _compile(fn, sh, *shapes)
+    assert hlo.count("tpu_custom_call") == 1
+    # the kernel takes the pool itself: no slice, reshape or copy of it
+    assert not paged_kv.pool_shaped_ops(hlo, [shapes[1][0]])
+
+
+def _decode_chunk_hlo(v5e, monkeypatch, quant_kv):
+    """``paged_decode_step`` at the offline cell's pool under a 4-step
+    scan with the cache donated, as ``LLMEngine._decode_impl`` runs it,
+    compiled for one v5e. Returns (optimized HLO, the pool's shape)."""
+    # the program asks the backend whether to interpret the kernel; this
+    # process's backend is the CPU, the target is not
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = CELLS["offline"]
+    cfg = llama.LlamaConfig(                  # InternLM2-1.8B, 4 layers
+        vocab_size=92544, dim=2048, n_layers=L, n_heads=16, n_kv_heads=8,
+        mlp_dim=8192, max_seq=2048, rope_scaling=None)
+    one = NamedSharding(Mesh(v5e[:1], ("x",)), P())
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one), tree)
+
+    params = jax.eval_shape(lambda: llama.init_params(
+        jax.random.key(0), cfg, dtype=jnp.bfloat16))
+    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(
+        cfg, cell["b"], cell["nbp"] * BS, BS, cell["nb"],
+        quant_kv=quant_kv))
+
+    def chunk(params, token, cache, tables):
+        def one_step(carry, _):
+            token, cache = carry
+            logits, cache = paged_kv.paged_decode_step(
+                params, token, cfg, cache, tables, kernel="pallas")
+            return (jnp.argmax(logits, -1).astype(jnp.int32), cache), None
+        return jax.lax.scan(one_step, (token, cache), None, length=4)[0]
+
+    compiled = jax.jit(chunk, donate_argnums=(2,)).lower(
+        on_chip(params),
+        jax.ShapeDtypeStruct((cell["b"],), jnp.int32, sharding=one),
+        on_chip(cache),
+        jax.ShapeDtypeStruct((cell["b"], cell["nbp"]), jnp.int32,
+                             sharding=one)).compile()
+    return compiled, cache["k"].shape
+
+
+@pytest.mark.parametrize("quant_kv", ["none", "int8"])
+def test_decode_chunk_updates_the_pool_in_place(v5e, monkeypatch, quant_kv):
+    compiled, pool_shape = _decode_chunk_hlo(v5e, monkeypatch, quant_kv)
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 1      # one kernel per layer
+    # the benchmark's readers find the kernel by this name
+    assert re.search(r"%closed_call\.\d+ = \S+ custom-call\(", hlo)
+    assert paged_kv.pool_shaped_ops(hlo, [pool_shape]) == []
+    # no second pool among the temporaries (the parent: 1.16 GB here)
+    pool_bytes = 2 * L * 640 * BS * KVH * D * (1 if quant_kv == "int8"
+                                                else 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 16
+
+
+def test_pool_shaped_ops_finds_what_the_parent_did():
+    """So that the checker cannot pass by seeing nothing: on the lines of
+    the parent's program (layers as ``xs``/``ys``, pool slices fed to the
+    kernel) it finds the two slices, two reshapes, two updates into the
+    stacked copy, two copies back into the carry, and the two buffers
+    allocated for the stacked copies — and not the scatters that run in
+    place on the slices, nor what is inside a fusion."""
+    found = paged_kv.pool_shaped_ops(PARENT_HLO, [(4, 640, 64, 8, 128)])
+    assert sorted(name for name, _, _ in found) == sorted([
+        "dynamic-slice_bitcast_fusion.4", "dynamic-slice_bitcast_fusion.5",
+        "reshape.234", "reshape.235",
+        "bitcast_dynamic-update-slice_fusion.4",
+        "bitcast_dynamic-update-slice_fusion.5",
+        "copy.65", "copy.67", "custom-call.18", "custom-call.19"])
+    assert {op for _, op, _ in found} == {"fusion", "reshape", "copy",
+                                           "custom-call"}
+    # a pool of another size: nothing here is pool-shaped
+    assert paged_kv.pool_shaped_ops(PARENT_HLO, [(4, 545, 64, 8, 128)]) == []
+
+
+PARENT_HLO = """\
+%fused_computation.7.clone.clone (param_0.406: bf16[4,640,64,8,128], param_1.422: s32[]) -> bf16[640,64,8,128] {
+  %param_0.406 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %dynamic_slice.114 = bf16[1,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} dynamic-slice(%param_0.406, %param_1.422, %constant.470, %constant.470, %constant.470, /*index=5*/%constant.470), dynamic_slice_sizes={1,640,64,8,128}
+  ROOT %bitcast.169 = bf16[640,64,8,128]{3,2,1,0:T(8,128)(2,1)S(1)} bitcast(%dynamic_slice.114)
+}
+%fused_computation.2.clone.clone (param_0.407: bf16[640,64,8,128], param_1.423: s32[36], param_2.358: bf16[36,8,128]) -> bf16[640,64,8,128] {
+  %param_0.407 = bf16[640,64,8,128]{3,2,1,0:T(8,128)(2,1)S(1)} parameter(0)
+  ROOT %scatter.21 = bf16[640,64,8,128]{3,2,1,0:T(8,128)(2,1)S(1)} scatter(%param_0.407, %custom-call.21, %transpose.49), update_window_dims={1,2}, inserted_window_dims={0,1}, scatter_dims_to_operand_dims={0,1}, index_vector_dim=1, to_apply=%region_3.6
+}
+%fused_computation.6.clone.clone (param_0.397: bf16[4,640,64,8,128], param_1.413: s32[]) -> bf16[640,64,8,128] {
+  %param_0.397 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %dynamic_slice.112 = bf16[1,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} dynamic-slice(%param_0.397, %param_1.413, %constant.461, %constant.461, %constant.461, /*index=5*/%constant.461), dynamic_slice_sizes={1,640,64,8,128}
+  ROOT %bitcast.163 = bf16[640,64,8,128]{3,2,1,0:T(8,128)(2,1)} bitcast(%dynamic_slice.112)
+}
+%fused_computation.3.clone.clone (param_0.398: bf16[640,64,8,128], param_1.414: s32[36], param_2.353: bf16[36,8,128]) -> bf16[640,64,8,128] {
+  %param_0.398 = bf16[640,64,8,128]{3,2,1,0:T(8,128)(2,1)} parameter(0)
+  ROOT %scatter.20 = bf16[640,64,8,128]{3,2,1,0:T(8,128)(2,1)} scatter(%param_0.398, %custom-call.20, %transpose.47), update_window_dims={1,2}, inserted_window_dims={0,1}, scatter_dims_to_operand_dims={0,1}, index_vector_dim=1, to_apply=%region_4.7
+}
+%fused_computation.5.clone.clone (param_0.408: bf16[4,640,64,8,128], param_1.424: s32[], param_2.359: bf16[640,64,8,128]) -> bf16[4,640,64,8,128] {
+  %param_0.408 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %param_2.359 = bf16[640,64,8,128]{3,2,1,0:T(8,128)(2,1)S(1)} parameter(2)
+  %bitcast.170 = bf16[1,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} bitcast(%param_2.359)
+  ROOT %dynamic_update_slice.15 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} dynamic-update-slice(%param_0.408, %bitcast.170, %param_1.424, %constant.476, %constant.476, /*index=5*/%constant.476, %constant.476)
+}
+%fused_computation.4.clone.clone (param_0.399: bf16[4,640,64,8,128], param_1.415: s32[], param_2.354: bf16[640,64,8,128]) -> bf16[4,640,64,8,128] {
+  %param_0.399 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %param_2.354 = bf16[640,64,8,128]{3,2,1,0:T(8,128)(2,1)} parameter(2)
+  %bitcast.164 = bf16[1,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} bitcast(%param_2.354)
+  ROOT %dynamic_update_slice.14 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} dynamic-update-slice(%param_0.399, %bitcast.164, %param_1.415, %constant.467, %constant.467, /*index=5*/%constant.467, %constant.467)
+}
+%layer_body (arg: (s32[], bf16[4,640,64,8,128], bf16[4,640,64,8,128])) -> (s32[], bf16[4,640,64,8,128], bf16[4,640,64,8,128]) {
+  %get-tuple-element.962 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} get-tuple-element(%wide.wide.arg_tuple.1), index=13
+  %dynamic-slice_bitcast_fusion.4 = bf16[640,64,8,128]{3,2,1,0:T(8,128)(2,1)S(1)} fusion(%get-tuple-element.962, %get-tuple-element.927), kind=kLoop, calls=%fused_computation.7.clone.clone
+  %fusion.142 = bf16[640,64,8,128]{3,2,1,0:T(8,128)(2,1)S(1)} fusion(%dynamic-slice_bitcast_fusion.4, %fusion.140, %copy.35), kind=kCustom, calls=%fused_computation.2.clone.clone
+  %reshape.234 = bf16[640,64,1024]{2,1,0:T(8,128)(2,1)} reshape(%fusion.142)
+  %get-tuple-element.963 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} get-tuple-element(%wide.wide.arg_tuple.1), index=14
+  %dynamic-slice_bitcast_fusion.5 = bf16[640,64,8,128]{3,2,1,0:T(8,128)(2,1)} fusion(%get-tuple-element.963, %get-tuple-element.927), kind=kLoop, calls=%fused_computation.6.clone.clone
+  %fusion.144 = bf16[640,64,8,128]{3,2,1,0:T(8,128)(2,1)} fusion(%dynamic-slice_bitcast_fusion.5, %fusion.140, %copy.36), kind=kCustom, calls=%fused_computation.3.clone.clone
+  %reshape.235 = bf16[640,64,1024]{2,1,0:T(8,128)(2,1)} reshape(%fusion.144)
+  %get-tuple-element.929 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} get-tuple-element(%wide.wide.arg_tuple.1), index=2
+  %bitcast_dynamic-update-slice_fusion.4 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} fusion(%get-tuple-element.929, %get-tuple-element.927, %fusion.142), kind=kLoop, calls=%fused_computation.5.clone.clone
+  %get-tuple-element.930 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} get-tuple-element(%wide.wide.arg_tuple.1), index=3
+  %bitcast_dynamic-update-slice_fusion.5 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} fusion(%get-tuple-element.930, %get-tuple-element.927, %fusion.144), kind=kLoop, calls=%fused_computation.4.clone.clone
+}
+%step_body (arg: (s32[], bf16[4,640,64,8,128], bf16[4,640,64,8,128])) -> (s32[], bf16[4,640,64,8,128], bf16[4,640,64,8,128]) {
+  %custom-call.18 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} custom-call(), custom_call_target="AllocateBuffer"
+  %custom-call.19 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} custom-call(), custom_call_target="AllocateBuffer"
+  %get-tuple-element.995 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} get-tuple-element(%wide.wide.arg_tuple.0), index=2
+  %get-tuple-element.997 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} get-tuple-element(%wide.wide.arg_tuple.0), index=4
+  %get-tuple-element.1017 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} get-tuple-element(%while.34), index=2
+  %copy.65 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} copy(%get-tuple-element.1017)
+  %get-tuple-element.1019 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} get-tuple-element(%while.34), index=3
+  %copy.67 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} copy(%get-tuple-element.1019)
+}
+"""

@@ -38,25 +38,12 @@ from kubeflow_tpu.serving.quant import (
 )
 from kubeflow_tpu.serving.scheduler import QuantConfig, SchedulerConfig
 
-from test_paged_attention_kernel import _gather_ref, _pool_case
+from test_paged_attention_kernel import (
+    _dequant, _gather_ref, _pool_case, _quantize_pool,
+)
 
 
 # ------------------------------------------------------------ helpers --
-
-def _quantize_pool(pool, qmax=127.0, dtype=jnp.int8):
-    """Per-block per-kv-head symmetric quantization of a full-precision
-    [NB, bs, KVH, D] pool -> (q pool, scale [NB, KVH] f32)."""
-    amax = jnp.max(jnp.abs(pool.astype(jnp.float32)), axis=(1, 3))
-    scale = jnp.maximum(amax / qmax, 1e-30)
-    q = pool.astype(jnp.float32) / scale[:, None, :, None]
-    if jnp.issubdtype(dtype, jnp.integer):
-        q = jnp.clip(jnp.round(q), -qmax, qmax)
-    return q.astype(dtype), scale.astype(jnp.float32)
-
-
-def _dequant(pool, scale):
-    return pool.astype(jnp.float32) * scale[:, None, :, None]
-
 
 def _quant_case(key, **kw):
     q, kp, vp, tables, kvl = _pool_case(key, **kw)
@@ -76,12 +63,12 @@ def _assert_quant_parity(case):
     q, kq, vq, ks, vs, tables, kvl = case
     kd = _dequant(kq, ks).astype(q.dtype)
     vd = _dequant(vq, vs).astype(q.dtype)
-    out = paged_decode_attention(q, kq, vq, tables, kvl, interpret=True,
+    out = paged_decode_attention(q, kq, vq, 0, tables, kvl, interpret=True,
                                  k_scale=ks, v_scale=vs)
-    fused_ref = paged_decode_attention(q, kd, vd, tables, kvl,
+    fused_ref = paged_decode_attention(q, kd, vd, 0, tables, kvl,
                                        interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(fused_ref))
-    ref = _gather_ref(q, kd, vd, tables, kvl)
+    ref = _gather_ref(q, kd, vd, 0, tables, kvl)
     live = np.asarray(kvl) > 0
     np.testing.assert_allclose(np.asarray(out)[live],
                                np.asarray(ref)[live], rtol=2e-5, atol=2e-5)
@@ -111,11 +98,11 @@ def test_quantized_kernel_scale_shape_validation():
         jax.random.key(12), b=2, h=4, kvh=2, d=32, bs=8, nbp=2,
         kv_len=[4, 4])
     with pytest.raises(ValueError, match="scale"):
-        paged_decode_attention(q, kq, vq, tables, kvl, interpret=True,
+        paged_decode_attention(q, kq, vq, 0, tables, kvl, interpret=True,
                                k_scale=ks)            # one without the other
     with pytest.raises(ValueError, match="scale"):
-        paged_decode_attention(q, kq, vq, tables, kvl, interpret=True,
-                               k_scale=ks[:, :1], v_scale=vs)
+        paged_decode_attention(q, kq, vq, 0, tables, kvl, interpret=True,
+                               k_scale=ks[:, :, :1], v_scale=vs)
 
 
 @pytest.mark.skipif(not hasattr(jnp, "float8_e4m3fn"),
@@ -146,16 +133,16 @@ def test_sharded_quantized_kernel_tensor2():
         jax.random.key(14), b=6, h=8, kvh=4, d=32, bs=8, nbp=3,
         kv_len=[0, 1, 7, 16, 17, 24])
     ref = _gather_ref(q, _dequant(kq, ks).astype(q.dtype),
-                      _dequant(vq, vs).astype(q.dtype), tables, kvl)
+                      _dequant(vq, vs).astype(q.dtype), 0, tables, kvl)
     sh = lambda spec, x: jax.device_put(x, NamedSharding(mesh, spec))
     out = paged_decode_attention_sharded(
         sh(P(None, "tensor", None), q),
-        sh(P(None, None, "tensor", None), kq),
-        sh(P(None, None, "tensor", None), vq),
-        sh(P(None, None), tables), sh(P(None), kvl),
+        sh(P(None, None, None, "tensor", None), kq),
+        sh(P(None, None, None, "tensor", None), vq),
+        0, sh(P(None, None), tables), sh(P(None), kvl),
         mesh=mesh, interpret=True,
-        k_scale=sh(P(None, "tensor"), ks),
-        v_scale=sh(P(None, "tensor"), vs))
+        k_scale=sh(P(None, None, "tensor"), ks),
+        v_scale=sh(P(None, None, "tensor"), vs))
     live = np.asarray(kvl) > 0
     np.testing.assert_allclose(np.asarray(out)[live],
                                np.asarray(ref)[live], rtol=2e-5, atol=2e-5)
@@ -168,19 +155,21 @@ def test_quant_scatter_rows_roundtrip_and_monotone_scale():
     true values; a later larger-amplitude write GROWS the block scale and
     requantizes the resident content under it (never shrinks it)."""
     rng = np.random.default_rng(0)
-    pool = jnp.zeros((4, 8, 2, 16), jnp.int8)
-    scale = jnp.zeros((4, 2), jnp.float32)
+    pools = jnp.zeros((2, 4, 8, 2, 16), jnp.int8)
+    scales = jnp.zeros((2, 4, 2), jnp.float32)
     r1 = jnp.asarray(rng.standard_normal((1, 2, 16)), jnp.float32)
-    pool, scale = paged_kv.quant_scatter_rows(
-        pool, scale, jnp.asarray([1]), jnp.asarray([0]), r1)
+    pools, scales = paged_kv.quant_scatter_rows(
+        pools, scales, 1, jnp.asarray([1]), jnp.asarray([0]), r1)
+    pool, scale = pools[1], scales[1]
     s1 = np.asarray(scale)
     got1 = np.asarray(pool[1, 0], np.float32) * s1[1][:, None]
     np.testing.assert_allclose(got1, np.asarray(r1[0]),
                                atol=float(s1[1].max()) / 2 + 1e-6)
     # second write, 10x amplitude, same block -> scale grows
     r2 = 10.0 * jnp.asarray(rng.standard_normal((1, 2, 16)), jnp.float32)
-    pool, scale = paged_kv.quant_scatter_rows(
-        pool, scale, jnp.asarray([1]), jnp.asarray([3]), r2)
+    pools, scales = paged_kv.quant_scatter_rows(
+        pools, scales, 1, jnp.asarray([1]), jnp.asarray([3]), r2)
+    pool, scale = pools[1], scales[1]
     s2 = np.asarray(scale)
     assert (s2[1] >= s1[1] - 1e-12).all()
     # the ORIGINAL row survived the requant within the NEW step size
@@ -190,8 +179,9 @@ def test_quant_scatter_rows_roundtrip_and_monotone_scale():
     got2 = np.asarray(pool[1, 3], np.float32) * s2[1][:, None]
     np.testing.assert_allclose(got2, np.asarray(r2[0]),
                                atol=float(s2[1].max()) / 2 + 1e-6)
-    # untouched blocks: untouched
+    # untouched blocks, and the other layer: untouched
     assert not np.asarray(pool[2]).any() and not s2[2].any()
+    assert not np.asarray(pools[0]).any() and not np.asarray(scales[0]).any()
 
 
 def test_quantized_insert_batch_masks_pad_rows():
