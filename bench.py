@@ -888,7 +888,7 @@ def _decode_path_times(eng, live_len: int,
             n = 2
             for _ in range(n):
                 cache = reset_len(cache, lens)
-                _, lps, _, cache = eng._decode(
+                _, lps, _, cache, _ = eng._decode(
                     eng.params, tok, cache, tables, active, z, zi, one,
                     jax.random.key(trial), greedy_only=True, kernel=kern,
                     chunk_len=eng.decode_chunk)
@@ -966,7 +966,7 @@ def _quant_teacher_forced(cfg, base_params, quant_params, quant_kv: str,
         logits_seq = []
         i = 0
         while True:
-            logits, cache = paged_decode_step(
+            logits, cache, _ = paged_decode_step(
                 params, jnp.asarray([toks[i]], jnp.int32), cfg, cache,
                 tables, kernel=kernel)
             logits_seq.append(np.asarray(logits[0], np.float32))

@@ -78,6 +78,36 @@ from jax.sharding import PartitionSpec as P
 NEG_INF = -1e30      # same mask value as the gather path (decode_attention)
 
 
+def _softmax_accumulate(s, values, m_ref, l_ref, acc_ref):
+    """One block of the online-softmax recurrence (the GQA kernel below keeps
+    its own copy inline: folding it into this helper moved where Mosaic loads
+    the V tile and cost that kernel 4% on the chip, PERF.md section 6, PR
+    29): masked scores ``s`` [H, T] (f32) and the block's ``values`` [T, D]
+    fold into the running max / sum / weighted values in VMEM scratch.
+    m/l scratch is lane-replicated so the [H, 128] tiles stay aligned
+    (only lane 0 is meaningful). Returns (acc, l_new)."""
+    m_prev, l_prev = m_ref[...], l_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new[:, :1])              # masked columns: exactly 0
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
+        p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                  # [H, D]
+    m_ref[...], l_ref[...], acc_ref[...] = m_new, l_new, acc
+    return acc, l_new
+
+
+def _live_block(block_size, n_tables, kvlen_ref, tables_ref, bi, j):
+    """The pool block iteration ``j`` of slot ``bi`` reads. Past the live
+    tail it stays the slot's LAST live block: an unchanged block index
+    elides the DMA, so dead iterations move nothing (an idle slot pins to
+    block 0, fetched once)."""
+    n_live = pl.cdiv(kvlen_ref[bi], block_size)
+    jc = jnp.clip(jnp.minimum(j, n_live - 1), 0, n_tables - 1)
+    return tables_ref[bi, jc]
+
+
 def _decode_kernel(layer_ref, kvlen_ref, tables_ref, q_ref, k_ref, v_ref,
                    *rest, scale, block_size, kv_heads, groups, head_dim,
                    quantized=False):
@@ -242,6 +272,121 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, kv_len, *,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
     )(*args)
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) decode: every query head over ONE shared row per token
+# ---------------------------------------------------------------------------
+
+def _latent_decode_kernel(layer_ref, kvlen_ref, tables_ref, q_ref, *rest,
+                          scale, block_size, value_dim, blocks_per_step):
+    del layer_ref, tables_ref           # consumed by the index maps
+    row_refs = rest[:blocks_per_step]
+    o_ref, m_ref, l_ref, acc_ref, rows_ref = rest[blocks_per_step:]
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    kv_len = kvlen_ref[b]
+    first = j * blocks_per_step * block_size     # this step's first token
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(first < kv_len)
+    def _contribute():
+        # the step's blocks side by side as ONE [G * bs, R] matrix: two
+        # products of a useful width and one softmax update a grid step,
+        # not two small products and an update a block (3.3 times the
+        # time on the chip, PERF.md section 6, PR 29). Blocks past the
+        # slot's live tail repeat its last live block and are masked.
+        for g, ref in enumerate(row_refs):
+            rows_ref[g * block_size:(g + 1) * block_size, :] = ref[0, 0]
+        rows = rows_ref[...]
+        # operands as stored (bf16 on the chip), f32 accumulation: at 61
+        # operations a cached byte a float32 product would make the MXU
+        # the bound (32 heads fill a quarter of it as it is)
+        s = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale      # [H, G * bs]
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(first + col < kv_len, s, NEG_INF)
+        # the values are the rows' first ``value_dim`` entries: a
+        # lane-aligned slice of what is already in VMEM
+        _softmax_accumulate(s, rows[:, :value_dim], m_ref, l_ref, acc_ref)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        # kv_len >= 1 always (the decode step just wrote this step's row);
+        # an all-dead slot still leaves defined output (zeros)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...][:, :1], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+def paged_latent_decode_attention(q, pool, layer, tables, kv_len, *,
+                                  value_dim: int, scale: float,
+                                  interpret: bool = False):
+    """Absorbed latent-attention decode over ONE layer of the whole pool.
+
+    q: [B, H, R] absorbed queries (``W_uk^T q_nope`` beside ``q_rope``);
+    pool: [L, num_blocks, block_size, R], one row per token shared by all
+    heads (the normed latent, then the rotated key); tables / kv_len /
+    layer as ``paged_decode_attention``. Scores are ``q . row * scale``
+    over all R entries, the output ``sum p row[:value_dim]``: [B, H,
+    value_dim] in q.dtype. ``W_uk`` is applied before and ``W_uv`` after,
+    outside.
+
+    The same walk as the GQA kernel (layer, lengths and tables by scalar
+    prefetch, dead tail pinned to the last live block, online softmax),
+    with two differences the shape asks for. A block is the ``[bs, R]``
+    matrix it is in memory, used whole by every head. And one grid step
+    reads up to 32 blocks, each through its own BlockSpec on the same
+    pool, and multiplies them as one matrix: a block is bs x R x 2 bytes
+    (80 KiB at 64 x 640), which moves in a quarter of a grid step's fixed
+    cost, and the long contexts this serves are hundreds of blocks a
+    slot."""
+    b, h, r = q.shape
+    n_layers, num_blocks, block_size, r_pool = pool.shape
+    if r != r_pool:
+        raise ValueError(f"row mismatch: q has {r}, pool has {r_pool}")
+    n_tables = tables.shape[1]
+    per_step = next(g for g in (32, 16, 8, 4, 2, 1) if n_tables % g == 0)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    kv_len = kv_len.astype(jnp.int32)
+    tables = tables.astype(jnp.int32)
+
+    def rows_map(g):
+        def index(bi, j, layer_ref, kvlen_ref, tables_ref):
+            return (layer_ref[0],
+                    _live_block(block_size, n_tables, kvlen_ref, tables_ref,
+                                bi, j * per_step + g), 0, 0)
+        return index
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, n_tables // per_step),
+        in_specs=[pl.BlockSpec((1, h, r), lambda bi, j, *_: (bi, 0, 0))]
+        + [pl.BlockSpec((1, 1, block_size, r), rows_map(g))
+           for g in range(per_step)],
+        out_specs=pl.BlockSpec((1, h, value_dim),
+                               lambda bi, j, *_: (bi, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((h, value_dim), jnp.float32),
+            pltpu.VMEM((per_step * block_size, r), pool.dtype),
+        ],
+    )
+    kernel = functools.partial(
+        _latent_decode_kernel, scale=scale, block_size=block_size,
+        value_dim=value_dim, blocks_per_step=per_step)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, value_dim), q.dtype),
+        interpret=interpret,
+    )(layer, kv_len, tables, q, *([pool] * per_step))
 
 
 def shard_unsupported_reason(mesh, n_kv_heads: int,

@@ -9,6 +9,15 @@ and the XLA scheduler want; overflow tokens are dropped by capacity like the
 reference implementations.
 
 Aux objectives: Switch load-balancing loss + router z-loss.
+
+The SERVED path of a model with many small experts is the second half of
+this file (``RouterConfig``, ``route``, ``routed_experts``): no capacity
+buffers and no dropped token. The ``T x k`` assignments are sorted by
+expert and the three expert matrices are applied as grouped products over
+the sorted rows, so a step reads the weights of the experts that were hit
+and no others, and a one-hot dispatch tensor (``[T, E, C]``, as costly as
+the experts themselves at 256 experts) never exists. ``all_experts`` is
+the plain loop the tests hold it against.
 """
 
 from __future__ import annotations
@@ -174,3 +183,130 @@ def moe_layer(params, x, cfg: MoEConfig, *, capacity: Optional[int] = None,
 def moe_aux_total(aux: dict) -> jax.Array:
     """Sum of the differentiable aux penalties (exclude diagnostics)."""
     return aux["moe_load_balance"] + aux["moe_router_z"]
+
+
+# ---------------------------------------------------------------------------
+# The served path: scores -> top-k -> sort by expert -> grouped products
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class RouterConfig:
+    """How a layer turns router logits into ``top_k`` experts and their
+    weights. ``score_func``: ``softmax`` | ``sigmoid`` over all experts.
+    ``select_bias``: the choice is the top-k of ``score + bias`` (a
+    per-expert correction that gradients do not reach) while the weights
+    are the scores WITHOUT it. ``norm_topk``: divide the chosen scores by
+    their sum; ``scale`` multiplies them afterwards."""
+
+    n_experts: int
+    top_k: int
+    score_func: str = "softmax"
+    select_bias: bool = False
+    norm_topk: bool = True
+    scale: float = 1.0
+
+
+def route(tokens, router_w, bias, rc: RouterConfig):
+    """tokens [T, D] -> (experts [T, k] int32, weights [T, k] f32).
+    Logits, scores and the choice are float32 at full matmul precision: a
+    bf16 product here moves the 8th and 9th score past each other."""
+    logits = jnp.dot(tokens.astype(jnp.float32),
+                     router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if rc.score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif rc.score_func == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"score_func={rc.score_func!r} "
+                         "(want softmax|sigmoid)")
+    choice = scores + bias.astype(jnp.float32) if rc.select_bias else scores
+    _, experts = jax.lax.top_k(choice, rc.top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if rc.norm_topk:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * rc.scale
+
+
+def grouped_matmul(rows, weights, group_sizes):
+    """``rows[sorted by group] @ weights[group]``: rows [M, K] whose first
+    ``group_sizes[0]`` rows belong to group 0 and so on, weights
+    [G, K, N] -> [M, N]. Rows past ``sum(group_sizes)`` belong to no group
+    and their output is unspecified.
+
+    The Pallas grouped matmul that ships with JAX (megablox ``gmm``): it
+    visits a group's weight tile once per row tile that touches the group
+    and skips empty groups, so a step of a few rows reads the experts that
+    were hit and nothing else. Chosen over ``jax.lax.ragged_dot`` on the
+    chip (PERF.md section 6, PR 29: 0.66 against 1.06 ms for 192 rows over
+    139 experts, 1.6 against 4.2 ms for 16,384 rows). Row tiles of 32 for
+    a decode step's tens of rows, 128 for a prefill chunk's thousands; on
+    the CPU the same kernel runs under the Pallas interpreter."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, k = rows.shape
+    n = weights.shape[-1]
+    tm = 128 if m >= 1024 else 32
+    pad = -m % tm
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    out = gmm(rows, weights, group_sizes, preferred_element_type=rows.dtype,
+              tiling=(tm, min(k, 2048), min(n, 2048)),
+              interpret=jax.default_backend() == "cpu")
+    return out[:m]
+
+
+def routed_experts(tokens, experts, weights, w_gate, w_up, w_down,
+                   valid=None, n_experts=None, first_group=0):
+    """Sum over each token's chosen experts of ``w * SwiGLU_e(token)``.
+
+    tokens [T, D]; experts/weights [T, k] over ``n_experts`` experts;
+    w_gate/w_up [G, D, M], w_down [G, M, D] with expert ``e`` at group
+    ``first_group + e``: a layer's own matrices (G = n_experts, the
+    default), or several layers' experts in one array (``[L * E, ...]``, a
+    free reshape of a stack) with ``first_group = layer * E`` traced.
+    Handing the grouped product the whole stack and an offset is what
+    keeps a layer loop from slicing a layer's experts out of the stack: a
+    copy of all of them, every layer of every step. ``valid`` [T] bool
+    marks real tokens (pad and idle rows are sorted behind every group,
+    multiply nothing and give zeros). Returns (y [T, D] in tokens.dtype,
+    tokens_per_expert [n_experts] int32). No token is dropped and none is
+    coupled to another: a row's output depends on its own experts alone,
+    whatever else is in the batch."""
+    t, k = experts.shape
+    groups = w_gate.shape[0]
+    e = groups if n_experts is None else n_experts
+    flat = experts.reshape(t * k)
+    if valid is not None:
+        flat = jnp.where(jnp.repeat(valid, k), flat, e)    # behind all groups
+    order = jnp.argsort(flat, stable=True)                 # [T*k] by expert
+    counts = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
+    sizes = counts if groups == e else jax.lax.dynamic_update_slice(
+        jnp.zeros((groups,), jnp.int32), counts, (first_group,))
+    rows = tokens[order // k]                              # [T*k, D]
+    h = jax.nn.silu(grouped_matmul(rows, w_gate.astype(rows.dtype), sizes)) \
+        * grouped_matmul(rows, w_up.astype(rows.dtype), sizes)
+    out = grouped_matmul(h, w_down.astype(rows.dtype), sizes)    # [T*k, D]
+    # back to (token, choice) order (the permutation inverted by a scatter,
+    # not a second sort); rows of no group may hold anything
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
+    out = out[back].reshape(t, k, -1).astype(jnp.float32)
+    live = jnp.ones((t,), bool) if valid is None else valid
+    out = jnp.where(live[:, None, None], out * weights[..., None], 0.0)
+    return out.sum(1).astype(tokens.dtype), counts
+
+
+def all_experts(tokens, experts, weights, w_gate, w_up, w_down, valid=None):
+    """What ``routed_experts`` computes, by a loop over ALL experts with a
+    dense gate matrix: the oracle of the CPU tests, E / k times the work."""
+    e = w_gate.shape[0]
+    gates = (jax.nn.one_hot(experts, e, dtype=jnp.float32)
+             * weights[..., None]).sum(1)                  # [T, E]
+    if valid is not None:
+        gates = gates * valid[:, None]
+    y = jnp.zeros(tokens.shape, jnp.float32)
+    for i in range(e):
+        h = jax.nn.silu(tokens @ w_gate[i].astype(tokens.dtype)) \
+            * (tokens @ w_up[i].astype(tokens.dtype))
+        y = y + gates[:, i:i + 1] * (h @ w_down[i].astype(tokens.dtype))
+    return y.astype(tokens.dtype)

@@ -324,6 +324,13 @@ class LLMModel(Model):
             "kv_free_blocks": eng.paged.allocator.free_blocks,
             "kv_reclaimable_blocks": eng.paged.reclaimable_blocks,
             "prefix_cache_hits_total": eng.paged.prefix_hits,
+            "kv_row_bytes": eng.kv_row_bytes(),
+            # expert layers (0 for a dense model): assignments routed and
+            # distinct experts hit, summed over decode steps and layers
+            "moe_routed_assignments_total": int(
+                0 if eng.moe_tokens_per_expert is None
+                else eng.moe_tokens_per_expert.sum()),
+            "moe_experts_hit_total": eng.moe_experts_hit,
             # a decode-kernel downgrade the caller didn't ask for (gpu
             # platform / unshardable mesh topology) is ~3.7x decode
             # bandwidth quietly lost — it must be visible on /metrics

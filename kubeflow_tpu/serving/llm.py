@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kubeflow_tpu.models import llama
 from kubeflow_tpu.obs import trace as obs_trace
 from kubeflow_tpu.obs.histogram import Histogram, log_buckets
 
@@ -82,6 +81,10 @@ class SamplingParams:
     # any of these ends generation like eos (finish_reason "stop"); text
     # stop STRINGS live a layer up in LLMModel, which owns the tokenizer
     stop_token_ids: tuple = ()
+    # expert models: keep, per generated token, the experts every expert
+    # layer routed it to (``GenRequest.routing``) — what a check against a
+    # reference needs to tell a near-tie from a wrong router
+    record_routing: bool = False
 
 
 @dataclasses.dataclass
@@ -92,6 +95,8 @@ class GenRequest:
     generated: list[int] = dataclasses.field(default_factory=list)
     # per-generated-token logprob under the model distribution
     logprobs: list[float] = dataclasses.field(default_factory=list)
+    # SamplingParams.record_routing: [expert layers, top_k] per token
+    routing: list = dataclasses.field(default_factory=list)
     done: bool = False
     aborted: bool = False
     # set by a text-level stop-string watcher before aborting: the abort
@@ -147,6 +152,7 @@ class _ChunkedPrefill:
     share_len: int
     tables: Any
     x_last: Any = None
+    stats: Any = None
 
 
 def _bucket(n: int, buckets: Sequence[int]) -> int:
@@ -212,9 +218,13 @@ def sample_logits(logits, rng, temperature, top_k, top_p,
 
 
 class LLMEngine:
-    """Continuous-batching generation over llama prefill/decode_step."""
+    """Continuous-batching generation over a model's paged programs.
+    ``cfg`` is any config ``paged_kv.paged_ops`` knows (a ``LlamaConfig``,
+    or one that brings its own ``paged_ops()``): what the engine needs of
+    the model — the pool's rows, the layer's pieces, the head, what it
+    cannot be served with — it asks through that one object."""
 
-    def __init__(self, params, cfg: llama.LlamaConfig, *,
+    def __init__(self, params, cfg, *,
                  max_batch: int = 8, max_seq: int = 1024,
                  prefill_buckets: Sequence[int] = (64, 128, 256, 512),
                  kv_block_size: Optional[int] = None,
@@ -227,7 +237,7 @@ class LLMEngine:
                  quant: Optional[QuantConfig] = None,
                  obs: Optional[obs_trace.SpanCollector] = None):
         from kubeflow_tpu.serving.paged_kv import (
-            PagedKV, _lm_head as lm_head_fn, paged_prefill_chunk
+            PagedKV, paged_ops, paged_prefill_chunk
             as paged_prefill_chunk_fn, paged_verify_step
             as paged_verify_step_fn, resolve_decode_kernel,
         )
@@ -237,6 +247,22 @@ class LLMEngine:
 
         self.cfg = cfg
         self.mesh = mesh
+        self.model = ops = paged_ops(cfg)
+        if quant is None and scheduler is not None:
+            quant = scheduler.quant
+        asked = {
+            "quantized KV pool": quant is not None and not quant.exact_parity
+            and quant.kv_dtype != "none",
+            "int8 weights": quant is not None and not quant.exact_parity
+            and quant.weight_dtype != "none",
+            "speculative decode": scheduler is not None
+            and scheduler.spec_decode,
+            "tensor mesh": mesh is not None,
+        }
+        for mechanism, why in ops.refuses.items():
+            if asked.get(mechanism):
+                raise ValueError(f"{type(cfg).__name__} cannot be served "
+                                 f"with {mechanism}: {why}")
         # decode-attention path (paged_kv module docstring): the
         # block-resident Pallas kernel is the TPU default — including
         # under a mesh, where it runs shard_map'd over the heads/KV
@@ -261,8 +287,6 @@ class LLMEngine:
         # plus its own quant_downgrades), logged once per process, never
         # a silent dtype change. The explicit quant= argument wins over
         # the scheduler policy's copy (one resolution authority).
-        if quant is None and scheduler is not None:
-            quant = scheduler.quant
         self.quant_requested = quant
         self.quant, quant_downgrades = resolve_quant(quant, cfg=cfg)
         self.quant_downgrades = len(quant_downgrades)
@@ -348,6 +372,12 @@ class LLMEngine:
         self._tokens = np.zeros((max_batch,), np.int32)   # next input token
         self._rng = jax.random.key(0)
         self.steps = 0
+        # expert layers only: assignments per (expert layer, expert) since
+        # the engine was built, and distinct experts hit summed over
+        # (decode step, expert layer); counted on the device, read back
+        # with the tokens they belong to
+        self.moe_tokens_per_expert: Optional[np.ndarray] = None
+        self.moe_experts_hit = 0
         self.generated_tokens = 0
         self.prefill_dispatches = 0       # observability: admission batching
         # multi-step decode: one dispatch runs `decode_chunk` decode+sample
@@ -400,9 +430,13 @@ class LLMEngine:
             self.spec = make_drafter(self.sched.cfg.spec_drafter,
                                      self.sched.cfg.spec_k)
 
-        self._prefill = jax.jit(
-            lambda p, toks, lens, cache: llama.prefill(
-                p, toks, cfg, cache, lengths=lens))
+        # whole-bucket prefill into a dense scratch, where the model has
+        # one; without it every prompt streams through the chunk program.
+        # (Lambdas on purpose, here and below: the benchmark's readers
+        # find the prefill programs by the name ``jit__lambda``.)
+        self._prefill = ops.bucket_prefill and jax.jit(
+            lambda p, toks, lens, cache: ops.bucket_prefill(
+                p, toks, lens, cache))
         # chunked prefill for prompts longer than every bucket: fixed
         # chunk size (the largest bucket) + traced offset/length keep the
         # compile count O(1) in prompt length
@@ -415,7 +449,7 @@ class LLMEngine:
         # the lm head runs ONCE on the final chunk's hidden row, not per
         # chunk (full-vocab matmul is the expensive part of short chunks)
         self._chunk_lm_head = jax.jit(
-            lambda p, x_last: lm_head_fn(p, x_last, self.cfg))
+            lambda p, x_last: ops.head(p, x_last))
         # first-token sampling + its logprob in ONE jitted call: computing
         # log_softmax eagerly per admitted request costs an op-by-op
         # full-vocab dispatch + transfer
@@ -481,7 +515,7 @@ class LLMEngine:
 
         def one_step(carry, rng_step):
             token, cache = carry
-            logits, cache = paged_decode_step(
+            logits, cache, stats = paged_decode_step(
                 params, token, self.cfg, cache, tables, kernel=kernel,
                 mesh=self.mesh)
             nxt = sample_logits(logits, rng_step, temperature, top_k,
@@ -495,14 +529,18 @@ class LLMEngine:
             # idle slots: pin len to 0 so the cursor can't creep toward
             # max_seq (their scatter lands in the scratch block 0)
             cache["len"] = jnp.where(active, cache["len"], 0)
-            return (nxt, cache), (nxt, lp)
+            return (nxt, cache), (nxt, lp, stats)
 
         rngs = jax.random.split(rng, chunk_len)
-        (next_tok, cache), (toks, lps) = jax.lax.scan(
+        (next_tok, cache), (toks, lps, stats) = jax.lax.scan(
             one_step, (token, cache), rngs)
         # next_tok: the device-side carry the pipelined dispatch feeds the
-        # NEXT chunk without waiting for the host to read toks back
-        return toks, lps, next_tok, cache        # toks/lps: [chunk, B]
+        # NEXT chunk without waiting for the host to read toks back.
+        # stats: the layers' counts summed over the chunk's steps (empty
+        # for a dense model), a few KB read back beside the tokens
+        stats = {key: val if key == "experts" else val.sum(0)
+                 for key, val in stats.items()}
+        return toks, lps, next_tok, cache, stats  # toks/lps: [chunk, B]
 
     def _insert_batch_impl(self, cache, k_new, v_new, blk_ids, lengths,
                            slots):
@@ -584,7 +622,7 @@ class LLMEngine:
         if not text:
             return
         shapes = [self.cache[key].sharding.shard_shape(self.cache[key].shape)
-                  for key in ("k", "v")]
+                  for key in self.model.pool_rows]
         found = pool_shaped_ops(text, shapes)
         self.decode_pool_shaped_ops = len(found)
         log = logger.warning if found else logger.info
@@ -848,6 +886,13 @@ class LLMEngine:
             self.request_hists["itl"].observe(gap, n_new)
         req.t_last_commit = now
 
+    def kv_row_bytes(self) -> int:
+        """Bytes ONE token caches over all layers and pools, as stored
+        (a quantized pool's scale tables left out)."""
+        return sum(int(np.prod(self.cache[key].shape[3:]))
+                   * self.cache[key].dtype.itemsize * self.cache[key].shape[0]
+                   for key in self.model.pool_rows)
+
     def has_work(self) -> bool:
         with self._lock:
             return bool(self._waiting or self._active or self._chunked
@@ -1035,19 +1080,19 @@ class LLMEngine:
             # the precompile()d executable (depot fast path): same
             # program as the jitted call below, acquired without a
             # cold compile on a scale-up replica
-            toks, lps, next_tok, self.cache = self._compiled_decode(
+            toks, lps, next_tok, self.cache, stats = self._compiled_decode(
                 self.params, token_in, self.cache, jnp.asarray(tab),
                 jnp.asarray(active_mask), jnp.asarray(temp),
                 jnp.asarray(top_k), jnp.asarray(top_p), step_rng)
         else:
-            toks, lps, next_tok, self.cache = self._decode(
+            toks, lps, next_tok, self.cache, stats = self._decode(
                 self.params, token_in, self.cache, jnp.asarray(tab),
                 jnp.asarray(active_mask), jnp.asarray(temp),
                 jnp.asarray(top_k), jnp.asarray(top_p), step_rng,
                 greedy_only=greedy_only,
                 kernel=self.kernel, chunk_len=chunk_len)
         return {
-            "toks": toks, "lps": lps, "next": next_tok,
+            "toks": toks, "lps": lps, "next": next_tok, "stats": stats,
             "chunk_len": chunk_len, "span": dspan,
             # snapshot: tokens belong to the requests active at
             # DISPATCH time — a slot may host a new request by the
@@ -1138,6 +1183,12 @@ class LLMEngine:
         with self._phase("step.wait", device_steps=inflight["chunk_len"]):
             toks = np.asarray(inflight["toks"])     # [chunk, B] (blocks here)
             lps = np.asarray(inflight["lps"])
+            routed = self._note_expert_stats(inflight["stats"], decode=True)
+            experts = None                     # [chunk, layers, B, 1, k]
+            if inflight["stats"] and any(
+                    r.sampling.record_routing
+                    for _, r in inflight["snapshot"]):
+                experts = np.asarray(inflight["stats"]["experts"])
         self.steps += toks.shape[0]
         finished = []
         committed_total = 0
@@ -1148,6 +1199,8 @@ class LLMEngine:
                 n0 = len(req.generated)
                 done = False
                 for t in range(toks.shape[0]):
+                    if experts is not None and req.sampling.record_routing:
+                        req.routing.append(experts[t, :, slot, 0])
                     if self._commit_token(req, slot, int(toks[t, slot]),
                                           float(lps[t, slot])):
                         # overshoot tokens beyond this point are trimmed
@@ -1168,8 +1221,26 @@ class LLMEngine:
                 # the decode span covers dispatch -> read-back (pipelined:
                 # device compute + the host overlap it bought)
                 self.obs.end(span, tokens_committed=committed_total,
-                             device_steps=int(toks.shape[0]))
+                             device_steps=int(toks.shape[0]), **routed)
         return finished
+
+    def _note_expert_stats(self, stats, decode: bool = False) -> dict:
+        """Fold a program's expert counts (``PagedOps.out``; nothing for
+        a dense model) into the engine's counters. Returns the span attrs
+        of a decode chunk: assignments made and distinct experts hit,
+        both summed over its steps and expert layers."""
+        if not stats:
+            return {}
+        per_expert = np.asarray(stats["tokens_per_expert"])      # [Lm, E]
+        if self.moe_tokens_per_expert is None:
+            self.moe_tokens_per_expert = np.zeros(per_expert.shape, np.int64)
+        self.moe_tokens_per_expert += per_expert
+        if not decode:
+            return {}
+        hit = int(np.asarray(stats["experts_hit"]).sum())
+        self.moe_experts_hit += hit
+        return {"routed_assignments": int(per_expert.sum()),
+                "experts_hit": hit}
 
     def _spec_step(self) -> list[GenRequest]:
         """One speculative draft+verify round over the active batch.
@@ -1319,17 +1390,26 @@ class LLMEngine:
         req = st.req
         L = len(req.prompt)
         W = self._chunk_width
+        attrs = {}
+        if self.model.routed_per_token:
+            attrs["routed_assignments"] = \
+                min(W, L - st.offset) * self.model.routed_per_token
         pspan = self._dispatch_span(
             "prefill.chunk", [req], slot=slot, offset=st.offset,
-            width=W, prompt_tokens=L)
+            width=W, prompt_tokens=L, **attrs)
         piece = np.zeros((1, W), np.int32)
         part = req.prompt[st.offset:st.offset + W]
         piece[0, :len(part)] = part
         chunk_fn = self._compiled_prefill_chunk or self._prefill_chunk
-        st.x_last, self.cache = chunk_fn(
+        st.x_last, self.cache, stats = chunk_fn(
             self.params, jnp.asarray(piece), self.cache, st.tables,
             jnp.int32(slot), jnp.int32(st.offset), jnp.int32(L),
             jnp.int32(st.share_len))
+        # the chunks' expert counts stay on the device until the last
+        # chunk's token is read back: no wait of their own
+        st.stats = stats if st.stats is None else {
+            key: val if key == "experts" else st.stats[key] + val
+            for key, val in stats.items()}
         st.offset += W
         self.sched.note_prefill_chunk(W)
         self.obs.end(pspan, final=st.offset >= L)
@@ -1341,6 +1421,9 @@ class LLMEngine:
         if st.offset >= L:
             logits = self._chunk_lm_head(self.params, st.x_last)
             tok, lp = self._sample_rows(logits, [req])
+            self._note_expert_stats(st.stats)
+            if st.stats and req.sampling.record_routing:
+                req.routing.append(np.asarray(st.stats["experts"]))
             self.cache = self._set_len(
                 self.cache, jnp.int32(L), jnp.int32(slot))
             del self._chunked[slot]
@@ -1407,7 +1490,8 @@ class LLMEngine:
             # positions) are SHARED, not recomputed storage — including for
             # chunked prompts, whose private full blocks publish chunk by
             # chunk (defer_publish) instead of at reserve time
-            chunked = len(req.prompt) > self.buckets[-1]
+            chunked = (self._prefill is None
+                       or len(req.prompt) > self.buckets[-1])
             n_shared = self.paged.reserve(
                 slot, len(req.prompt), req.sampling.max_tokens,
                 min_blocks=blocks_for(len(req.prompt), bs),
@@ -1481,7 +1565,7 @@ class LLMEngine:
             ids = self.paged.slot_blocks(slot)
             blk[i, n_shared:nb_prefill] = ids[n_shared:nb_prefill]
             slots[i] = slot
-        scratch = llama.init_cache(self.cfg, width, bucket)
+        scratch = self.model.bucket_scratch(width, bucket)
         self.prefill_dispatches += 1
         pspan = self._dispatch_span(
             "prefill.batch", [r for r, _, _ in batch],

@@ -50,26 +50,77 @@ from __future__ import annotations
 import dataclasses
 import functools
 import re
-from typing import Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from kubeflow_tpu.models import llama
-from kubeflow_tpu.ops.attention import decode_attention
+from kubeflow_tpu.ops.attention import decode_attention as decode_attention_fn
 from kubeflow_tpu.ops.norms import rms_norm
 from kubeflow_tpu.ops.rotary import apply_rope, rope_frequencies
 from kubeflow_tpu.serving.quant import kv_store_dtype
 
 
-def init_paged_cache(cfg: llama.LlamaConfig, max_batch: int, max_seq: int,
+@dataclasses.dataclass(frozen=True)
+class PagedOps:
+    """A model as the paged programs and the engine see it: the pieces of
+    one layer around attention, what a token caches, and what the model
+    cannot be served with. The three programs below (decode, chunked
+    prefill, speculative verify) are written once over these pieces; a
+    config class offers its own through a ``paged_ops()`` method and
+    ``LlamaConfig`` models get ``_llama_ops`` (``paged_ops(cfg)``).
+
+    ``pool_rows``: pool name -> the shape of ONE token's row in it; a pool
+    is ``[n_layers, num_blocks, block_size, *row]``. ``layer_stacks(params)``:
+    the stacked layer trees in order (layers of one kind per stack), each
+    with the names of the weights it wants whole (``_scan_layers``); each
+    is scanned with the pools in the carry. ``qkv(lp, x, positions)`` ->
+    ``(q, {pool: rows [B, S, *row]})``; ``decode_attention(lp, q, pools,
+    layer, tables, kv_len, kernel, mesh, interpret)`` -> o of the one new
+    row per slot; ``chunk_attention(lp, q, pools, layer, tables,
+    q_start)`` -> o of [B, C] rows at positions ``q_start[b] + i``, causal
+    over what the slot's blocks hold; ``out(lp, x, o, token_mask)`` ->
+    ``(x, stats)`` with ``stats`` a dict of small per-layer counts (empty
+    for a dense layer); ``head(params, x_last)`` -> float32 logits from
+    the hidden state before the final norm. ``bucket_prefill(params,
+    tokens, lengths, scratch)`` / ``bucket_scratch(width, bucket)``: the
+    dense-scratch prefill of whole buckets, or None where every prompt
+    streams through ``paged_prefill_chunk``. ``routed_per_token``: expert
+    assignments one token makes over all layers (0: no experts).
+    ``refuses``: mechanism -> why the engine must not be built with it."""
+
+    n_layers: int
+    pool_rows: dict
+    layer_stacks: Callable
+    embed: Callable
+    qkv: Callable
+    decode_attention: Callable
+    chunk_attention: Callable
+    out: Callable
+    head: Callable
+    bucket_prefill: Optional[Callable] = None
+    bucket_scratch: Optional[Callable] = None
+    routed_per_token: int = 0
+    refuses: dict = dataclasses.field(default_factory=dict)
+
+
+def paged_ops(cfg) -> PagedOps:
+    make = getattr(cfg, "paged_ops", None)
+    return make() if make is not None else _llama_ops(cfg)
+
+
+def init_paged_cache(cfg, max_batch: int, max_seq: int,
                      block_size: int, num_blocks: int, dtype=None,
                      kv_sharding=None, len_sharding=None,
                      quant_kv: str = "none",
                      scale_sharding=None) -> dict:
     """Pool + per-slot lengths. ``num_blocks`` bounds total resident tokens
-    (num_blocks * block_size), independent of max_batch * max_seq.
+    (num_blocks * block_size), independent of max_batch * max_seq. The
+    pools and their rows are the model's (``PagedOps.pool_rows``): K and V
+    of ``[KV, D]`` rows for a GQA model, one ``kv`` pool of latent rows for
+    a latent-attention one.
     ``kv_sharding`` allocates the pool DIRECTLY with that sharding — a
     pod-sized pool must never transit one chip unsharded.
 
@@ -82,27 +133,25 @@ def init_paged_cache(cfg: llama.LlamaConfig, max_batch: int, max_seq: int,
     if max_seq % block_size:
         raise ValueError(f"max_seq={max_seq} not a multiple of "
                          f"block_size={block_size}")
+    ops = paged_ops(cfg)
     dtype = dtype or cfg.dtype
-    shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads,
-             cfg.head_dim)
-    if quant_kv and quant_kv != "none":
-        sdtype = kv_store_dtype(quant_kv)
-        sshape = (cfg.n_layers, num_blocks, cfg.n_kv_heads)
-        return {
-            "k": jnp.zeros(shape, sdtype, device=kv_sharding),
-            "v": jnp.zeros(shape, sdtype, device=kv_sharding),
-            "k_scale": jnp.zeros(sshape, jnp.float32,
-                                 device=scale_sharding),
-            "v_scale": jnp.zeros(sshape, jnp.float32,
-                                 device=scale_sharding),
-            "len": jnp.zeros((max_batch,), jnp.int32,
-                             device=len_sharding),
-        }
-    return {
-        "k": jnp.zeros(shape, dtype, device=kv_sharding),
-        "v": jnp.zeros(shape, dtype, device=kv_sharding),
-        "len": jnp.zeros((max_batch,), jnp.int32, device=len_sharding),
-    }
+    quantized = bool(quant_kv) and quant_kv != "none"
+    if quantized and "quantized KV pool" in ops.refuses:
+        raise ValueError(f"{type(cfg).__name__} has no quantized KV pool: "
+                         + ops.refuses["quantized KV pool"])
+    cache = {}
+    for name, row in ops.pool_rows.items():
+        cache[name] = jnp.zeros(
+            (ops.n_layers, num_blocks, block_size, *row),
+            kv_store_dtype(quant_kv) if quantized else dtype,
+            device=kv_sharding)
+    if quantized:
+        for name, row in ops.pool_rows.items():
+            cache[name + "_scale"] = jnp.zeros(
+                (ops.n_layers, num_blocks, row[0]), jnp.float32,
+                device=scale_sharding)
+    cache["len"] = jnp.zeros((max_batch,), jnp.int32, device=len_sharding)
+    return cache
 
 
 class BlockAllocator:
@@ -277,7 +326,7 @@ class PagedKV:
     ``defer_publish=True``) and publish completed read-only blocks chunk
     by chunk via ``publish_prompt_blocks``."""
 
-    cfg: llama.LlamaConfig
+    cfg: Any                         # a config ``paged_ops`` knows
     max_batch: int
     max_seq: int
     block_size: int
@@ -420,7 +469,8 @@ class PagedKV:
 # host and the extra scatter writes land where writes are already allowed.
 
 def _pool_keys(cache: dict) -> tuple:
-    return tuple(k for k in ("k", "v", "k_scale", "v_scale") if k in cache)
+    """The pools and their scale tables: everything but the lengths."""
+    return tuple(k for k in cache if k != "len")
 
 
 @functools.partial(jax.jit, static_argnames=("keys",))
@@ -654,20 +704,18 @@ def dequant_gather_view(pool, scale, layer, tables, cfg):
     return v.reshape(b, -1, *pool.shape[3:])
 
 
-def _scatter_kv_rows(pools, layer, blk, off, k, v):
-    """This step's K and V rows [..., KV, D] into layer ``layer`` of the
-    carried pools at (blk, off); quantize-on-write where the pool is
+def _scatter_rows(pools, layer, blk, off, rows):
+    """This step's rows (``{pool: [..., *row]}``) into layer ``layer`` of
+    the carried pools at (blk, off); quantize-on-write where the pool is
     quantized (``k_scale`` present). Returns the updated pools dict."""
     if "k_scale" in pools:
         k_pool, k_sc = quant_scatter_rows(pools["k"], pools["k_scale"],
-                                          layer, blk, off, k)
+                                          layer, blk, off, rows["k"])
         v_pool, v_sc = quant_scatter_rows(pools["v"], pools["v_scale"],
-                                          layer, blk, off, v)
+                                          layer, blk, off, rows["v"])
         return {"k": k_pool, "v": v_pool, "k_scale": k_sc, "v_scale": v_sc}
-    return {"k": pools["k"].at[layer, blk, off].set(
-                k.astype(pools["k"].dtype)),
-            "v": pools["v"].at[layer, blk, off].set(
-                v.astype(pools["v"].dtype))}
+    return {key: pools[key].at[layer, blk, off].set(
+                rows[key].astype(pools[key].dtype)) for key in pools}
 
 
 def _gather_views(pools, layer, tables, cfg):
@@ -685,22 +733,45 @@ def _gather_views(pools, layer, tables, cfg):
         b, -1, *pools[key].shape[3:]) for key in ("k", "v"))
 
 
-def _scan_layers(params, x, cache, layer_fn):
-    """Run ``layer_fn(lp, x, pools, layer) -> (x, pools)`` over the layers
-    with the layer index and the layer's weights scanned and the pools
-    (``k``, ``v`` and the scale tables of a quantized pool) in the CARRY,
-    so each layer's rows are scattered into the one buffer that came in.
-    Returns (x, pools)."""
+def _scan_layers(ops: PagedOps, params, x, cache, layer_fn):
+    """Run ``layer_fn(lp, x, pools, layer) -> (x, pools, stats)`` over the
+    layers with the layer index and the layer's weights scanned and the
+    pools (and the scale tables of a quantized pool) in the CARRY, so each
+    layer's rows are scattered into the one buffer that came in. A model
+    whose layers are of several kinds has one stack per kind
+    (``ops.layer_stacks``): the stacks are scanned one after the other,
+    the layer index running on, the same carry through all of them.
+    Returns (x, pools, stats) with each stat stacked over the layers that
+    report it.
+
+    A stack comes as ``(stacked tree, whole)``: the weights named in
+    ``whole`` are NOT scanned but handed to every layer as the stack they
+    are, with the layer's place in it as ``stack_index`` — for weights a
+    kernel addresses by (layer, group) itself, which a scan would slice
+    out, a copy, layer by layer."""
     pools = {key: cache[key] for key in _pool_keys(cache)}
+    first, stats = 0, {}
+    for stack, whole in ops.layer_stacks(params):
+        kept = {key: stack[key] for key in whole}
+        n = jax.tree.leaves(stack)[0].shape[0]
+        xs = (first + jnp.arange(n),
+              {key: val for key, val in stack.items() if key not in whole})
+        if kept:
+            xs += (jnp.arange(n),)
 
-    def body(carry, xs):
-        layer, lp = xs
-        return layer_fn(lp, *carry, layer), None
+        def body(carry, xs, kept=kept):
+            layer, lp = xs[:2]
+            if kept:
+                lp = dict(lp, **kept, stack_index=xs[2])
+            x, pools, ys = layer_fn(lp, *carry, layer)
+            return (x, pools), ys
 
-    (x, pools), _ = jax.lax.scan(
-        body, (x, pools),
-        (jnp.arange(cache["k"].shape[0]), params["layers"]))
-    return x, pools
+        (x, pools), ys = jax.lax.scan(body, (x, pools), xs)
+        first += n
+        for key, val in ys.items():
+            stats[key] = (val if key not in stats
+                          else jnp.concatenate([stats[key], val]))
+    return x, pools, stats
 
 
 def paged_insert_batch(cache, k_new, v_new, blk_ids, lengths, slots):
@@ -759,6 +830,70 @@ def paged_insert_batch(cache, k_new, v_new, blk_ids, lengths, slots):
     return {"k": k, "v": v, "len": ln}
 
 
+def _llama_ops(cfg: llama.LlamaConfig) -> PagedOps:
+    """``LlamaConfig`` (dense GQA, optionally the capacity-buffer expert
+    FFN) as the paged programs see it."""
+    from kubeflow_tpu.ops.attention import _xla_attention
+
+    inv_freq = jnp.asarray(rope_frequencies(
+        cfg.head_dim, cfg.rope_theta, cfg.rope_scaling,
+        original_max_seq=cfg.max_seq,
+    ))
+
+    def qkv(lp, x, positions):
+        q, k, v = _layer_qkv(lp, x, positions, cfg, inv_freq)
+        return q, {"k": k, "v": v}
+
+    def decode_attention(lp, q, pools, layer, tables, kv_len, kernel, mesh,
+                         interpret):
+        if kernel == "pallas":
+            # block-resident kernel over the carried pool, addressed by
+            # (layer, block): per slot, only the live blocks named by its
+            # table row move HBM->VMEM; no [max_seq] view and no slice of
+            # the pool exists. Under a mesh the call shard_maps over the
+            # heads/KV axis — per-shard pool blocks, replicated tables, no
+            # collectives (quantized scale tables shard on kv-heads with
+            # the pool).
+            from kubeflow_tpu.ops.pallas_paged_attention import (
+                paged_decode_attention_sharded,
+            )
+
+            return paged_decode_attention_sharded(
+                q[:, 0], pools["k"], pools["v"], layer, tables, kv_len,
+                mesh=mesh, interpret=interpret,
+                k_scale=pools.get("k_scale"),
+                v_scale=pools.get("v_scale"))[:, None]
+        k_view, v_view = _gather_views(pools, layer, tables, cfg)
+        return decode_attention_fn(q, k_view, v_view, kv_len)
+
+    def chunk_attention(lp, q, pools, layer, tables, q_start):
+        # the shared GQA causal kernel with traced query offsets: row i
+        # of slot b (absolute position q_start[b]+i) attends kv rows <= it
+        k_view, v_view = _gather_views(pools, layer, tables, cfg)
+        return _xla_attention(q, k_view, v_view, causal=True,
+                              q_offset=q_start)
+
+    def bucket_prefill(params, tokens, lengths, scratch):
+        logits, filled = llama.prefill(params, tokens, cfg, scratch,
+                                       lengths=lengths)
+        return logits, {"k": filled["k"], "v": filled["v"]}
+
+    return PagedOps(
+        n_layers=cfg.n_layers,
+        pool_rows={"k": (cfg.n_kv_heads, cfg.head_dim),
+                   "v": (cfg.n_kv_heads, cfg.head_dim)},
+        layer_stacks=lambda params: [(params["layers"], ())],
+        embed=lambda params, tokens: llama.embed_tokens(params, tokens, cfg),
+        qkv=qkv, decode_attention=decode_attention,
+        chunk_attention=chunk_attention,
+        out=lambda lp, x, o, token_mask: (
+            _layer_out(lp, x, o, cfg, token_mask=token_mask), {}),
+        head=lambda params, x_last: _lm_head(params, x_last, cfg),
+        bucket_prefill=bucket_prefill,
+        bucket_scratch=lambda width, bucket: llama.init_cache(
+            cfg, width, bucket))
+
+
 def _resolve_decode_kernel(kernel: str) -> str:
     """Map the ``kernel=`` switch to an executable path on this backend.
     "auto": pallas on TPU, gather elsewhere. An explicit "pallas" request
@@ -804,66 +939,50 @@ def resolve_decode_kernel(kernel: str, mesh=None,
     return resolved, None
 
 
-def paged_decode_step(params, token, cfg: llama.LlamaConfig, cache, tables,
+def paged_decode_step(params, token, cfg, cache, tables,
                       kernel: str = "gather", mesh=None):
     """One decode step over the paged pool. token: [B] int32; tables:
-    [B, max_blocks_per_seq] int32 -> (logits [B, V], cache). The pools
-    ride the layer loop as a carry and are updated in place (module
+    [B, max_blocks_per_seq] int32 -> (logits [B, V], cache, stats: the
+    layers' counts from ``PagedOps.out``, ``{}`` for a dense model). The
+    pools ride the layer loop as a carry and are updated in place (module
     docstring): donate ``cache`` and nothing pool-sized moves. ``kernel``
     picks the attention path: "gather" | "pallas" | "auto"; with ``mesh``
     the pallas path runs shard_map'd over the heads/KV tensor axis
-    (per-shard pool blocks, replicated tables)."""
+    (per-shard pool blocks, replicated tables). ``cfg`` is any config
+    ``paged_ops`` knows."""
+    ops = paged_ops(cfg)
     kernel, _ = resolve_decode_kernel(kernel, mesh=mesh,
                                       n_kv_heads=cfg.n_kv_heads)
     interpret = jax.default_backend() == "cpu"
     b = token.shape[0]
-    bs = cache["k"].shape[2]
+    bs = cache[next(iter(ops.pool_rows))].shape[2]
     pos = cache["len"]                                   # [B]
     positions = pos[:, None]
-    inv_freq = jnp.asarray(rope_frequencies(
-        cfg.head_dim, cfg.rope_theta, cfg.rope_scaling,
-        original_max_seq=cfg.max_seq,
-    ))
-    x = llama.embed_tokens(params, token[:, None], cfg)
+    x = ops.embed(params, token[:, None])
 
     batch = jnp.arange(b)
     blk = tables[batch, pos // bs]                       # [B] dest block
     off = pos % bs                                       # [B] row in block
+    # idle slots hold len 0: keep their garbage rows out of expert routing
+    token_mask = (pos > 0)[:, None]
 
     def layer_fn(lp, x, pools, layer):
-        q, k, v = _layer_qkv(lp, x, positions, cfg, inv_freq)
-        # scatter this step's KV row into each slot's current block
-        pools = _scatter_kv_rows(pools, layer, blk, off, k[:, 0], v[:, 0])
-        if kernel == "pallas":
-            # block-resident kernel over the carried pool, addressed by
-            # (layer, block): per slot, only the live blocks named by its
-            # table row move HBM->VMEM; no [max_seq] view and no slice of
-            # the pool exists. Under a mesh the call shard_maps over the
-            # heads/KV axis — per-shard pool blocks, replicated tables, no
-            # collectives (quantized scale tables shard on kv-heads with
-            # the pool).
-            from kubeflow_tpu.ops.pallas_paged_attention import (
-                paged_decode_attention_sharded,
-            )
+        q, rows = ops.qkv(lp, x, positions)
+        # scatter this step's row into each slot's current block
+        pools = _scatter_rows(pools, layer, blk, off,
+                              {key: r[:, 0] for key, r in rows.items()})
+        o = ops.decode_attention(lp, q, pools, layer, tables, pos + 1,
+                                 kernel, mesh, interpret)
+        x, stats = ops.out(lp, x, o, token_mask)
+        return x, pools, stats
 
-            o = paged_decode_attention_sharded(
-                q[:, 0], pools["k"], pools["v"], layer, tables, pos + 1,
-                mesh=mesh, interpret=interpret,
-                k_scale=pools.get("k_scale"),
-                v_scale=pools.get("v_scale"))[:, None]
-        else:
-            k_view, v_view = _gather_views(pools, layer, tables, cfg)
-            o = decode_attention(q, k_view, v_view, pos + 1)
-        # idle slots hold len 0: keep their garbage rows out of MoE routing
-        return _layer_out(lp, x, o, cfg,
-                          token_mask=(pos > 0)[:, None]), pools
-
-    x, pools = _scan_layers(params, x, cache, layer_fn)
-    logits = _lm_head(params, x[:, 0], cfg)
-    return logits, {**pools, "len": cache["len"] + 1}
+    x, pools, stats = _scan_layers(ops, params, x, cache, layer_fn)
+    logits = ops.head(params, x[:, 0])
+    cache = {**pools, "len": cache["len"] + 1}
+    return logits, cache, stats
 
 
-def paged_prefill_chunk(params, tokens, cfg: llama.LlamaConfig, cache,
+def paged_prefill_chunk(params, tokens, cfg, cache,
                         tables, slot, offset, length, share_len=0):
     """Chunked prefill straight into the paged pool (vLLM chunked-prefill
     role): processes `tokens` [1, C] as positions offset..offset+C-1 of
@@ -879,17 +998,15 @@ def paged_prefill_chunk(params, tokens, cfg: llama.LlamaConfig, cache,
     rewritten while other slots read them (the re-computed values are
     bit-identical, so attention over the view stays exact either way).
     Returns (x_last [1, D]: the PRE-final-norm hidden state at the
-    chunk's last TRUE row — _lm_head applies final_norm; the caller runs
+    chunk's last TRUE row — the head applies final_norm; the caller runs
     it ONCE on the final chunk's value rather than paying a full-vocab
-    matmul per chunk — and the updated cache). cache["len"] for the slot
-    is NOT advanced here; the engine sets it once after the last chunk
-    (decode masks by len, so partial writes stay invisible)."""
+    matmul per chunk — the updated cache, and the layers' counts as in
+    ``paged_decode_step``). cache["len"] for the slot is NOT advanced
+    here; the engine sets it once after the last chunk (decode masks by
+    len, so partial writes stay invisible)."""
+    ops = paged_ops(cfg)
     _, c = tokens.shape
-    bs = cache["k"].shape[2]
-    inv_freq = jnp.asarray(rope_frequencies(
-        cfg.head_dim, cfg.rope_theta, cfg.rope_scaling,
-        original_max_seq=cfg.max_seq,
-    ))
+    bs = cache[next(iter(ops.pool_rows))].shape[2]
     pos = offset + jnp.arange(c)                          # [C] absolute
     valid = pos < length
     # destination rows: real rows land in the slot's table blocks; pad
@@ -901,27 +1018,27 @@ def paged_prefill_chunk(params, tokens, cfg: llama.LlamaConfig, cache,
         0)
     off = pos % bs
     positions = pos[None, :]
-    x = llama.embed_tokens(params, tokens, cfg)
-
-    from kubeflow_tpu.ops.attention import _xla_attention
+    x = ops.embed(params, tokens)
+    q_start = jnp.reshape(offset, (1,))
 
     def layer_fn(lp, x, pools, layer):
-        q, k, v = _layer_qkv(lp, x, positions, cfg, inv_freq)
-        pools = _scatter_kv_rows(pools, layer, blk, off, k[0], v[0])
-        k_view, v_view = _gather_views(pools, layer, tables[slot][None],
-                                       cfg)
-        # the shared GQA causal kernel with traced query offset: row i
-        # (absolute position offset+i) attends kv rows <= offset+i
-        o = _xla_attention(q, k_view, v_view, causal=True, q_offset=offset)
-        return _layer_out(lp, x, o, cfg, token_mask=valid[None, :]), pools
+        q, rows = ops.qkv(lp, x, positions)
+        pools = _scatter_rows(pools, layer, blk, off,
+                              {key: r[0] for key, r in rows.items()})
+        o = ops.chunk_attention(lp, q, pools, layer, tables[slot][None],
+                                q_start)
+        x, stats = ops.out(lp, x, o, valid[None, :])
+        return x, pools, stats
 
     last_row = jnp.clip(length - offset - 1, 0, c - 1)
-    x, pools = _scan_layers(params, x, cache, layer_fn)
-    return x[:, last_row], {**pools, "len": cache["len"]}
+    x, pools, stats = _scan_layers(ops, params, x, cache, layer_fn)
+    cache = {**pools, "len": cache["len"]}
+    if "experts" in stats:       # [layers, 1, C, k]: the last true row's
+        stats["experts"] = stats["experts"][:, 0, last_row]
+    return x[:, last_row], cache, stats
 
 
-def paged_verify_step(params, tokens, cfg: llama.LlamaConfig, cache,
-                      tables, limit):
+def paged_verify_step(params, tokens, cfg, cache, tables, limit):
     """Batched multi-token target step for speculative decoding: ONE
     dispatch scores ``S`` candidate positions per slot (vLLM/Medusa
     verify role). tokens: [B, S] int32 where column 0 is the slot's last
@@ -941,19 +1058,16 @@ def paged_verify_step(params, tokens, cfg: llama.LlamaConfig, cache,
     cache["len"] is NOT advanced here; the engine commits the accepted
     length host-side after comparing drafts against the argmax chain.
 
-    Attention uses the gather view with per-slot causal offsets (the
-    only multi-query-row path; S is tiny, so this step is compute-
+    Attention uses the model's chunk form with per-slot causal offsets
+    (the only multi-query-row path; S is tiny, so this step is compute-
     shaped like a short prefill, not the bandwidth-bound single-row
     decode the pallas kernel exists for) — under a mesh XLA
     auto-partitions it like the chunked-prefill program.
 
     Returns (logits [B, S, V] f32, cache)."""
+    ops = paged_ops(cfg)
     b, s = tokens.shape
-    bs = cache["k"].shape[2]
-    inv_freq = jnp.asarray(rope_frequencies(
-        cfg.head_dim, cfg.rope_theta, cfg.rope_scaling,
-        original_max_seq=cfg.max_seq,
-    ))
+    bs = cache[next(iter(ops.pool_rows))].shape[2]
     start = cache["len"]                                   # [B]
     pos = start[:, None] + jnp.arange(s)[None, :]          # [B, S] absolute
     valid = pos < limit[:, None]
@@ -964,24 +1078,21 @@ def paged_verify_step(params, tokens, cfg: llama.LlamaConfig, cache,
                jnp.clip(pos // bs, 0, tables.shape[1] - 1)],
         0)
     off = pos % bs
-    x = llama.embed_tokens(params, tokens, cfg)            # [B, S, D]
-
-    from kubeflow_tpu.ops.attention import _xla_attention
+    x = ops.embed(params, tokens)                          # [B, S, D]
 
     def layer_fn(lp, x, pools, layer):
-        q, k, v = _layer_qkv(lp, x, pos, cfg, inv_freq)
+        q, rows = ops.qkv(lp, x, pos)
         # duplicate blk entries (several rows of one slot's block in a
         # single verify) are safe in a quantized pool: quant_scatter_rows
         # folds their amaxes via scatter-max before any content write
-        pools = _scatter_kv_rows(pools, layer, blk, off, k, v)
-        k_view, v_view = _gather_views(pools, layer, tables, cfg)
+        pools = _scatter_rows(pools, layer, blk, off, rows)
         # per-slot query offsets: row s (position start[b]+s) attends kv
         # rows <= start[b]+s — this step's own earlier rows included,
         # every stale/rejected row beyond them masked
-        o = _xla_attention(q, k_view, v_view, causal=True, q_offset=start)
-        return _layer_out(lp, x, o, cfg, token_mask=valid), pools
+        o = ops.chunk_attention(lp, q, pools, layer, tables, start)
+        x, stats = ops.out(lp, x, o, valid)
+        return x, pools, stats
 
-    x, pools = _scan_layers(params, x, cache, layer_fn)
-    logits = _lm_head(params, x.reshape(b * s, x.shape[-1]),
-                      cfg).reshape(b, s, -1)
+    x, pools, _ = _scan_layers(ops, params, x, cache, layer_fn)
+    logits = ops.head(params, x.reshape(b * s, x.shape[-1])).reshape(b, s, -1)
     return logits, {**pools, "len": cache["len"]}

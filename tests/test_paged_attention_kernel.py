@@ -198,9 +198,9 @@ def test_decode_step_block_boundary_crossing():
     tables = jnp.asarray(pk.tables)
     tok = jnp.asarray([5, 0, 9], jnp.int32)
     for _ in range(3):
-        lg, cache_g = paged_kv.paged_decode_step(
+        lg, cache_g, _ = paged_kv.paged_decode_step(
             params, tok, cfg, cache_g, tables, kernel="gather")
-        lp, cache_p = paged_kv.paged_decode_step(
+        lp, cache_p, _ = paged_kv.paged_decode_step(
             params, tok, cfg, cache_p, tables, kernel="pallas")
         np.testing.assert_allclose(np.asarray(lg), np.asarray(lp),
                                    rtol=1e-4, atol=1e-4)
@@ -249,7 +249,7 @@ def test_engine_decode_chunk_equals_single_steps(kernel, quant_kv):
     def run(chunk_len, n):
         c, tok, toks = jax.tree.map(jnp.copy, cache), tok0, []
         for i in range(n):
-            t, _, tok, c = eng._decode(
+            t, _, tok, c, _ = eng._decode(
                 eng.params, tok, c, *args, jax.random.key(i),
                 greedy_only=True, kernel=eng.kernel, chunk_len=chunk_len)
             toks.append(np.asarray(t))
@@ -446,9 +446,9 @@ def test_sharded_decode_step_end_to_end_parity():
     tables = jnp.asarray(pk.tables)
     tok = jnp.asarray([5, 9], jnp.int32)
     for _ in range(3):
-        lg, cache_g = paged_kv.paged_decode_step(
+        lg, cache_g, _ = paged_kv.paged_decode_step(
             params, tok, cfg, cache_g, tables, kernel="gather")
-        lp, cache_p = paged_kv.paged_decode_step(
+        lp, cache_p, _ = paged_kv.paged_decode_step(
             params, tok, cfg, cache_p, tables, kernel="pallas", mesh=mesh)
         np.testing.assert_allclose(np.asarray(lg), np.asarray(lp),
                                    rtol=1e-4, atol=1e-4)

@@ -23,8 +23,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from kubeflow_tpu.ops.pallas_attention import flash_attention
 from kubeflow_tpu.ops.pallas_paged_attention import (
     paged_decode_attention, paged_decode_attention_sharded,
+    paged_latent_decode_attention,
 )
-from kubeflow_tpu.models import llama
+from kubeflow_tpu.models import llama, mla_moe
 from kubeflow_tpu.parallel.aot import topology_devices
 from kubeflow_tpu.serving import paged_kv
 
@@ -171,7 +172,7 @@ def _decode_chunk_hlo(v5e, monkeypatch, quant_kv):
     def chunk(params, token, cache, tables):
         def one_step(carry, _):
             token, cache = carry
-            logits, cache = paged_kv.paged_decode_step(
+            logits, cache, _ = paged_kv.paged_decode_step(
                 params, token, cfg, cache, tables, kernel="pallas")
             return (jnp.argmax(logits, -1).astype(jnp.int32), cache), None
         return jax.lax.scan(one_step, (token, cache), None, length=4)[0]
@@ -197,6 +198,90 @@ def test_decode_chunk_updates_the_pool_in_place(v5e, monkeypatch, quant_kv):
     pool_bytes = 2 * L * 640 * BS * KVH * D * (1 if quant_kv == "int8"
                                                 else 2)
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 16
+
+
+# joyai-llm-flash-l5.longctx-offline (benchmarks/traffic): 24 slots of 416
+# blocks of 64 rows, 7,168 blocks, the published widths
+LATENT = dict(b=24, nb=7168, nbp=416, h=32, row=640, value=512)
+
+
+def test_latent_decode_kernel_at_the_cells_shapes(v5e):
+    """The latent kernel reads [64, 640] blocks of the pool as it is stored
+    (eight through as many BlockSpecs a grid step): Mosaic accepts it, and
+    nothing relays or copies the pool in front of it. A row of 576 does
+    compile too, but XLA then stores the pool in a compact transposed
+    layout and copies all of it before every call (PERF.md, PR 29)."""
+    c = LATENT
+    one = NamedSharding(Mesh(v5e[:1], ("x",)), P())
+    shapes = [((c["b"], c["h"], c["row"]), jnp.bfloat16),
+              ((L, c["nb"], BS, c["row"]), jnp.bfloat16), ((), jnp.int32),
+              ((c["b"], c["nbp"]), jnp.int32), ((c["b"],), jnp.int32)]
+
+    def fn(q, pool, layer, tables, kv_len):
+        return paged_latent_decode_attention(
+            q, pool, layer, tables, kv_len, value_dim=c["value"],
+            scale=192 ** -0.5)
+
+    hlo = _compile(fn, [one] * 5, *shapes)
+    assert hlo.count("tpu_custom_call") == 1
+    assert not paged_kv.pool_shaped_ops(hlo, [shapes[1][0]])
+
+
+def test_latent_decode_chunk_updates_the_pool_in_place(v5e, monkeypatch):
+    """``paged_decode_step`` of the latent / routed-expert model at the
+    cell's engine (1 dense + 2 expert layers of the published widths) under
+    a 4-step scan with the cache donated: nothing pool-sized, the pool's
+    bytes are NB x bs x row (no padded size-1 dimension, no second pool),
+    and no layer's experts are sliced out of their stack."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c = LATENT
+    cfg = mla_moe.MlaMoeConfig(n_layers=3, n_predict_layers=0,
+                               max_seq=c["nbp"] * BS)
+    assert cfg.pool_row == c["row"] and cfg.row_dim == 576
+    one = NamedSharding(Mesh(v5e[:1], ("x",)), P())
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one), tree)
+
+    params = jax.eval_shape(lambda: mla_moe.init_params(
+        jax.random.key(0), cfg, dtype=jnp.bfloat16))
+    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(
+        cfg, c["b"], c["nbp"] * BS, BS, c["nb"]))
+    assert set(cache) == {"kv", "len"}           # ONE pool
+    pool_bytes = 3 * c["nb"] * BS * c["row"] * 2
+    assert cache["kv"].size * 2 == pool_bytes
+
+    def chunk(params, token, cache, tables):
+        def one_step(carry, _):
+            token, cache = carry
+            logits, cache, stats = paged_kv.paged_decode_step(
+                params, token, cfg, cache, tables, kernel="pallas")
+            return (jnp.argmax(logits, -1).astype(jnp.int32), cache), stats
+        return jax.lax.scan(one_step, (token, cache), None, length=4)
+
+    compiled = jax.jit(chunk, donate_argnums=(2,)).lower(
+        on_chip(params),
+        jax.ShapeDtypeStruct((c["b"],), jnp.int32, sharding=one),
+        on_chip(cache),
+        jax.ShapeDtypeStruct((c["b"], c["nbp"]), jnp.int32,
+                             sharding=one)).compile()
+    hlo = compiled.as_text()
+    # the readers find the kernel (a dense and an expert stack: two call
+    # sites) and the grouped products by these names
+    assert len(re.findall(r"%closed_call\.\d+ = \S+ custom-call\(", hlo)) == 2
+    assert len(re.findall(r"%gmm(\.\d+)? = \S+ custom-call\(", hlo)) == 3
+    assert paged_kv.pool_shaped_ops(hlo, [cache["kv"].shape]) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes      # updated in place
+    # neither a copy of the pool nor one of a layer's experts (805 MB a
+    # matrix) among the temporaries
+    assert mem.temp_size_in_bytes < 2 ** 27
+    experts = 256 * 2048 * 768
+    assert not [line for line in hlo.splitlines() if re.search(
+        r" = bf16\[(1,)?256,(2048,768|768,2048)\]\S* (fusion|copy|"
+        r"dynamic-slice)\(", line)], "a layer's experts were sliced out"
+    assert experts * 2 * 3 * 2 * 1.01 < mem.argument_size_in_bytes
 
 
 def test_pool_shaped_ops_finds_what_the_parent_did():

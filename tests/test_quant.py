@@ -237,9 +237,9 @@ def test_decode_step_quant_kernel_vs_quant_gather_lockstep():
     cache_p = jax.tree.map(jnp.copy, cache)
     tok = jnp.asarray([5, 0, 9], jnp.int32)
     for _ in range(3):
-        lg, cache_g = paged_kv.paged_decode_step(
+        lg, cache_g, _ = paged_kv.paged_decode_step(
             params, tok, cfg, cache_g, tables, kernel="gather")
-        lp, cache_p = paged_kv.paged_decode_step(
+        lp, cache_p, _ = paged_kv.paged_decode_step(
             params, tok, cfg, cache_p, tables, kernel="pallas")
         np.testing.assert_allclose(np.asarray(lg), np.asarray(lp),
                                    rtol=1e-4, atol=1e-4)

@@ -225,7 +225,7 @@ def test_verify_step_logits_match_sequential_decode(tiny32):
     chain = [np.asarray(tok)]
     dec_logits = []
     for _ in range(4):
-        lg, cache_d = paged_kv.paged_decode_step(
+        lg, cache_d, _ = paged_kv.paged_decode_step(
             params, tok, cfg, cache_d, tables, kernel="gather")
         dec_logits.append(np.asarray(lg))
         tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)
