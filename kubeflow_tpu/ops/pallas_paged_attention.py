@@ -17,15 +17,27 @@ Design (decode only — one query token per slot):
   pool through its layer loop and updates it in place
   (``serving/paged_kv.py``), and ``pool[layer]`` handed to a custom call
   would be a copy of a layer's pool, every layer.
-- Grid ``(batch, max_blocks_per_seq)``; the layer, the live lengths and
-  the block-table rows ride in as **scalar-prefetch** operands, so the K/V
-  BlockSpec index maps return ``(layer, tables[b, j], 0, 0, 0)`` — the
-  pool block, not the logical position — while the pipeline prefetches.
-- Iterations past a slot's live block count (``ceil(kv_len/block)``, NOT
-  ``max_blocks_per_seq``) are pinned by the index map to the slot's LAST
-  live block: Pallas elides the re-fetch of an unchanged block, so dead
-  tail iterations issue **no DMA and no compute** (`pl.when`-guarded) —
-  per-step HBM traffic is O(live tokens), the paged-attention property.
+- Grid ``(batch,)``: one grid step a slot. The layer, the live lengths
+  and the block-table rows ride in as **scalar-prefetch** operands; the
+  pools stay in HBM (``memory_space=pl.ANY``) and the kernel walks the
+  slot's table itself: a ``fori_loop`` over the slot's LIVE chunks,
+  ``cdiv(cdiv(kv_len, block), G)`` of them, each ``G`` pool blocks copied
+  by ``make_async_copy(pool.at[layer, tables[b, j]], ...)`` into a
+  double-buffered VMEM scratch. The next chunk's copies are started
+  before the current one is waited on, and under a slot's LAST chunk the
+  first chunk of the next live slot, so a slot does not begin with an
+  exposed copy.
+- What is not live costs nothing: an idle slot (``kv_len`` 0) starts no
+  copy and multiplies nothing, a short slot's loop ends at its tail, and
+  no copy is started for a block past it — per-step HBM traffic is
+  O(live tokens), the paged-attention property. (As a grid over
+  ``(batch, max_blocks_per_seq)`` with dead steps pinned to the last live
+  block, 1,180 of 1,280 steps of a call were dead on the chat cell, a
+  sixth of a live step's time each: PERF.md section 6, PR 30.)
+- ``G`` comes from the shapes alone (``_chunk_blocks``: a chunk's K and
+  V as f32 matrices of 2 MiB each, 8 blocks of 64 x 8 x 128): the chip
+  pays a fixed cost a softmax update, and two products over one block are
+  too small to hide it.
 - The pool is read in the layout it is stored in: a block arrives as
   ``(block, KV_H, D)`` and is used as the ``[block * KV_H, D]`` matrix it
   is in memory (merging leading dims of an f32 tile is free). A merged
@@ -33,20 +45,22 @@ Design (decode only — one query token per slot):
   is NOT a free reshape of the pool on the chip — both shapes are tiled
   over their last two dims, so it is a relayout of everything reshaped
   (PR 27: 2 of the 8 whole-pool operations a step).
-- GQA in-kernel, without per-head tiles: ONE ``[H, D] x [D, block*KV_H]``
+- GQA in-kernel, without per-head tiles: ONE ``[H, D] x [D, G*block*KV_H]``
   product scores every query head against every (token, kv head) row of
-  the block and a mask keeps each head's own kv head (column ``c`` is kv
-  head ``c % KV_H``, query head ``h`` belongs to ``h // groups``); the
-  masked probabilities are exactly 0, so ONE ``[H, block*KV_H] x
-  [block*KV_H, D]`` product sums each head's own rows. That is KV_H times
-  the useful MXU work, and free: the kernel moves 1 byte per ~32 flop
-  against a ridge of 240, and on the chip it is as fast as the per-head
-  lane-slice form it replaces and 15% faster than per-head strided
-  sublane reads of the same block (PERF.md §6, PR 27). Each pool block is
-  fetched ONCE per slot; KV heads are never repeated, and no
-  ``[max_seq]`` view ever exists.
-- Online softmax across a slot's blocks (running max / sum / weighted
-  accumulator in VMEM scratch, f32), exactly the flash recurrence the
+  the chunk and a mask keeps each head's own kv head (column ``c`` is kv
+  head ``c % KV_H``, query head ``h`` belongs to ``h // groups``) and the
+  live tokens; the masked probabilities are exactly 0, so ONE ``[H,
+  G*block*KV_H] x [G*block*KV_H, D]`` product sums each head's own rows.
+  That is KV_H times the useful MXU work, and free: the kernel moves 1
+  byte per ~32 flop against a ridge of 240, and on the chip it is as fast
+  as the per-head lane-slice form it replaces and 15% faster than
+  per-head strided sublane reads of the same block (PERF.md §6, PR 27).
+  Each pool block is fetched ONCE per slot; KV heads are never repeated,
+  and no ``[max_seq]`` view ever exists. The value rows past a slot's
+  live tail are zeroed as well as their scores masked: the buffer holds
+  whatever an earlier slot left there, and ``0 x NaN`` is NaN.
+- Online softmax across a slot's chunks (running max / sum / weighted
+  accumulator carried by the loop, f32), exactly the flash recurrence the
   training kernel uses.
 
 ``interpret=True`` runs the identical kernel logic on CPU (tier-1 tests);
@@ -108,78 +122,168 @@ def _live_block(block_size, n_tables, kvlen_ref, tables_ref, bi, j):
     return tables_ref[bi, jc]
 
 
-def _decode_kernel(layer_ref, kvlen_ref, tables_ref, q_ref, k_ref, v_ref,
+def _decode_kernel(layer_ref, kvlen_ref, tables_ref, q_ref, k_hbm, v_hbm,
                    *rest, scale, block_size, kv_heads, groups, head_dim,
-                   quantized=False):
-    del layer_ref                   # consumed by the index maps
+                   chunk_blocks, quantized):
+    """One grid step = one slot: walk its live blocks ``chunk_blocks`` at a
+    time through a double-buffered VMEM scratch, one online-softmax update
+    a chunk. The pools stay in HBM and are addressed by (layer,
+    tables[b, j]) in the copies; a quantized pool's scale tables come as
+    this layer's [1, NB, KV_H], whole in VMEM."""
     if quantized:
-        # quantized pools ride with per-block per-kv-head scale tiles
-        # ([1, 1, 1, KV_H] f32, same index-map clipping as the pool blocks)
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+        ks_ref, vs_ref, o_ref, k_buf, v_buf, sems, state = rest
     else:
-        o_ref, m_ref, l_ref, acc_ref = rest
+        o_ref, k_buf, v_buf, sems, state = rest
         ks_ref = vs_ref = None
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    n_slots = pl.num_programs(0)
+    n_heads = q_ref.shape[1]
+    layer = layer_ref[0]
+    chunk_rows = chunk_blocks * block_size * kv_heads
+
+    def copies(slot, chunk, buf, g):
+        """The copies that bring block ``g`` of a slot's chunk into
+        buffer ``buf`` (built alike to start and to wait for them)."""
+        blk = tables_ref[slot, chunk * chunk_blocks + g]
+        return [pltpu.make_async_copy(k_hbm.at[layer, blk], k_buf.at[buf, g],
+                                      sems.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[layer, blk], v_buf.at[buf, g],
+                                      sems.at[1, buf])]
+
+    def for_live_blocks(slot, chunk, buf, act):
+        """``act`` on the copies of the chunk's LIVE blocks only: nothing
+        past a slot's tail is ever fetched."""
+        n_blocks = pl.cdiv(kvlen_ref[slot], block_size)
+        for g in range(chunk_blocks):
+            @pl.when(chunk * chunk_blocks + g < n_blocks)
+            def _():
+                for copy in copies(slot, chunk, buf, g):
+                    act(copy)
+
+    def start(slot, chunk, buf):
+        for_live_blocks(slot, chunk, buf, lambda copy: copy.start())
+
+    def wait(slot, chunk, buf):
+        for_live_blocks(slot, chunk, buf, lambda copy: copy.wait())
+
+    @pl.when(b == 0)
+    def _reset():
+        state[0] = 0        # the buffer the next chunk computed lies in
+        state[1] = -1       # the slot whose first chunk is already in flight
+
     kv_len = kvlen_ref[b]
-    n_live = pl.cdiv(kv_len, block_size)
+    n_blocks = pl.cdiv(kv_len, block_size)
+    n_chunks = pl.cdiv(n_blocks, chunk_blocks)
 
-    def tile(ref, sc_ref):
-        """One pool block as the [block * KV_H, D] f32 matrix it is in
-        memory (row t * KV_H + g: token t, kv head g), dequantized."""
-        x = ref[0, 0].astype(jnp.float32)                    # [bs, KV_H, D]
-        if sc_ref is not None:
-            # dequant fused into the online-softmax inner loop: the
-            # int8/fp8 tile upcasts and multiplies its block's per-kv-head
-            # scale between DMA and the MXU — the exact per-element
-            # pipeline the gather oracle runs, so kernel-vs-oracle parity
-            # stays bit-for-bit in f32
-            x = x * sc_ref[0, 0, 0][None, :, None]
-        return x.reshape(block_size * kv_heads, head_dim)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        # kv_len >= 1 always (the decode step just wrote this step's row),
-        # but an all-dead slot must still leave defined output
+    @pl.when(kv_len == 0)
+    def _idle():
+        # kv_len >= 1 for every slot the engine reads (the decode step just
+        # wrote this step's row); an idle slot costs no copy and no product
+        # and still leaves defined output
         o_ref[0] = jnp.zeros_like(o_ref[0])
 
-    @pl.when(j < n_live)
-    def _contribute():
-        q = q_ref[0].astype(jnp.float32) * scale             # [H, D]
-        # every query head against every (token, kv head) row of the
-        # block in ONE product, then masked to the head's own kv head:
-        # the block stays in the layout it is stored in (no per-head
-        # strided read, no relayout), and the kernel is bound by memory
-        # (32 flop/byte against a ridge of 240), so the KV_H-fold of
-        # redundant MXU work is free
-        s = jax.lax.dot_general(
-            q, tile(k_ref, ks_ref), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [H, bs*KV_H]
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        own = (col % kv_heads) == (head // groups)
-        live = j * block_size + col // kv_heads < kv_len
-        s = jnp.where(own & live, s, NEG_INF)
+    @pl.when(kv_len > 0)
+    def _live():
+        buf0 = state[0]
 
-        # online-softmax recurrence; m/l scratch is lane-replicated so the
-        # [H, 128] tiles stay aligned (only lane 0 is meaningful)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])          # masked columns: exactly 0
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
-            p, tile(v_ref, vs_ref), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [H, D]
-        m_ref[...], l_ref[...], acc_ref[...] = m_new, l_new, acc
-        # the output block is revisited across j (its index map ignores
-        # j), so writing the normalized running state every live block
-        # costs VMEM traffic only; the last live write is what lands
-        o_ref[0] = (acc / jnp.maximum(l_new[:, :1], 1e-30)).astype(
-            o_ref.dtype)
+        @pl.when(state[1] != b)
+        def _first():               # nobody before this slot fetched for it
+            start(b, 0, buf0)
+
+        # the next slot with anything to read: its first chunk is started
+        # under this slot's last, or every slot would begin with an exposed
+        # copy (a call 116 -> 159 us at the offline cell's occupancy,
+        # PERF.md section 6, PR 30)
+        nxt_slot = jax.lax.while_loop(
+            lambda i: (i < n_slots) & (
+                kvlen_ref[jnp.minimum(i, n_slots - 1)] == 0),
+            lambda i: i + 1, b + 1)
+
+        q = q_ref[0].astype(jnp.float32) * scale             # [H, D]
+        # column c of a chunk's scores is token c // KV_H of the chunk, kv
+        # head c % KV_H; query head h belongs to kv head h // groups. A
+        # foreign head's column gets a token no length reaches.
+        shape = (n_heads, chunk_rows)
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        tok = jnp.where((col % kv_heads) == (head // groups),
+                        col // kv_heads, jnp.int32(2 ** 30))
+
+        def tile(buf_ref, sc_ref, buf, c):
+            """A chunk as the [G * block * KV_H, D] f32 matrix it is in
+            memory (row t * KV_H + g: token t, kv head g), dequantized."""
+            if sc_ref is None:
+                return buf_ref[buf].astype(jnp.float32).reshape(
+                    chunk_rows, head_dim)
+            # dequant between the copy and the MXU, per element as the
+            # gather oracle does it (bit-for-bit parity in f32), each block
+            # by its own row of the layer's scale table
+            blocks = []
+            for g in range(chunk_blocks):
+                j = jnp.minimum(c * chunk_blocks + g, tables_ref.shape[1] - 1)
+                sc = sc_ref[0, pl.ds(tables_ref[b, j], 1), :]    # [1, KV_H]
+                x = buf_ref[buf, g].astype(jnp.float32).reshape(
+                    block_size, kv_heads, head_dim) * sc[0][None, :, None]
+                blocks.append(x.reshape(block_size * kv_heads, head_dim))
+            return jnp.concatenate(blocks, axis=0)
+
+        def chunk_step(c, carry):
+            m_prev, l_prev, acc = carry
+            buf = (buf0 + c) % 2
+            last = c + 1 == n_chunks
+
+            @pl.when(jnp.logical_not(last))
+            def _():
+                start(b, c + 1, 1 - buf)
+
+            @pl.when(last & (nxt_slot < n_slots))
+            def _():
+                start(nxt_slot, 0, 1 - buf)
+
+            wait(b, c, buf)
+            left = kv_len - c * (chunk_blocks * block_size)  # live tokens
+            # every query head against every (token, kv head) row of the
+            # chunk in ONE product, masked to the head's own kv head and
+            # the live tokens: the blocks stay in the layout they are
+            # stored in, and the KV_H-fold of redundant MXU work is free
+            # beside the bytes (32 flop/byte against a ridge of 240)
+            s = jax.lax.dot_general(
+                q, tile(k_buf, ks_ref, buf, c), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [H, rows]
+            s = jnp.where(tok < left, s, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)             # masked columns: exactly 0
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            # rows past the live tail are whatever the buffer held (no copy
+            # of this slot wrote them): their scores are masked above, but
+            # 0 x NaN is NaN in the second product, so their VALUES are
+            # zeroed too (free beside the copies, measured)
+            v = tile(v_buf, vs_ref, buf, c)
+            row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+            v = jnp.where(row < left * kv_heads, v, 0.0)
+            acc = acc * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [H, D]
+            return m_new, l_new, acc
+
+        _, l_fin, acc = jax.lax.fori_loop(
+            0, n_chunks, chunk_step,
+            (jnp.full((n_heads, 1), NEG_INF, jnp.float32),
+             jnp.zeros((n_heads, 1), jnp.float32),
+             jnp.zeros((n_heads, head_dim), jnp.float32)))
+        o_ref[0] = (acc / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
+        state[0] = (buf0 + n_chunks) % 2
+        state[1] = nxt_slot
+
+
+def _chunk_blocks(block_size, kvh, head_dim, n_tables):
+    """Pool blocks a softmax update: the chunk's K and V as f32 matrices
+    (the products' operands) are held to 2 MiB each, beside which the two
+    double-buffered copies in the pool's dtype and the [H, rows] scores
+    fit the default scoped VMEM. A tile pads the kv-head dim to 8."""
+    block_f32 = block_size * -(-kvh // 8) * 8 * head_dim * 4
+    return max(1, min(n_tables, (2 * 2 ** 20) // block_f32))
 
 
 def paged_decode_attention(q, k_pool, v_pool, layer, tables, kv_len, *,
@@ -196,16 +300,15 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, kv_len, *,
     INCLUDING this step. Returns [B, H, D] in q.dtype.
 
     The caller never slices ``pool[layer]`` (a copy of a layer's pool,
-    every layer): the layer rides as a scalar-prefetch operand beside the
-    lengths and the tables and the K/V index maps return
-    ``(layer, tables[b, j], ...)``.
+    every layer): the pools are handed over whole and stay in HBM, the
+    layer rides as a scalar-prefetch operand beside the lengths and the
+    tables, and the kernel copies ``pool[layer, tables[b, j]]`` itself.
 
     k_scale/v_scale: [L, num_blocks, KV_H] f32 per-block per-kv-head
     scales of an int8/fp8-quantized pool (both or neither). When given,
-    each fetched pool tile dequants (upcast * scale) inside the online-
-    softmax inner loop — the scale tiles ride the same scalar-prefetch
-    index map as the pool blocks, so dead-tail iterations elide their
-    DMA too.
+    the layer's scale table rides whole in VMEM and each copied block
+    dequants by its own row of it (upcast * scale) between the copy and
+    the products.
     """
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
@@ -215,61 +318,58 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, kv_len, *,
         raise ValueError(f"head_dim mismatch: q has {d}, pool has {d_k}")
     if h % kvh:
         raise ValueError(f"H={h} not a multiple of KV_H={kvh}")
-    groups = h // kvh
     n_tables = tables.shape[1]
+    chunk = _chunk_blocks(block_size, kvh, d, n_tables)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     kv_len = kv_len.astype(jnp.int32)
     tables = tables.astype(jnp.int32)
 
-    def kv_map(bi, j, layer_ref, kvlen_ref, tables_ref):
-        # past the live tail, pin to the last live block: the unchanged
-        # block index elides the DMA (idle slots pin to block 0, fetched
-        # once)
-        n_live = pl.cdiv(kvlen_ref[bi], block_size)
-        jc = jnp.clip(jnp.minimum(j, n_live - 1), 0, n_tables - 1)
-        return (layer_ref[0], tables_ref[bi, jc], 0, 0, 0)
-
-    def scale_map(*idx):
-        return kv_map(*idx)[:4]
-
-    in_specs = [
-        pl.BlockSpec((1, h, d), lambda bi, j, *_: (bi, 0, 0)),
-        pl.BlockSpec((1, 1, block_size, kvh, d), kv_map),
-        pl.BlockSpec((1, 1, block_size, kvh, d), kv_map),
-    ]
+    if kvh * k_pool.dtype.itemsize % 4:
+        # a kv-head dim that does not fill a 32-bit sublane (int8 at
+        # tensor = 4: 2 heads a shard) is padded in memory, and a copy
+        # cannot name the padding: such a pool is read with a block's
+        # (token, kv head) rows merged (ROADMAP A3d: a relayout of it)
+        k_pool, v_pool = (p.reshape(n_layers, num_blocks, block_size * kvh, d)
+                          for p in (k_pool, v_pool))
+    whole = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, h, d), lambda bi, *_: (bi, 0, 0)),
+                whole, whole]
     args = (layer, kv_len, tables, q, k_pool, v_pool)
+    scratch = [
+        pltpu.VMEM((2, chunk) + k_pool.shape[2:], k_pool.dtype),
+        pltpu.VMEM((2, chunk) + v_pool.shape[2:], v_pool.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),         # [K | V, buffer]
+        pltpu.SMEM((2,), jnp.int32),             # carried from slot to slot
+    ]
     if k_scale is not None:
         if k_scale.shape != (n_layers, num_blocks, kvh):
             raise ValueError(f"k_scale shape {k_scale.shape} != "
                              f"{(n_layers, num_blocks, kvh)}")
-        # [L, NB, 1, KV_H]: Mosaic wants a block's last two dims to be
-        # (8, 128) multiples or the array's own, and one block's (1, KV_H)
-        # row is neither inside [NB, KV_H]
-        in_specs += [pl.BlockSpec((1, 1, 1, kvh), scale_map),
-                     pl.BlockSpec((1, 1, 1, kvh), scale_map)]
-        args += tuple(
-            s.astype(jnp.float32).reshape(n_layers, num_blocks, 1, kvh)
-            for s in (k_scale, v_scale))
+        # the layer's scale table rides whole in VMEM (fetched once a call:
+        # its block index never changes); a block's row is read from it
+        in_specs += [pl.BlockSpec(
+            (1, num_blocks, kvh),
+            lambda bi, layer_ref, *_: (layer_ref[0], 0, 0))] * 2
+        args += (k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, n_tables),
+        grid=(b,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, d), lambda bi, j, *_: (bi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, 128), jnp.float32),   # running max (lane-repl.)
-            pltpu.VMEM((h, 128), jnp.float32),   # running sum
-            pltpu.VMEM((h, d), jnp.float32),     # running weighted values
-        ],
+        out_specs=pl.BlockSpec((1, h, d), lambda bi, *_: (bi, 0, 0)),
+        scratch_shapes=scratch,
     )
     kernel = functools.partial(
         _decode_kernel, scale=1.0 / (d ** 0.5), block_size=block_size,
-        kv_heads=kvh, groups=groups, head_dim=d,
+        kv_heads=kvh, groups=h // kvh, head_dim=d, chunk_blocks=chunk,
         quantized=k_scale is not None)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        # slots in order: a slot starts the next one's first copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*args)
 
@@ -337,9 +437,11 @@ def paged_latent_decode_attention(q, pool, layer, tables, kv_len, *,
     value_dim] in q.dtype. ``W_uk`` is applied before and ``W_uv`` after,
     outside.
 
-    The same walk as the GQA kernel (layer, lengths and tables by scalar
-    prefetch, dead tail pinned to the last live block, online softmax),
-    with two differences the shape asks for. A block is the ``[bs, R]``
+    The GQA kernel's operands (layer, lengths and tables by scalar
+    prefetch) and online softmax, over a grid of (slot, table entries)
+    with the dead tail pinned to the slot's last live block (the walk
+    the GQA kernel had until it took the loop inside), with two
+    differences the shape asks for. A block is the ``[bs, R]``
     matrix it is in memory, used whole by every head. And one grid step
     reads up to 32 blocks, each through its own BlockSpec on the same
     pool, and multiplies them as one matrix: a block is bs x R x 2 bytes

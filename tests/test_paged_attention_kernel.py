@@ -16,7 +16,9 @@ import pytest
 
 from kubeflow_tpu.models import llama
 from kubeflow_tpu.ops.attention import decode_attention
-from kubeflow_tpu.ops.pallas_paged_attention import paged_decode_attention
+from kubeflow_tpu.ops.pallas_paged_attention import (
+    _chunk_blocks, paged_decode_attention,
+)
 from kubeflow_tpu.serving import paged_kv
 
 
@@ -167,6 +169,100 @@ def test_layer_addressed_kernel_reads_its_own_layer(pool_dtype, layer):
             np.testing.assert_allclose(got, ref, rtol=2e-2, atol=2e-2)
         else:
             assert np.abs(got - ref).max() > 0.1
+
+
+# blocks of the serving cells' shape (64 tokens x 8 kv heads x 128) under a
+# table of 20, so that the chunk the wrapper derives is shorter than the table
+CELL_BS, CELL_KVH, CELL_D, CELL_NBP = 64, 8, 128, 20
+
+
+def _cell_block_case(pool_dtype, key, kv_len):
+    """A batch of the lengths ``kv_len(G)`` over a 2-layer pool of the
+    cells' blocks, G the blocks a chunk of the kernel's walk."""
+    bs, kvh, d, nbp = CELL_BS, CELL_KVH, CELL_D, CELL_NBP
+    g = _chunk_blocks(bs, kvh, d, nbp)
+    assert 1 < g and 2 * g <= nbp, "the table must hold two chunks and more"
+    kv_len = kv_len(g)
+    dtype = jnp.float32 if pool_dtype == "f32" else jnp.bfloat16
+    q, kp, vp, tables, kvl = _pool_case(
+        key, b=len(kv_len), h=16, kvh=kvh, d=d, bs=bs, nbp=nbp,
+        kv_len=kv_len, dtype=dtype, layers=2,
+        num_blocks=sum(-(-n // bs) for n in kv_len) + 1)
+    return q, kp, vp, tables, kvl
+
+
+@pytest.mark.parametrize("pool_dtype", ["f32", "bf16", "int8"])
+def test_lengths_on_every_boundary_of_the_walk(pool_dtype):
+    """A slot is walked in chunks of G pool blocks, G from the shapes:
+    idle, one token, a block, a block and one, a chunk, a chunk and one,
+    one short of two chunks, the table's end — mixed in one batch, read
+    from layer 1 of the pool."""
+    bs = CELL_BS
+    q, kp, vp, tables, kvl = _cell_block_case(
+        pool_dtype, jax.random.key(30),
+        lambda g: [0, 1, bs, bs + 1, g * bs, g * bs + 1, 2 * g * bs - 1,
+                   CELL_NBP * bs])
+    scales = {}
+    kd, vd = kp, vp
+    if pool_dtype == "int8":
+        (kp, ks), (vp, vs) = _quantize_pool(kp), _quantize_pool(vp)
+        scales = dict(k_scale=ks, v_scale=vs)
+        kd, vd = _dequant(kp, ks), _dequant(vp, vs)
+    out = paged_decode_attention(q, kp, vp, jnp.int32(1), tables, kvl,
+                                 interpret=True, **scales)
+    assert out.dtype == q.dtype and bool(jnp.isfinite(out).all())
+    live = np.asarray(kvl) > 0
+    tol = 2e-5 if pool_dtype == "f32" else 2e-2
+    got = np.asarray(out.astype(jnp.float32))[live]
+    for layer in (0, 1):
+        ref = np.asarray(_gather_ref(q.astype(jnp.float32), kd, vd, layer,
+                                     tables, kvl).astype(jnp.float32))[live]
+        if layer == 1:
+            np.testing.assert_allclose(got, ref, rtol=tol, atol=tol)
+        else:
+            assert np.abs(got - ref).max() > 0.05
+
+
+@pytest.mark.parametrize("pool_dtype", ["f32", "int8"])
+def test_dead_blocks_are_never_read_and_stale_rows_never_count(pool_dtype):
+    """Every pool block that no LIVE table entry names is NaN (block 0,
+    which the dead entries name, among them; for an int8 pool its scales
+    are), and short slots follow long ones, so the buffer they are copied
+    into still holds the long slot's blocks beyond their own: the output
+    is finite and the oracle's on the clean pool."""
+    bs = CELL_BS
+    q, kp, vp, tables, kvl = _cell_block_case(
+        pool_dtype, jax.random.key(31),
+        lambda g: [2 * g * bs, 3, 0, CELL_NBP * bs, bs + 1, (g + 1) * bs, 1])
+    named = np.zeros(kp.shape[1], bool)
+    for s, n in enumerate(np.asarray(kvl)):
+        named[np.asarray(tables)[s, :-(-int(n) // bs)]] = True
+    assert not named[0] and named[1:].all()
+    # some blocks beyond the named ones too
+    pad = [(0, 0), (0, 3)] + [(0, 0)] * 3
+    kp, vp = jnp.pad(kp, pad), jnp.pad(vp, pad)
+    dead = jnp.asarray(np.concatenate([~named, [True] * 3]))
+    scales = {}
+    if pool_dtype == "int8":
+        (kq, ks), (vq, vs) = _quantize_pool(kp), _quantize_pool(vp)
+        ref = _gather_ref(q, _dequant(kq, ks), _dequant(vq, vs), 1, tables,
+                          kvl)
+        poison = dead[None, :, None]
+        scales = dict(k_scale=jnp.where(poison, jnp.nan, ks),
+                      v_scale=jnp.where(poison, jnp.nan, vs))
+        kp, vp = kq, vq
+    else:
+        ref = _gather_ref(q, kp, vp, 1, tables, kvl)
+        poison = dead[None, :, None, None, None]
+        kp, vp = jnp.where(poison, jnp.nan, kp), jnp.where(poison, jnp.nan, vp)
+    out = paged_decode_attention(q, kp, vp, jnp.int32(1), tables, kvl,
+                                 interpret=True, **scales)
+    assert bool(jnp.isfinite(out).all())
+    live = np.asarray(kvl) > 0
+    tol = 2e-5 if pool_dtype == "f32" else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(out.astype(jnp.float32))[live],
+        np.asarray(ref.astype(jnp.float32))[live], rtol=tol, atol=tol)
 
 
 def test_rejects_bad_shapes():

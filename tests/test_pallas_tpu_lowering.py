@@ -35,7 +35,11 @@ NB = B * NBP + 1
 L = 4                                    # layers in the pool
 # the serving cells' engines (benchmarks/traffic/*.json)
 CELLS = {"chat": dict(h=32, b=32, nb=545, nbp=40),
-         "offline": dict(h=16, b=36, nb=640, nbp=24)}
+         "offline": dict(h=16, b=36, nb=640, nbp=24),
+         # long tables (8 x 8,192 tokens; 8 x 26,624, the longctx cell's
+         # max_seq): the kernel's chunk is derived from the shapes
+         "long128": dict(h=32, b=8, nb=8 * 128 + 1, nbp=128),
+         "long416": dict(h=32, b=8, nb=8 * 416 + 1, nbp=416)}
 
 
 @pytest.fixture(scope="module")
@@ -127,7 +131,8 @@ def test_paged_decode_sharded_over_four_chips(v5e, quantized):
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_paged_decode_at_the_cells_shapes(v5e, cell, variant):
     """The layer-addressed kernel reads (bs, KV, D) blocks of the pool as
-    it is stored, at both serving cells' heads, batch, tables and pool."""
+    it is stored, at both serving cells' heads, batch, tables and pool,
+    and where a slot's table is hundreds of blocks long."""
     quantized = variant == "int8"
     shapes = _paged_shapes(jnp.int8 if quantized else jnp.bfloat16,
                            **CELLS[cell])
