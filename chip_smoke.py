@@ -236,11 +236,11 @@ def leg_check(a) -> dict:
 
     from kubeflow_tpu.models import hf_llama, llama
     from kubeflow_tpu.ops.attention import decode_attention
+    from kubeflow_tpu.ops.paged_pool import dequant_gather_view
     from kubeflow_tpu.ops.pallas_paged_attention import (
         paged_decode_attention_sharded,
     )
     from kubeflow_tpu.parallel import MeshConfig, build_mesh
-    from kubeflow_tpu.serving.paged_kv import dequant_gather_view
     from kubeflow_tpu.utils import compile_cache
 
     size = SIZES[a.size]

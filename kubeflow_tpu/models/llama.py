@@ -21,14 +21,17 @@ requires, sized for the BASELINE.json configs (Llama-3-8B serving, 70B FSDP).
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from kubeflow_tpu.ops.attention import attention, decode_attention
+from kubeflow_tpu.models.paged import PagedOps
+from kubeflow_tpu.ops.attention import (
+    _xla_attention, attention, decode_attention,
+)
 from kubeflow_tpu.ops.norms import rms_norm
+from kubeflow_tpu.ops.paged_pool import gather_views
 from kubeflow_tpu.ops.rotary import apply_rope, rope_frequencies
 from kubeflow_tpu.parallel.sharding import constrain
 
@@ -83,6 +86,10 @@ class LlamaConfig:
             dim=self.dim, mlp_dim=self.mlp_dim, n_experts=self.n_experts,
             top_k=self.moe_top_k, capacity_factor=self.moe_capacity_factor,
             dtype=self.dtype)
+
+    def paged_ops(self) -> PagedOps:
+        """The model as the serving programs see it (``_paged_ops``)."""
+        return _paged_ops(self)
 
 
 def llama3_8b(**kw) -> LlamaConfig:
@@ -201,48 +208,71 @@ def param_logical_axes(cfg: LlamaConfig):
 
 
 # ---------------------------------------------------------------------------
-# int8 weight serving (serving/quant.py quantizes the tree; these helpers
-# are the per-tile dequant the serving call sites share)
+# The layer's pieces (shared by forward, the pipeline stages and the paged
+# serving programs). Each handles the int8 weight tree (serving/quant.py:
+# ``name_q`` int8 + ``name_s`` f32 per-output-channel scales) by KEY
+# PRESENCE, once; the quant-off expression is the plain einsum, so an
+# unquantized tree traces the program it always did. ``constrain`` is the
+# trainer's activation-sharding hook (``parallel.sharding.constrain`` from
+# ``_block``); the serving programs pass none.
 # ---------------------------------------------------------------------------
 
+def _unconstrained(x, names):
+    return x
+
+
 def qmm(spec, x, tree, name, cfg: LlamaConfig):
-    """Matmul over an int8-quantized weight ``name`` (``name_q`` int8 +
-    ``name_s`` f32 per-output-channel scales in ``tree``): the HBM read
-    is one byte per param, the tile upcasts to the compute dtype inside
-    the fused einsum, and the scales multiply the OUTPUT tile — a dense
-    dequantized weight never exists."""
+    """Matmul over an int8-quantized weight ``name``: the HBM read is one
+    byte per param, the tile upcasts to the compute dtype inside the fused
+    einsum, and the scales multiply the OUTPUT tile — a dense dequantized
+    weight never exists."""
     out = jnp.einsum(spec, x, tree[name + "_q"].astype(cfg.dtype))
     return out * tree[name + "_s"].astype(cfg.dtype)
 
 
-def embed_tokens(params, tokens, cfg: LlamaConfig):
-    """Embedding lookup, quant-aware: int8 tables dequant the gathered
-    rows with their per-vocab-row scale. The unquantized branch is the
-    exact expression the call sites used before — the quant-off program
-    stays bitwise-identical."""
+def rope_inv_freq(cfg: LlamaConfig):
+    return jnp.asarray(rope_frequencies(
+        cfg.head_dim, cfg.rope_theta, cfg.rope_scaling,
+        original_max_seq=cfg.max_seq,
+    ))
+
+
+def embed_tokens(params, tokens, cfg: LlamaConfig, constrain=_unconstrained):
+    """Embedding lookup; int8 tables dequant the gathered rows with their
+    per-vocab-row scale."""
     if "embed_q" in params:
         rows = params["embed_q"].astype(cfg.dtype)[tokens]
         return rows * params["embed_s"].astype(cfg.dtype)[tokens][..., None]
-    return params["embed"].astype(cfg.dtype)[tokens]
+    # SPMD-clean under the trainer's mesh: a row gather from the
+    # (vocab=tensor, embed=fsdp)-sharded table makes the partitioner emit
+    # an "involuntary full rematerialization" of the [B,S,D] activation (it
+    # can't reshard gather output efficiently). Explicitly replicating the
+    # bf16-cast table first makes the gather local and the batch/seq
+    # partition a free slice — the same table all-gather XLA's fallback
+    # pays, minus the (much larger) activation replication.
+    table = constrain(params["embed"].astype(cfg.dtype), (None, None))
+    return table[tokens]
 
 
-def quant_head_logits(params, x, cfg: LlamaConfig):
-    """LM-head matmul over the int8 tree: tied embeddings reuse the
-    embedding table (its per-vocab-ROW scales become per-output-channel
-    scales of the transposed head); untied heads carry their own
-    per-vocab-channel scales. x: [..., D] -> [..., V] compute dtype."""
-    if cfg.tie_embeddings:
-        out = jnp.einsum("...d,dv->...v", x,
-                         params["embed_q"].T.astype(cfg.dtype))
-        return out * params["embed_s"].astype(cfg.dtype)
-    out = jnp.einsum("...d,dv->...v", x,
-                     params["lm_head_q"].astype(cfg.dtype))
-    return out * params["lm_head_s"].astype(cfg.dtype)
+def attention_inputs(lp, x, positions, cfg: LlamaConfig, inv_freq,
+                     constrain=_unconstrained):
+    """Norm, the three projections, rope: x [B, S, D] at ``positions``
+    [B|1, S] -> (q [B, S, H, hd] rotated, k [B, S, KV, hd] rotated, v)."""
+    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    if "wq_q" in lp:
+        q = qmm("bsd,dhk->bshk", h, lp, "wq", cfg)
+        k = qmm("bsd,dhk->bshk", h, lp, "wk", cfg)
+        v = qmm("bsd,dhk->bshk", h, lp, "wv", cfg)
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"].astype(cfg.dtype))
+        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"].astype(cfg.dtype))
+        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"].astype(cfg.dtype))
+    q = constrain(q, ("batch", "seq", "act_heads", None))
+    k = constrain(k, ("batch", "seq", None, None))
+    q = apply_rope(q, positions, inv_freq)
+    k = apply_rope(k, positions, inv_freq)
+    return q, k, v
 
-
-# ---------------------------------------------------------------------------
-# Forward
-# ---------------------------------------------------------------------------
 
 def _ffn(h, lp, cfg: LlamaConfig, token_mask=None):
     """FFN half of a block on the normed input h: (delta, aux_loss_scalar).
@@ -270,17 +300,46 @@ def _ffn(h, lp, cfg: LlamaConfig, token_mask=None):
     return down, jnp.zeros((), jnp.float32)
 
 
+def attention_out_and_ffn(lp, x, o, cfg: LlamaConfig, token_mask=None,
+                          constrain=_unconstrained):
+    """What follows attention: ``W_o``, the residual, the FFN and its
+    residual. o: [B, S, H, hd]. Returns (x, aux_loss_scalar)."""
+    if "wo_q" in lp:
+        o = qmm("bshk,hkd->bsd", o, lp, "wo", cfg)
+    else:
+        o = jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(cfg.dtype))
+    x = x + constrain(o, ("batch", "seq", "act_embed"))
+    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    down, aux = _ffn(h, lp, cfg, token_mask=token_mask)
+    return x + constrain(down, ("batch", "seq", "act_embed")), aux
+
+
+def head_logits(params, x, cfg: LlamaConfig):
+    """Final norm and the LM head: x [..., D] before the norm -> logits
+    [..., V] in the compute dtype. Tied embeddings reuse the table; in the
+    int8 tree its per-vocab-ROW scales become per-output-channel scales of
+    the transposed head, and an untied head carries its own."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    name = "embed" if cfg.tie_embeddings else "lm_head"
+    quantized = "embed_q" in params
+    head = params[name + "_q"] if quantized else params[name]
+    if cfg.tie_embeddings:
+        head = head.T
+    logits = jnp.einsum("...d,dv->...v", x, head.astype(cfg.dtype))
+    if quantized:
+        logits = logits * params[name + "_s"].astype(cfg.dtype)
+    return logits
+
+
+# ---------------------------------------------------------------------------
+# Forward
+# ---------------------------------------------------------------------------
+
 def _block(x, lp, inv_freq, positions, cfg: LlamaConfig, mesh=None):
-    """One transformer block. x: [B,S,D] in compute dtype.
-    Returns (x, aux_loss_scalar)."""
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"].astype(cfg.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"].astype(cfg.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"].astype(cfg.dtype))
-    q = constrain(q, ("batch", "seq", "act_heads", None))
-    k = constrain(k, ("batch", "seq", None, None))
-    q = apply_rope(q, positions, inv_freq)
-    k = apply_rope(k, positions, inv_freq)
+    """One transformer block as the trainer runs it: the pieces with the
+    activation constraints and the configured attention between them.
+    x: [B,S,D] in compute dtype. Returns (x, aux_loss_scalar)."""
+    q, k, v = attention_inputs(lp, x, positions, cfg, inv_freq, constrain)
     if cfg.attn_impl in ("ring", "ulysses"):
         from kubeflow_tpu.parallel.ring_attention import (
             ring_attention, ulysses_attention,
@@ -293,12 +352,7 @@ def _block(x, lp, inv_freq, positions, cfg: LlamaConfig, mesh=None):
     else:
         o = attention(q, k, v, causal=True, impl=cfg.attn_impl,
                       block_q=cfg.attn_block, block_kv=cfg.attn_block)
-    o = jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(cfg.dtype))
-    x = x + constrain(o, ("batch", "seq", "act_embed"))
-
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    down, aux = _ffn(h, lp, cfg)
-    return x + constrain(down, ("batch", "seq", "act_embed")), aux
+    return attention_out_and_ffn(lp, x, o, cfg, constrain=constrain)
 
 
 def _remat_wrap(fn, cfg: LlamaConfig):
@@ -321,19 +375,8 @@ def forward(params, tokens, cfg: LlamaConfig, positions=None, mesh=None,
     """
     if positions is None:
         positions = jnp.arange(tokens.shape[1])[None, :]
-    inv_freq = jnp.asarray(rope_frequencies(
-        cfg.head_dim, cfg.rope_theta, cfg.rope_scaling,
-        original_max_seq=cfg.max_seq,
-    ))
-    # Embedding lookup, SPMD-clean: a row gather from the (vocab=tensor,
-    # embed=fsdp)-sharded table makes the partitioner emit an "involuntary
-    # full rematerialization" of the [B,S,D] activation (it can't reshard
-    # gather output efficiently). Explicitly replicating the bf16-cast
-    # table first makes the gather local and the batch/seq partition a
-    # free slice — the same table all-gather XLA's fallback pays, minus
-    # the (much larger) activation replication, and warning-free.
-    table = constrain(params["embed"].astype(cfg.dtype), (None, None))
-    x = table[tokens]
+    inv_freq = rope_inv_freq(cfg)
+    x = embed_tokens(params, tokens, cfg, constrain)
     x = constrain(x, ("batch", "seq", "act_embed"))
 
     block = _remat_wrap(
@@ -341,10 +384,7 @@ def forward(params, tokens, cfg: LlamaConfig, positions=None, mesh=None,
     )
     x, aux_per_layer = jax.lax.scan(block, x, params["layers"])
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
-    logits = constrain(logits, ("batch", "seq", None))
+    logits = constrain(head_logits(params, x, cfg), ("batch", "seq", None))
     logits = logits.astype(jnp.float32)
     if return_aux:
         return logits, {"moe_aux": jnp.sum(aux_per_layer)}
@@ -352,143 +392,93 @@ def forward(params, tokens, cfg: LlamaConfig, positions=None, mesh=None,
 
 
 # ---------------------------------------------------------------------------
-# KV-cached decoding (serving path)
+# The serving programs' view of the model (models/paged.PagedOps)
 # ---------------------------------------------------------------------------
 
-def init_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None):
-    dtype = dtype or cfg.dtype
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {
-        "k": jnp.zeros(shape, dtype),
-        "v": jnp.zeros(shape, dtype),
-        "len": jnp.zeros((batch,), jnp.int32),
-    }
+def bucket_prefill(params, tokens, lengths, cfg: LlamaConfig):
+    """A whole bucket of prompts in one causal pass. tokens: [B, S]
+    left-aligned, right-padded; ``lengths`` [B] int32 each prompt's true
+    length. Returns (logits [B, V] f32 at position ``lengths - 1``, the
+    layers' rows ``{"k", "v"}: [L, B, S, KV, hd]`` for
+    ``paged_insert_batch``). Rows beyond a prompt's length hold garbage
+    and are never attended (the insert skips or masks them, decode masks
+    to the slot's length and overwrites them one position at a time)."""
+    positions = jnp.arange(tokens.shape[1])[None, :]
+    inv_freq = rope_inv_freq(cfg)
+    token_mask = positions < lengths[:, None]
+    # "ring"/"ulysses" are training-only context-parallel paths; prefill
+    # falls back to the first-party pallas kernel for those — O(S) memory,
+    # CPU-interpretable
+    impl = cfg.attn_impl if cfg.attn_impl in ("xla", "flash", "pallas") \
+        else "pallas"
 
-
-def prefill(params, tokens, cfg: LlamaConfig, cache, lengths=None):
-    """Run the prompt through the model, filling the cache.
-
-    tokens: [B,S] left-aligned, right-padded. ``lengths`` ([B] int32, default
-    S) gives each prompt's true length: logits are read at position
-    ``lengths-1`` and ``cache["len"]`` is set per sequence, so the
-    continuous-batching engine can prefill padded buckets. Pad rows beyond a
-    sequence's length hold garbage KV but are never attended (decode masks to
-    cache len and overwrites them one position at a time).
-    """
-    b, s = tokens.shape
-    if lengths is None:
-        lengths = jnp.full((b,), s, jnp.int32)
-    positions = jnp.arange(s)[None, :]
-    inv_freq = jnp.asarray(rope_frequencies(
-        cfg.head_dim, cfg.rope_theta, cfg.rope_scaling,
-        original_max_seq=cfg.max_seq,
-    ))
-    x = embed_tokens(params, tokens, cfg)
-
-    def block(x, xs):
-        lp, k_cache_l, v_cache_l = xs
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        if "wq_q" in lp:
-            q = qmm("bsd,dhk->bshk", h, lp, "wq", cfg)
-            k = qmm("bsd,dhk->bshk", h, lp, "wk", cfg)
-            v = qmm("bsd,dhk->bshk", h, lp, "wv", cfg)
-        else:
-            q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"].astype(cfg.dtype))
-            k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"].astype(cfg.dtype))
-            v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"].astype(cfg.dtype))
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
-        # honor the configured impl ("ring"/"ulysses" are training-only
-        # context-parallel paths; prefill falls back to the first-party
-        # pallas kernel for those — O(S) memory, CPU-interpretable)
-        impl = cfg.attn_impl if cfg.attn_impl in ("xla", "flash", "pallas") \
-            else "pallas"
+    def layer(x, lp):
+        q, k, v = attention_inputs(lp, x, positions, cfg, inv_freq)
         o = attention(q, k, v, causal=True, impl=impl,
                       block_q=cfg.attn_block, block_kv=cfg.attn_block)
-        if "wo_q" in lp:
-            o = qmm("bshk,hkd->bsd", o, lp, "wo", cfg)
-        else:
-            o = jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(cfg.dtype))
-        x = x + o
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        down, _ = _ffn(h, lp, cfg, token_mask=positions < lengths[:, None])
-        x = x + down
-        new_k = jax.lax.dynamic_update_slice(
-            k_cache_l, k.astype(k_cache_l.dtype), (0, 0, 0, 0)
-        )
-        new_v = jax.lax.dynamic_update_slice(
-            v_cache_l, v.astype(v_cache_l.dtype), (0, 0, 0, 0)
-        )
-        return x, (new_k, new_v)
+        x, _ = attention_out_and_ffn(lp, x, o, cfg, token_mask=token_mask)
+        return x, (k, v)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        block, x, (params["layers"], cache["k"], cache["v"])
-    )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x, (k, v) = jax.lax.scan(layer, embed_tokens(params, tokens, cfg),
+                             params["layers"])
     last = jnp.take_along_axis(
         x, jnp.maximum(lengths - 1, 0)[:, None, None].astype(jnp.int32),
         axis=1,
     )[:, 0]
-    if "embed_q" in params:
-        logits = quant_head_logits(params, last, cfg)
-    else:
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"])
-        logits = jnp.einsum("bd,dv->bv", last, head.astype(cfg.dtype))
-    cache = {"k": new_k, "v": new_v, "len": lengths.astype(jnp.int32)}
-    return logits.astype(jnp.float32), cache
+    return head_logits(params, last, cfg).astype(jnp.float32), \
+        {"k": k, "v": v}
 
 
-def decode_step(params, token, cfg: LlamaConfig, cache):
-    """One decode step. token: [B] int32 -> (logits [B,V], cache)."""
-    b = token.shape[0]
-    pos = cache["len"]  # [B]
-    positions = pos[:, None]
-    inv_freq = jnp.asarray(rope_frequencies(
-        cfg.head_dim, cfg.rope_theta, cfg.rope_scaling,
-        original_max_seq=cfg.max_seq,
-    ))
-    x = embed_tokens(params, token[:, None], cfg)
+def _paged_ops(cfg: LlamaConfig) -> PagedOps:
+    """Dense GQA (optionally the capacity-buffer expert FFN) as the paged
+    programs see it."""
+    inv_freq = rope_inv_freq(cfg)
 
-    def block(x, xs):
-        lp, k_cache_l, v_cache_l = xs
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        if "wq_q" in lp:
-            q = qmm("bsd,dhk->bshk", h, lp, "wq", cfg)
-            k = qmm("bsd,dhk->bshk", h, lp, "wk", cfg)
-            v = qmm("bsd,dhk->bshk", h, lp, "wv", cfg)
-        else:
-            q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"].astype(cfg.dtype))
-            k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"].astype(cfg.dtype))
-            v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"].astype(cfg.dtype))
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
-        # scatter the new KV row at each sequence's current length
-        idx = pos[:, None, None, None]
-        onehot = (jnp.arange(k_cache_l.shape[1])[None, :, None, None] == idx)
-        new_k = jnp.where(onehot, k.astype(k_cache_l.dtype), k_cache_l)
-        new_v = jnp.where(onehot, v.astype(v_cache_l.dtype), v_cache_l)
-        o = decode_attention(q, new_k, new_v, pos + 1)
-        if "wo_q" in lp:
-            o = qmm("bshk,hkd->bsd", o, lp, "wo", cfg)
-        else:
-            o = jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(cfg.dtype))
-        x = x + o
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        down, _ = _ffn(h, lp, cfg, token_mask=(pos > 0)[:, None])
-        x = x + down
-        return x, (new_k, new_v)
+    def qkv(lp, x, positions):
+        q, k, v = attention_inputs(lp, x, positions, cfg, inv_freq)
+        return q, {"k": k, "v": v}
 
-    x, (new_k, new_v) = jax.lax.scan(
-        block, x, (params["layers"], cache["k"], cache["v"])
-    )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if "embed_q" in params:
-        logits = quant_head_logits(params, x[:, 0], cfg)
-    else:
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"])
-        logits = jnp.einsum("bd,dv->bv", x[:, 0], head.astype(cfg.dtype))
-    return logits.astype(jnp.float32), {
-        "k": new_k, "v": new_v, "len": cache["len"] + 1
-    }
+    def decode_attn(lp, q, pools, layer, tables, kv_len, kernel, mesh,
+                    interpret):
+        if kernel == "pallas":
+            # block-resident kernel over the carried pool, addressed by
+            # (layer, block): per slot, only the live blocks named by its
+            # table row move HBM->VMEM; no [max_seq] view and no slice of
+            # the pool exists. Under a mesh the call shard_maps over the
+            # heads/KV axis — per-shard pool blocks, replicated tables, no
+            # collectives (quantized scale tables shard on kv-heads with
+            # the pool).
+            from kubeflow_tpu.ops.pallas_paged_attention import (
+                paged_decode_attention_sharded,
+            )
+
+            return paged_decode_attention_sharded(
+                q[:, 0], pools["k"], pools["v"], layer, tables, kv_len,
+                mesh=mesh, interpret=interpret,
+                k_scale=pools.get("k_scale"),
+                v_scale=pools.get("v_scale"))[:, None]
+        k_view, v_view = gather_views(pools, layer, tables, cfg)
+        return decode_attention(q, k_view, v_view, kv_len)
+
+    def chunk_attention(lp, q, pools, layer, tables, q_start):
+        # the shared GQA causal kernel with traced query offsets: row i
+        # of slot b (absolute position q_start[b]+i) attends kv rows <= it
+        k_view, v_view = gather_views(pools, layer, tables, cfg)
+        return _xla_attention(q, k_view, v_view, causal=True,
+                              q_offset=q_start)
+
+    return PagedOps(
+        n_layers=cfg.n_layers,
+        pool_rows={"k": (cfg.n_kv_heads, cfg.head_dim),
+                   "v": (cfg.n_kv_heads, cfg.head_dim)},
+        layer_stacks=lambda params: [(params["layers"], ())],
+        embed=lambda params, tokens: embed_tokens(params, tokens, cfg),
+        qkv=qkv, decode_attention=decode_attn,
+        chunk_attention=chunk_attention,
+        out=lambda lp, x, o, token_mask: (
+            attention_out_and_ffn(lp, x, o, cfg, token_mask=token_mask)[0],
+            {}),
+        head=lambda params, x_last: head_logits(
+            params, x_last, cfg).astype(jnp.float32),
+        bucket_prefill=lambda params, tokens, lengths: bucket_prefill(
+            params, tokens, lengths, cfg))

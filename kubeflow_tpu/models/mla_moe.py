@@ -446,7 +446,7 @@ def forward(params, tokens, cfg: MlaMoeConfig, return_predict: bool = False):
 
 
 # ---------------------------------------------------------------------------
-# The serving programs' view of the model (serving/paged_kv.PagedOps)
+# The serving programs' view of the model (models/paged.PagedOps)
 # ---------------------------------------------------------------------------
 
 def _kv_tile(n_tables: int, block_size: int, target: int = 1024) -> int:
@@ -458,7 +458,7 @@ def _kv_tile(n_tables: int, block_size: int, target: int = 1024) -> int:
 
 
 def _paged_ops(cfg: MlaMoeConfig):
-    from kubeflow_tpu.serving.paged_kv import PagedOps
+    from kubeflow_tpu.models.paged import PagedOps
 
     def layer_stacks(params):
         # the routed experts' matrices are handed over whole: the grouped

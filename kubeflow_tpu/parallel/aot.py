@@ -19,6 +19,7 @@ HBM budgets are per-chip device memory: v5p = 95 GB, v5e = 16 GB.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import glob
 import json
@@ -131,6 +132,18 @@ def _sds(shape_tree, sharding_tree):
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
         shape_tree, sharding_tree,
     )
+
+
+@contextlib.contextmanager
+def _backend_is(platform: str):
+    """``jax.default_backend()`` answers ``platform`` inside: for code that
+    picks a path by the backend while it is lowered for described devices."""
+    real = jax.default_backend
+    jax.default_backend = lambda: platform
+    try:
+        yield
+    finally:
+        jax.default_backend = real
 
 
 def _analyze(name, topology, num_slices, mesh, compiled,
@@ -549,9 +562,14 @@ def aot_serve_proof(
     name: str = "serve",
     hbm_gb: Optional[float] = None,
 ) -> ScaleProof:
-    """Compile the tensor-parallel serving hot path (prefill + decode_step
-    over a full KV cache) for the target slice; per-chip HBM must hold
-    bf16 params/TP + the KV pool/TP."""
+    """Compile the two programs a tensor-parallel ``LLMEngine`` spends its
+    time in, as it builds them, for the target slice: ``paged_decode_step``
+    (the kernel the engine resolves on a TPU, shard_map'd over the mesh)
+    over the dense arena's worth of blocks sharded on the kv-head dim, and
+    the model's bucket prefill. Per-chip HBM must hold bf16 params/TP + the
+    KV pool/TP beside either program."""
+    from kubeflow_tpu.serving import paged_kv
+
     devices = topology_devices(topology)
     mesh = build_mesh(MeshConfig(tensor=tensor), devices=devices)
     param_sh = shd.tree_shardings(mesh, llama.param_logical_axes(cfg))
@@ -560,28 +578,37 @@ def aot_serve_proof(
         jax.random.key(0))
     params_in = _sds(params_shape, param_sh)
 
-    cache_shape = jax.eval_shape(
-        lambda: llama.init_cache(cfg, batch, max_seq))
-    kv_spec = PartitionSpec(None, None, None, "tensor", None)
-    cache_sh = {
-        "k": NamedSharding(mesh, kv_spec),
-        "v": NamedSharding(mesh, kv_spec),
-        "len": NamedSharding(mesh, PartitionSpec()),
-    }
-    cache_in = _sds(cache_shape, cache_sh)
     repl = NamedSharding(mesh, PartitionSpec())
+    kv_sh = NamedSharding(
+        mesh, PartitionSpec(None, None, None, "tensor", None))
+    block_size = 64                       # the engine's default
+    table_len = max_seq // block_size
+    cache_shape = jax.eval_shape(lambda: paged_kv.init_paged_cache(
+        cfg, batch, max_seq, block_size, batch * table_len + 1))
+    cache_in = _sds(cache_shape, {"k": kv_sh, "v": kv_sh, "len": repl})
 
-    tok_in = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=repl)
+    def replicated(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=repl)
+
+    kernel, _ = paged_kv.resolve_decode_kernel(
+        "auto", mesh=mesh, n_kv_heads=cfg.n_kv_heads, platform="tpu")
     decode = jax.jit(
-        lambda p, t, c: llama.decode_step(p, t, cfg, c),
+        lambda p, t, c, tables: paged_kv.paged_decode_step(
+            p, t, cfg, c, tables, kernel=kernel, mesh=mesh),
         donate_argnums=(2,))
-    compiled_decode = decode.lower(params_in, tok_in, cache_in).compile()
+    # the program asks the backend whether to interpret its kernel: this
+    # process holds no chip, the answer is the target's
+    with _backend_is("tpu"):
+        compiled_decode = decode.lower(
+            params_in, replicated((batch,)), cache_in,
+            replicated((batch, table_len))).compile()
 
-    prompt_in = jax.ShapeDtypeStruct(
-        (batch, prefill_len), jnp.int32, sharding=repl)
+    ops = cfg.paged_ops()
     prefill = jax.jit(
-        lambda p, t, c: llama.prefill(p, t, cfg, c), donate_argnums=(2,))
-    compiled_prefill = prefill.lower(params_in, prompt_in, cache_in).compile()
+        lambda p, toks, lens: ops.bucket_prefill(p, toks, lens))
+    compiled_prefill = prefill.lower(
+        params_in, replicated((batch, prefill_len)),
+        replicated((batch,))).compile()
 
     kind = topology.split(":", 1)[0]
     budget = hbm_gb or HBM_PER_CHIP_GB.get(kind, 95.0)
@@ -589,6 +616,14 @@ def aot_serve_proof(
                        compiled_decode, budget)
     proof_p = _analyze(f"{name}-prefill", topology, 1, mesh,
                        compiled_prefill, budget)
+    # the pool stays resident while a prefill runs, though that program
+    # takes no pool: count its shard among the prefill's arguments
+    pool_gb = sum(
+        x.size * x.dtype.itemsize
+        for x in (cache_shape["k"], cache_shape["v"])) / tensor / (1 << 30)
+    proof_p.argument_gb = round(proof_p.argument_gb + pool_gb, 3)
+    proof_p.peak_gb = round(proof_p.peak_gb + pool_gb, 3)
+    proof_p.fits = proof_p.peak_gb < budget
     # one resident footprint serves both programs; report the worse one
     worst = max((proof_d, proof_p), key=lambda p: p.peak_gb)
     worst.name = name

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 
 from kubeflow_tpu.ops.losses import softmax_cross_entropy
-from kubeflow_tpu.ops.norms import rms_norm
-from kubeflow_tpu.ops.rotary import rope_frequencies
 from kubeflow_tpu.parallel.pipeline import pipeline_apply
 from kubeflow_tpu.parallel.sharding import constrain
 
@@ -68,16 +66,13 @@ def pipeline_forward(params, tokens, cfg, mesh, *,
                      microbatches: int, axis: str = "pipeline"):
     """Pipelined full-sequence forward: tokens [B,S] -> (logits [B,S,V] f32,
     aux dict). B must divide by ``microbatches``."""
-    from kubeflow_tpu.models.llama import _block, _remat_wrap
+    from kubeflow_tpu.models import llama
 
     positions = jnp.arange(tokens.shape[1])[None, :]
-    inv_freq = jnp.asarray(rope_frequencies(
-        cfg.head_dim, cfg.rope_theta, cfg.rope_scaling,
-        original_max_seq=cfg.max_seq,
-    ))
+    inv_freq = llama.rope_inv_freq(cfg)
 
-    block = _remat_wrap(
-        lambda x, lp: _block(x, lp, inv_freq, positions, cfg), cfg)
+    block = llama._remat_wrap(
+        lambda x, lp: llama._block(x, lp, inv_freq, positions, cfg), cfg)
 
     def stage_fn(stage_layers, x):
         x, aux_per_layer = jax.lax.scan(block, x, stage_layers)
@@ -87,14 +82,12 @@ def pipeline_forward(params, tokens, cfg, mesh, *,
         stage_fn, mesh, axis=axis, microbatches=microbatches,
         partial_manual=True, stage_aux=True)
 
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    x = llama.embed_tokens(params, tokens, cfg)
     x = constrain(x, ("batch", "seq", "act_embed"))
     x, moe_aux = fwd(params["stages"], x)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
-    logits = constrain(logits, ("batch", "seq", None))
+    logits = constrain(llama.head_logits(params, x, cfg),
+                       ("batch", "seq", None))
     return logits.astype(jnp.float32), {"moe_aux": moe_aux}
 
 
@@ -145,15 +138,12 @@ def mpmd_model_config(run_cfg, env=None):
 def _mpmd_block(mcfg, seq: int):
     """The one block builder both the MPMD chunks and the SPMD oracle
     trace — identical math is the parity contract."""
-    from kubeflow_tpu.models.llama import _block, _remat_wrap
+    from kubeflow_tpu.models import llama
 
     positions = jnp.arange(seq)[None, :]
-    inv_freq = jnp.asarray(rope_frequencies(
-        mcfg.head_dim, mcfg.rope_theta, mcfg.rope_scaling,
-        original_max_seq=mcfg.max_seq,
-    ))
-    return _remat_wrap(
-        lambda x, lp: _block(x, lp, inv_freq, positions, mcfg), mcfg)
+    inv_freq = llama.rope_inv_freq(mcfg)
+    return llama._remat_wrap(
+        lambda x, lp: llama._block(x, lp, inv_freq, positions, mcfg), mcfg)
 
 
 class MpmdLlamaSpec:
@@ -200,12 +190,14 @@ class MpmdLlamaSpec:
                 "lm_head": full["lm_head"]}
 
     def chunk_fn(self, cfg, chunk: int):
+        from kubeflow_tpu.models import llama
+
         mcfg = self.mcfg
         block = _mpmd_block(mcfg, self.seq)
 
         if chunk == 0:
             def fn(p, tokens):
-                x = p["embed"].astype(mcfg.dtype)[tokens]
+                x = llama.embed_tokens(p, tokens, mcfg)
                 x, _ = jax.lax.scan(block, x, p["layers"])
                 return x
         else:
@@ -215,14 +207,14 @@ class MpmdLlamaSpec:
         return fn
 
     def head_fn(self, cfg):
+        from kubeflow_tpu.models import llama
+
         mcfg, M = self.mcfg, cfg.microbatches
 
         def fn(hp, y, t):
-            x = rms_norm(y, hp["final_norm"], mcfg.norm_eps)
-            logits = jnp.einsum(
-                "bsd,dv->bsv", x, hp["lm_head"].astype(mcfg.dtype))
             loss, _ = softmax_cross_entropy(
-                logits.astype(jnp.float32), t, z_loss=mcfg.z_loss)
+                llama.head_logits(hp, y, mcfg).astype(jnp.float32), t,
+                z_loss=mcfg.z_loss)
             return loss / M
         return fn
 

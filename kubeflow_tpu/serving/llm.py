@@ -219,10 +219,10 @@ def sample_logits(logits, rng, temperature, top_k, top_p,
 
 class LLMEngine:
     """Continuous-batching generation over a model's paged programs.
-    ``cfg`` is any config ``paged_kv.paged_ops`` knows (a ``LlamaConfig``,
-    or one that brings its own ``paged_ops()``): what the engine needs of
-    the model — the pool's rows, the layer's pieces, the head, what it
-    cannot be served with — it asks through that one object."""
+    ``cfg`` is any config with a ``paged_ops()`` method (``LlamaConfig``,
+    ``MlaMoeConfig``): what the engine needs of the model — the pool's
+    rows, the layer's pieces, the head, what it cannot be served with — it
+    asks through that one object."""
 
     def __init__(self, params, cfg, *,
                  max_batch: int = 8, max_seq: int = 1024,
@@ -430,13 +430,12 @@ class LLMEngine:
             self.spec = make_drafter(self.sched.cfg.spec_drafter,
                                      self.sched.cfg.spec_k)
 
-        # whole-bucket prefill into a dense scratch, where the model has
-        # one; without it every prompt streams through the chunk program.
-        # (Lambdas on purpose, here and below: the benchmark's readers
-        # find the prefill programs by the name ``jit__lambda``.)
+        # whole-bucket prefill, where the model has one; without it every
+        # prompt streams through the chunk program. (Lambdas on purpose,
+        # here and below: the benchmark's readers find the prefill
+        # programs by the name ``jit__lambda``.)
         self._prefill = ops.bucket_prefill and jax.jit(
-            lambda p, toks, lens, cache: ops.bucket_prefill(
-                p, toks, lens, cache))
+            lambda p, toks, lens: ops.bucket_prefill(p, toks, lens))
         # chunked prefill for prompts longer than every bucket: fixed
         # chunk size (the largest bucket) + traced offset/length keep the
         # compile count O(1) in prompt length
@@ -1565,13 +1564,12 @@ class LLMEngine:
             ids = self.paged.slot_blocks(slot)
             blk[i, n_shared:nb_prefill] = ids[n_shared:nb_prefill]
             slots[i] = slot
-        scratch = self.model.bucket_scratch(width, bucket)
         self.prefill_dispatches += 1
         pspan = self._dispatch_span(
             "prefill.batch", [r for r, _, _ in batch],
             bucket=bucket, batch=len(batch))
         logits, filled = self._prefill(
-            self.params, jnp.asarray(toks), jnp.asarray(lengths), scratch)
+            self.params, jnp.asarray(toks), jnp.asarray(lengths))
         self.cache = self._insert_batch(
             self.cache, filled["k"], filled["v"], jnp.asarray(blk),
             jnp.asarray(lengths), jnp.asarray(slots))

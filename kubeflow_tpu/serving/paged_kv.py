@@ -1,13 +1,18 @@
 """Block-paged KV cache for the LLM engine (vLLM's PagedAttention role,
 SURVEY.md §2.4 LLM row), XLA-first.
 
-The dense engine arena ([L, max_batch, max_seq, KV, D]) charges every slot
+A dense arena ([L, max_batch, max_seq, KV, D]) would charge every slot
 for the worst-case sequence length. Here KV lives in a pool of fixed-size
 blocks ([L, num_blocks, block_size, KV, D]) and each slot owns a *block
 table* — the ordered block ids backing its logical sequence — so arena
 memory scales with tokens actually resident, and a pool holding
 ``num_blocks * block_size`` tokens can serve far more concurrent short
-requests than the dense arena of equal bytes.
+requests than a dense arena of equal bytes.
+
+What a model is to these programs is ``models/paged.py::PagedOps``, which
+every servable config builds in its own ``paged_ops()`` from the layer
+pieces its ``forward`` uses; this module names no model. The pool's array
+format (views, quantized rows and scales) is ``ops/paged_pool.py``.
 
 Everything stays static-shape for XLA: the pool and the [max_batch,
 max_blocks_per_seq] table array never change shape; tables are
@@ -50,65 +55,22 @@ from __future__ import annotations
 import dataclasses
 import functools
 import re
-from typing import Any, Callable, Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kubeflow_tpu.models import llama
-from kubeflow_tpu.ops.attention import decode_attention as decode_attention_fn
-from kubeflow_tpu.ops.norms import rms_norm
-from kubeflow_tpu.ops.rotary import apply_rope, rope_frequencies
+from kubeflow_tpu.models.paged import PagedOps
+from kubeflow_tpu.ops.paged_pool import (
+    kv_qmax, kv_store, quant_scatter_rows,
+)
 from kubeflow_tpu.serving.quant import kv_store_dtype
 
 
-@dataclasses.dataclass(frozen=True)
-class PagedOps:
-    """A model as the paged programs and the engine see it: the pieces of
-    one layer around attention, what a token caches, and what the model
-    cannot be served with. The three programs below (decode, chunked
-    prefill, speculative verify) are written once over these pieces; a
-    config class offers its own through a ``paged_ops()`` method and
-    ``LlamaConfig`` models get ``_llama_ops`` (``paged_ops(cfg)``).
-
-    ``pool_rows``: pool name -> the shape of ONE token's row in it; a pool
-    is ``[n_layers, num_blocks, block_size, *row]``. ``layer_stacks(params)``:
-    the stacked layer trees in order (layers of one kind per stack), each
-    with the names of the weights it wants whole (``_scan_layers``); each
-    is scanned with the pools in the carry. ``qkv(lp, x, positions)`` ->
-    ``(q, {pool: rows [B, S, *row]})``; ``decode_attention(lp, q, pools,
-    layer, tables, kv_len, kernel, mesh, interpret)`` -> o of the one new
-    row per slot; ``chunk_attention(lp, q, pools, layer, tables,
-    q_start)`` -> o of [B, C] rows at positions ``q_start[b] + i``, causal
-    over what the slot's blocks hold; ``out(lp, x, o, token_mask)`` ->
-    ``(x, stats)`` with ``stats`` a dict of small per-layer counts (empty
-    for a dense layer); ``head(params, x_last)`` -> float32 logits from
-    the hidden state before the final norm. ``bucket_prefill(params,
-    tokens, lengths, scratch)`` / ``bucket_scratch(width, bucket)``: the
-    dense-scratch prefill of whole buckets, or None where every prompt
-    streams through ``paged_prefill_chunk``. ``routed_per_token``: expert
-    assignments one token makes over all layers (0: no experts).
-    ``refuses``: mechanism -> why the engine must not be built with it."""
-
-    n_layers: int
-    pool_rows: dict
-    layer_stacks: Callable
-    embed: Callable
-    qkv: Callable
-    decode_attention: Callable
-    chunk_attention: Callable
-    out: Callable
-    head: Callable
-    bucket_prefill: Optional[Callable] = None
-    bucket_scratch: Optional[Callable] = None
-    routed_per_token: int = 0
-    refuses: dict = dataclasses.field(default_factory=dict)
-
-
 def paged_ops(cfg) -> PagedOps:
-    make = getattr(cfg, "paged_ops", None)
-    return make() if make is not None else _llama_ops(cfg)
+    """The model's pieces, from the config's own ``paged_ops()``."""
+    return cfg.paged_ops()
 
 
 def init_paged_cache(cfg, max_batch: int, max_seq: int,
@@ -326,7 +288,7 @@ class PagedKV:
     ``defer_publish=True``) and publish completed read-only blocks chunk
     by chunk via ``publish_prompt_blocks``."""
 
-    cfg: Any                         # a config ``paged_ops`` knows
+    cfg: Any                         # a config with ``paged_ops()``
     max_batch: int
     max_seq: int
     block_size: int
@@ -597,113 +559,6 @@ def pool_shaped_ops(hlo_text: str, pool_shapes) -> list:
 
 # ------------------------------------------------------------ jitted bodies
 
-def _layer_qkv(lp, x, positions, cfg, inv_freq):
-    """Shared attention-input path for the paged decode AND chunked-prefill
-    layer bodies — one place for the projection/rope math so the two paths
-    cannot drift. int8-quantized layer trees (``wq_q`` present) run the
-    same einsums over the int8 tensors and scale the output tile."""
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    if "wq_q" in lp:
-        q = llama.qmm("bsd,dhk->bshk", h, lp, "wq", cfg)
-        k = llama.qmm("bsd,dhk->bshk", h, lp, "wk", cfg)
-        v = llama.qmm("bsd,dhk->bshk", h, lp, "wv", cfg)
-    else:
-        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"].astype(cfg.dtype))
-        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"].astype(cfg.dtype))
-        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"].astype(cfg.dtype))
-    q = apply_rope(q, positions, inv_freq)
-    k = apply_rope(k, positions, inv_freq)
-    return q, k, v
-
-
-def _layer_out(lp, x, o, cfg, token_mask=None):
-    """Shared attention-output + FFN path (see _layer_qkv). token_mask
-    keeps pad/idle rows out of MoE routing (capacity stealing)."""
-    if "wo_q" in lp:
-        o = llama.qmm("bshk,hkd->bsd", o, lp, "wo", cfg)
-    else:
-        o = jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(cfg.dtype))
-    x = x + o
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    down, _ = llama._ffn(h, lp, cfg, token_mask=token_mask)
-    return x + down
-
-
-def _lm_head(params, x_last, cfg):
-    x_last = rms_norm(x_last, params["final_norm"], cfg.norm_eps)
-    if "embed_q" in params:
-        return llama.quant_head_logits(params, x_last,
-                                       cfg).astype(jnp.float32)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    return jnp.einsum("bd,dv->bv", x_last,
-                      head.astype(cfg.dtype)).astype(jnp.float32)
-
-
-# ---- quantized-pool value path (int8 / fp8_e4m3 KV) ----
-
-def _kv_store(x, store_dtype):
-    """f32 values -> pool storage dtype: round+clip for int8, a plain
-    cast (round-to-nearest) for the fp8 emulation."""
-    if jnp.issubdtype(store_dtype, jnp.integer):
-        return jnp.clip(jnp.round(x), -127, 127).astype(store_dtype)
-    return x.astype(store_dtype)
-
-
-def _kv_qmax(store_dtype) -> float:
-    return 127.0 if jnp.issubdtype(store_dtype, jnp.integer) else 448.0
-
-
-def quant_scatter_rows(pool, scale, layer, blk, off, rows):
-    """Quantize-on-write for the per-step KV scatters (decode, chunked
-    prefill, spec verify): write ``rows`` into layer ``layer`` of the
-    quantized ``pool`` [L, NB, bs, KV, D] at (blk, off) under the
-    per-block per-kv-head ``scale`` [L, NB, KV], growing scales
-    monotonically (scatter-max) and requantizing each touched block's
-    resident rows when its scale grows — so earlier rows stay decodable
-    under the one scale the read path (kernel and oracle alike) applies.
-    When the scale does NOT grow the requant ratio is exactly 1.0 and
-    int8 content round-trips unchanged. Both arrays are updated by
-    scatters at ``(layer, blk)``: in a loop that carries them nothing
-    pool-sized is copied.
-
-    blk/off: int32, any common shape; rows: [..., KV, D]. Duplicate blk
-    entries (verify writing several rows of one slot's block) are
-    benign: the scatter-max folds all their amaxes first, every
-    duplicate then computes the identical grown scale and requantized
-    resident content, and the new rows land at distinct offsets. Rows
-    routed to the scratch block 0 only ever pollute scratch scales,
-    which nothing meaningful reads."""
-    blk = blk.reshape(-1)
-    off = off.reshape(-1)
-    rows = rows.reshape(blk.shape[0], *rows.shape[-2:]).astype(jnp.float32)
-    qmax = _kv_qmax(pool.dtype)
-    amax = jnp.max(jnp.abs(rows), axis=-1)               # [N, KV]
-    old = scale[layer, blk]                              # [N, KV]
-    scale = scale.at[layer, blk].max(amax / qmax)
-    new = scale[layer, blk]
-    safe = jnp.maximum(new, 1e-30)
-    ratio = jnp.where(new > 0, old / safe, 0.0)          # <= 1.0 always
-    resident = (pool[layer, blk].astype(jnp.float32)
-                * ratio[:, None, :, None])
-    pool = pool.at[layer, blk].set(_kv_store(resident, pool.dtype))
-    q = jnp.where(new[:, :, None] > 0, rows / safe[:, :, None], 0.0)
-    pool = pool.at[layer, blk, off].set(_kv_store(q, pool.dtype))
-    return pool, scale
-
-
-def dequant_gather_view(pool, scale, layer, tables, cfg):
-    """Slot-logical [B, T, KV, D] view of layer ``layer`` of a QUANTIZED
-    pool: gather the table's blocks, upcast, multiply each block's
-    per-kv-head scale, cast to the compute dtype — element-for-element
-    the pipeline the Pallas kernel fuses into its inner loop, which is
-    what keeps the kernel-vs-oracle parity tests exact under
-    quantization."""
-    b = tables.shape[0]
-    v = (pool[layer, tables].astype(jnp.float32)
-         * scale[layer, tables][:, :, None, :, None]).astype(cfg.dtype)
-    return v.reshape(b, -1, *pool.shape[3:])
-
-
 def _scatter_rows(pools, layer, blk, off, rows):
     """This step's rows (``{pool: [..., *row]}``) into layer ``layer`` of
     the carried pools at (blk, off); quantize-on-write where the pool is
@@ -716,21 +571,6 @@ def _scatter_rows(pools, layer, blk, off, rows):
         return {"k": k_pool, "v": v_pool, "k_scale": k_sc, "v_scale": v_sc}
     return {key: pools[key].at[layer, blk, off].set(
                 rows[key].astype(pools[key].dtype)) for key in pools}
-
-
-def _gather_views(pools, layer, tables, cfg):
-    """Slot-logical K and V views [B, T, KV, D] of layer ``layer``: block
-    j of a table row holds logical positions [j*bs, (j+1)*bs) — table
-    order IS sequence order. A quantized pool dequants on the way (the
-    quantized gather oracle)."""
-    if "k_scale" in pools:
-        return (dequant_gather_view(pools["k"], pools["k_scale"], layer,
-                                    tables, cfg),
-                dequant_gather_view(pools["v"], pools["v_scale"], layer,
-                                    tables, cfg))
-    b = tables.shape[0]
-    return tuple(pools[key][layer, tables].reshape(
-        b, -1, *pools[key].shape[3:]) for key in ("k", "v"))
 
 
 def _scan_layers(ops: PagedOps, params, x, cache, layer_fn):
@@ -793,7 +633,7 @@ def paged_insert_batch(cache, k_new, v_new, blk_ids, lengths, slots):
     bs = cache["k"].shape[2]
     b, nb = blk_ids.shape
     if "k_scale" in cache:
-        qmax = _kv_qmax(cache["k"].dtype)
+        qmax = kv_qmax(cache["k"].dtype)
         t = k_new.shape[2]
         live = (jnp.arange(t)[None, :]
                 < lengths[:, None])[None, :, :, None, None]
@@ -805,10 +645,10 @@ def paged_insert_batch(cache, k_new, v_new, blk_ids, lengths, slots):
         vs = jnp.max(jnp.abs(vb), axis=(3, 5)) / qmax
         ksafe = jnp.maximum(ks, 1e-30)[:, :, :, None, :, None]
         vsafe = jnp.maximum(vs, 1e-30)[:, :, :, None, :, None]
-        kq = _kv_store(jnp.where(ksafe > 1e-30, kb / ksafe, 0.0),
-                       cache["k"].dtype)
-        vq = _kv_store(jnp.where(vsafe > 1e-30, vb / vsafe, 0.0),
-                       cache["v"].dtype)
+        kq = kv_store(jnp.where(ksafe > 1e-30, kb / ksafe, 0.0),
+                      cache["k"].dtype)
+        vq = kv_store(jnp.where(vsafe > 1e-30, vb / vsafe, 0.0),
+                      cache["v"].dtype)
         k = cache["k"].at[:, blk_ids].set(kq)
         v = cache["v"].at[:, blk_ids].set(vq)
         k_scale = cache["k_scale"].at[:, blk_ids].set(ks)
@@ -828,70 +668,6 @@ def paged_insert_batch(cache, k_new, v_new, blk_ids, lengths, slots):
     slots_drop = jnp.where(slots >= 0, slots, cache["len"].shape[0])
     ln = cache["len"].at[slots_drop].set(lengths, mode="drop")
     return {"k": k, "v": v, "len": ln}
-
-
-def _llama_ops(cfg: llama.LlamaConfig) -> PagedOps:
-    """``LlamaConfig`` (dense GQA, optionally the capacity-buffer expert
-    FFN) as the paged programs see it."""
-    from kubeflow_tpu.ops.attention import _xla_attention
-
-    inv_freq = jnp.asarray(rope_frequencies(
-        cfg.head_dim, cfg.rope_theta, cfg.rope_scaling,
-        original_max_seq=cfg.max_seq,
-    ))
-
-    def qkv(lp, x, positions):
-        q, k, v = _layer_qkv(lp, x, positions, cfg, inv_freq)
-        return q, {"k": k, "v": v}
-
-    def decode_attention(lp, q, pools, layer, tables, kv_len, kernel, mesh,
-                         interpret):
-        if kernel == "pallas":
-            # block-resident kernel over the carried pool, addressed by
-            # (layer, block): per slot, only the live blocks named by its
-            # table row move HBM->VMEM; no [max_seq] view and no slice of
-            # the pool exists. Under a mesh the call shard_maps over the
-            # heads/KV axis — per-shard pool blocks, replicated tables, no
-            # collectives (quantized scale tables shard on kv-heads with
-            # the pool).
-            from kubeflow_tpu.ops.pallas_paged_attention import (
-                paged_decode_attention_sharded,
-            )
-
-            return paged_decode_attention_sharded(
-                q[:, 0], pools["k"], pools["v"], layer, tables, kv_len,
-                mesh=mesh, interpret=interpret,
-                k_scale=pools.get("k_scale"),
-                v_scale=pools.get("v_scale"))[:, None]
-        k_view, v_view = _gather_views(pools, layer, tables, cfg)
-        return decode_attention_fn(q, k_view, v_view, kv_len)
-
-    def chunk_attention(lp, q, pools, layer, tables, q_start):
-        # the shared GQA causal kernel with traced query offsets: row i
-        # of slot b (absolute position q_start[b]+i) attends kv rows <= it
-        k_view, v_view = _gather_views(pools, layer, tables, cfg)
-        return _xla_attention(q, k_view, v_view, causal=True,
-                              q_offset=q_start)
-
-    def bucket_prefill(params, tokens, lengths, scratch):
-        logits, filled = llama.prefill(params, tokens, cfg, scratch,
-                                       lengths=lengths)
-        return logits, {"k": filled["k"], "v": filled["v"]}
-
-    return PagedOps(
-        n_layers=cfg.n_layers,
-        pool_rows={"k": (cfg.n_kv_heads, cfg.head_dim),
-                   "v": (cfg.n_kv_heads, cfg.head_dim)},
-        layer_stacks=lambda params: [(params["layers"], ())],
-        embed=lambda params, tokens: llama.embed_tokens(params, tokens, cfg),
-        qkv=qkv, decode_attention=decode_attention,
-        chunk_attention=chunk_attention,
-        out=lambda lp, x, o, token_mask: (
-            _layer_out(lp, x, o, cfg, token_mask=token_mask), {}),
-        head=lambda params, x_last: _lm_head(params, x_last, cfg),
-        bucket_prefill=bucket_prefill,
-        bucket_scratch=lambda width, bucket: llama.init_cache(
-            cfg, width, bucket))
 
 
 def _resolve_decode_kernel(kernel: str) -> str:
@@ -949,7 +725,7 @@ def paged_decode_step(params, token, cfg, cache, tables,
     picks the attention path: "gather" | "pallas" | "auto"; with ``mesh``
     the pallas path runs shard_map'd over the heads/KV tensor axis
     (per-shard pool blocks, replicated tables). ``cfg`` is any config
-    ``paged_ops`` knows."""
+    with a ``paged_ops()`` method."""
     ops = paged_ops(cfg)
     kernel, _ = resolve_decode_kernel(kernel, mesh=mesh,
                                       n_kv_heads=cfg.n_kv_heads)
@@ -987,9 +763,10 @@ def paged_prefill_chunk(params, tokens, cfg, cache,
     """Chunked prefill straight into the paged pool (vLLM chunked-prefill
     role): processes `tokens` [1, C] as positions offset..offset+C-1 of
     `slot`'s sequence, attending to everything the slot's blocks already
-    hold. No dense scratch cache exists — prompts longer than any prefill
-    bucket (up to max_seq) stream through in fixed-size chunks, so the
-    compile count stays O(1) in prompt length (offset/length are traced).
+    hold. Prompts longer than any prefill bucket (up to max_seq), and every
+    prompt of a model without ``PagedOps.bucket_prefill``, stream through
+    in fixed-size chunks, so the compile count stays O(1) in prompt length
+    (offset/length are traced).
 
     Rows at positions >= `length` (the final chunk's padding) scatter to
     block 0 — the pool's scratch block — never into live data; so do rows

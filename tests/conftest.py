@@ -53,6 +53,43 @@ def mesh_expert():
 _kube_servers = []
 
 
+def paged_session(cfg, params, prompts, max_seq=32, block_size=8):
+    """The programs the engine runs, without the engine: the model's
+    bucket prefill over ``prompts`` ([B, n], right-padded to whole
+    blocks), its rows through ``paged_insert_batch`` into a pool, then one
+    ``paged_decode_step`` (gather) a token. Returns (logits [B, V] at each
+    prompt's last token, ``step(tokens [B]) -> logits [B, V]``)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kubeflow_tpu.serving import paged_kv
+
+    prompts = np.asarray(prompts, np.int32)
+    b, n = prompts.shape
+    per_slot = max_seq // block_size
+    nb = -(-n // block_size)
+    tables = 1 + np.arange(b * per_slot, dtype=np.int32).reshape(b, per_slot)
+    toks = np.zeros((b, nb * block_size), np.int32)
+    toks[:, :n] = prompts
+    lengths = jnp.full((b,), n, jnp.int32)
+    logits, rows = paged_kv.paged_ops(cfg).bucket_prefill(
+        params, jnp.asarray(toks), lengths)
+    cache = paged_kv.paged_insert_batch(
+        paged_kv.init_paged_cache(cfg, b, max_seq, block_size,
+                                  b * per_slot + 1),
+        rows["k"], rows["v"], jnp.asarray(tables[:, :nb]), lengths,
+        jnp.arange(b))
+
+    def step(tokens):
+        nonlocal cache
+        logits, cache, _ = paged_kv.paged_decode_step(
+            params, jnp.asarray(tokens, jnp.int32), cfg, cache,
+            jnp.asarray(tables), kernel="gather")
+        return logits
+
+    return logits, step
+
+
 def make_test_cluster():
     """Cluster factory for the controller suites. Default: FakeCluster.
     KFT_TEST_CLUSTER=kube swaps in KubeCluster over an in-process fake

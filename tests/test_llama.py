@@ -7,6 +7,8 @@ from kubeflow_tpu.models import llama
 from kubeflow_tpu.parallel.sharding import tree_pspecs
 from kubeflow_tpu.utils.pytree import tree_param_count
 
+from conftest import paged_session
+
 
 @pytest.fixture(scope="module")
 def tiny():
@@ -40,23 +42,21 @@ def test_causality(tiny):
 
 
 def test_decode_matches_forward(tiny):
-    """Prefill + decode_step must agree with the full forward pass."""
+    """The bucket prefill, its rows inserted into the pool, and the paged
+    decode step must agree with the full forward pass."""
     cfg, params = tiny
     rng = np.random.default_rng(1)
     seq = rng.integers(0, cfg.vocab_size, (2, 12)).astype(np.int32)
     full = llama.forward(params, jnp.asarray(seq), cfg)
 
-    cache = llama.init_cache(cfg, batch=2, max_len=32, dtype=jnp.float32)
-    logits_p, cache = llama.prefill(params, jnp.asarray(seq[:, :8]), cfg, cache)
+    logits_p, step = paged_session(cfg, params, seq[:, :8])
     np.testing.assert_allclose(
         np.asarray(logits_p), np.asarray(full[:, 7]), rtol=1e-3, atol=1e-3
     )
     for i in range(8, 12):
-        logits_d, cache = llama.decode_step(
-            params, jnp.asarray(seq[:, i]), cfg, cache
-        )
         np.testing.assert_allclose(
-            np.asarray(logits_d), np.asarray(full[:, i]), rtol=1e-3, atol=1e-3
+            np.asarray(step(seq[:, i])), np.asarray(full[:, i]),
+            rtol=1e-3, atol=1e-3
         )
 
 

@@ -90,7 +90,10 @@ def test_three_stage_pipeline_runs_and_matches_oracle():
                             schedule="1f1b")
     _, losses = run_inproc(cfg)
     oracle = run_oracle(cfg)
-    assert losses[0] == oracle[0]
+    # not bitwise, step 0 included: three stage programs and the oracle's
+    # one program are compiled separately, and XLA sums the loss's 24 rows
+    # in another order (one float32 ulp here); a wiring bug is off by
+    # orders of magnitude
     np.testing.assert_allclose(losses, oracle, rtol=2e-5, atol=0)
 
 

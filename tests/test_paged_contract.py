@@ -1,0 +1,122 @@
+"""The seam between the models and the paged serving programs
+(``models/paged.py::PagedOps``): every servable config builds its own view
+in ``paged_ops()`` from the pieces its ``forward`` uses, the serving layer
+names no model, and the imports point one way (serving -> models -> ops).
+"""
+
+import ast
+import dataclasses
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import kubeflow_tpu
+from kubeflow_tpu.models import llama, mla_moe
+from kubeflow_tpu.models.paged import PagedOps
+from kubeflow_tpu.serving import paged_kv
+from kubeflow_tpu.serving.quant import _LAYER_WEIGHTS, quantize_weights
+
+from conftest import paged_session
+
+PACKAGE = pathlib.Path(kubeflow_tpu.__file__).parent
+
+
+@pytest.mark.parametrize(
+    "model, tiny",
+    [(llama, llama.llama_tiny), (mla_moe, mla_moe.mla_moe_tiny)],
+    ids=["llama_tiny", "mla_moe_tiny"])
+def test_paged_ops_is_the_configs_own(model, tiny):
+    cfg = tiny(dtype=jnp.float32)
+    ops = paged_kv.paged_ops(cfg)
+    assert isinstance(ops, PagedOps)
+    # nothing but the config's method decides what the programs see
+    sentinel = object()
+    stub = type("Stub", (), {"paged_ops": lambda self: sentinel})()
+    assert paged_kv.paged_ops(stub) is sentinel
+    assert ops.n_layers == cfg.n_layers
+
+    b, max_seq, bs, nb = 2, 32, 8, 9
+    cache = jax.eval_shape(
+        lambda: paged_kv.init_paged_cache(cfg, b, max_seq, bs, nb))
+    assert set(cache) == set(ops.pool_rows) | {"len"}
+    for name, row in ops.pool_rows.items():
+        assert cache[name].shape == (ops.n_layers, nb, bs, *row)
+
+    if ops.bucket_prefill is None:
+        return
+    params = jax.eval_shape(
+        lambda: model.init_params(jax.random.key(0), cfg))
+    logits, rows = jax.eval_shape(
+        ops.bucket_prefill, params,
+        jax.ShapeDtypeStruct((b, 2 * bs), jnp.int32),
+        jax.ShapeDtypeStruct((b,), jnp.int32))
+    assert logits.shape == (b, cfg.vocab_size)
+    assert logits.dtype == jnp.float32
+    assert set(rows) == set(ops.pool_rows)
+    for name, row in ops.pool_rows.items():
+        assert rows[name].shape == (ops.n_layers, b, 2 * bs, *row)
+
+
+def _imports(path):
+    """Every module a file imports, at any depth of nesting."""
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            yield from (alias.name for alias in node.names)
+        elif isinstance(node, ast.ImportFrom) and node.module:
+            yield node.module
+            yield from (f"{node.module}.{alias.name}"
+                        for alias in node.names)
+
+
+def test_imports_point_one_way():
+    upward = [
+        (str(path.relative_to(PACKAGE)), name)
+        for layer in ("models", "ops")
+        for path in sorted((PACKAGE / layer).rglob("*.py"))
+        for name in _imports(path)
+        if name.startswith("kubeflow_tpu.serving")]
+    assert upward == []
+    named = [name for name in _imports(PACKAGE / "serving" / "paged_kv.py")
+             if name.startswith("kubeflow_tpu.models.llama")]
+    assert named == []
+
+
+def _dequantized(qp):
+    """The float tree whose weights are exactly what the int8 tree's
+    ``name_q`` x ``name_s`` stand for."""
+    def restore(tree, name, axes):
+        w = tree.pop(name + "_q").astype(jnp.float32)
+        tree[name] = w * jnp.expand_dims(tree.pop(name + "_s"), axes)
+
+    out = dict(qp, layers=dict(qp["layers"]))
+    restore(out, "embed", (1,))
+    if "lm_head_q" in out:
+        restore(out, "lm_head", (0,))
+    for name, axes in _LAYER_WEIGHTS.items():
+        restore(out["layers"], name, axes)
+    return out
+
+
+@pytest.mark.parametrize("tied", [True, False], ids=["tied", "untied"])
+@pytest.mark.parametrize("program", ["bucket_prefill", "decode"])
+def test_int8_tree_runs_the_float_trees_pieces(program, tied):
+    """One set of pieces serves both trees: the quantized ``llama_tiny``
+    through the bucket prefill and the decode step equals the same
+    programs over the float tree holding the dequantized weights (scaling
+    the output tile against scaling the weight: float32 rounding apart)."""
+    cfg = dataclasses.replace(llama.llama_tiny(dtype=jnp.float32),
+                              tie_embeddings=tied)
+    qp = quantize_weights(
+        llama.init_params(jax.random.key(0), cfg, dtype=jnp.float32), cfg)
+    seq = np.random.default_rng(2).integers(
+        0, cfg.vocab_size, (2, 9)).astype(np.int32)
+    got = []
+    for params in (qp, _dequantized(qp)):
+        logits, step = paged_session(cfg, params, seq[:, :8])
+        got.append(logits if program == "bucket_prefill"
+                   else step(seq[:, 8]))
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(got[1]),
+                               rtol=1e-4, atol=1e-4)

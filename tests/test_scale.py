@@ -13,6 +13,8 @@ from kubeflow_tpu.parallel import (
     pipeline_apply, stack_stage_params,
 )
 
+from conftest import paged_session
+
 
 # ---------------------------------------------------------------- pipeline
 
@@ -160,14 +162,10 @@ def test_llama_moe_decode_matches_forward():
                            moe_capacity_factor=8.0, dtype=jnp.float32)
     params = llama.init_params(jax.random.key(0), cfg)
     prompt = [5, 6, 7, 8]
-    cache = llama.init_cache(cfg, 1, 32)
-    logits, cache = llama.prefill(
-        params, jnp.asarray([prompt], jnp.int32), cfg, cache)
+    logits, step = paged_session(cfg, params, [prompt])
     toks = [int(jnp.argmax(logits[0]))]
     for _ in range(3):
-        logits, cache = llama.decode_step(
-            params, jnp.asarray(toks[-1:], jnp.int32), cfg, cache)
-        toks.append(int(jnp.argmax(logits[0])))
+        toks.append(int(jnp.argmax(step(toks[-1:])[0])))
 
     ref = list(prompt)
     for _ in range(4):
