@@ -354,6 +354,9 @@ def leg_check(a) -> dict:
         if not np.isfinite(out).all() or err > 2e-2:
             raise SmokeFailure(f"check: kernel {name} vs oracle: {err}")
 
+    report["kernels"]["latent_prefill_chunk"] = _latent_prefill_check(
+        a.size, interpret)
+
     # --- several chips: the server's own build path shards, per device ---
     if n > 1:
         from kubeflow_tpu.serving import runtime
@@ -378,6 +381,83 @@ def leg_check(a) -> dict:
                 or not _on_every_device(sh["wq"], n)):
             raise SmokeFailure(f"check: serving not sharded: {sh}")
     return report
+
+
+def _latent_prefill_check(size: str, interpret: bool) -> dict:
+    """The latent model's prefill chunk kernel against the plain form in
+    float32, at JoyAI-LLM-Flash's widths: one slot, a chunk of 2,048
+    queries at position 12,288 over a permuted table, read at the last of
+    two layers."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kubeflow_tpu.ops.pallas_paged_attention import (
+        paged_latent_prefill_attention,
+    )
+
+    h, latent, d_n, d_r, d_v, row, bs, chunk, q_start = (
+        (32, 512, 128, 64, 128, 640, 64, 2048, 12288) if size == "full"
+        else (4, 16, 8, 8, 8, 128, 8, 32, 40))
+    nbp = (q_start + chunk) // bs
+    scale = (d_n + d_r) ** -0.5
+    ks = jax.random.split(jax.random.key(SEED + 1), 4)
+    # queries of four times a normed row's size: a softmax that picks rows
+    # (outputs of size ~1), not the mean of 14k rows that unit queries give
+    q = 4.0 * jax.random.normal(ks[0], (1, chunk, h, d_n + d_r), jnp.bfloat16)
+    pool = jax.random.normal(ks[1], (2, nbp + 1, bs, row), jnp.bfloat16)
+    w_uk = (latent ** -0.5 * jax.random.normal(ks[2], (latent, h, d_n))
+            ).astype(jnp.bfloat16)
+    w_uv = (latent ** -0.5 * jax.random.normal(ks[3], (latent, h, d_v))
+            ).astype(jnp.bfloat16)
+    tables = jnp.asarray(1 + np.random.default_rng(SEED).permutation(nbp),
+                         jnp.int32)[None]
+    start = jnp.asarray([q_start], jnp.int32)
+
+    @jax.jit
+    def kernel(q, pool, w_uk, w_uv):
+        return paged_latent_prefill_attention(
+            q, pool, w_uk, w_uv, 1, tables, start, rope_dim=d_r, scale=scale,
+            interpret=interpret)
+
+    @jax.jit
+    def oracle(q, pool, w_uk, w_uv):
+        f32 = jnp.float32
+        rows = pool[1][tables[0]].reshape(nbp * bs, row).astype(f32)
+        seen = jnp.arange(nbp * bs)[None] <= q_start + jnp.arange(chunk)[:, None]
+
+        def head(x):                      # a head at a time: [C, T] scores
+            q_h, wk, wv = x
+            k = jnp.concatenate([rows[:, :latent] @ wk.astype(f32),
+                                 rows[:, latent:latent + d_r]], -1)
+            s = (q_h.astype(f32) @ k.T) * scale
+            p = jax.nn.softmax(jnp.where(seen, s, -1e30), -1)
+            return p @ (rows[:, :latent] @ wv.astype(f32))
+
+        o = jax.lax.map(head, (jnp.moveaxis(q[0], 1, 0),
+                               jnp.moveaxis(w_uk, 1, 0),
+                               jnp.moveaxis(w_uv, 1, 0)))
+        return jnp.moveaxis(o, 0, 1)[None]
+
+    t0 = time.perf_counter()
+    out = np.asarray(kernel(q, pool, w_uk, w_uv).astype(jnp.float32))
+    ref = np.asarray(oracle(q, pool, w_uk, w_uv))
+    err = float(np.max(np.abs(out - ref)))
+    top = float(np.abs(ref).max())
+    # the kernel rounds a tile's keys, values and probabilities to bf16 for
+    # its products (as the XLA form it replaced did) and its output to bf16;
+    # the oracle rounds nothing. Allowed: TWO bf16 steps at the largest
+    # output. On the v5e the error read 0.0150 on outputs up to 4.39 (half a
+    # step there, the output's own rounding), and a kernel with the mask a
+    # row off, the scale 1.225 times too large or the rotary key left out
+    # 0.93, 1.07 and 4.63: thirty steps and more (my chip run, PR 32)
+    tol = float(2.0 ** (np.floor(np.log2(top)) - 6))
+    if not np.isfinite(out).all() or err > tol:
+        raise SmokeFailure(f"check: latent prefill kernel vs oracle: {err} "
+                           f"(allowed {tol})")
+    return {"max_abs_err": round(err, 5), "max_abs_ref": round(top, 3),
+            "allowed": tol,
+            "compile_and_run_s": round(time.perf_counter() - t0, 2)}
 
 
 def child_main(a) -> int:

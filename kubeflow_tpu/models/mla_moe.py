@@ -449,14 +449,6 @@ def forward(params, tokens, cfg: MlaMoeConfig, return_predict: bool = False):
 # The serving programs' view of the model (models/paged.PagedOps)
 # ---------------------------------------------------------------------------
 
-def _kv_tile(n_tables: int, block_size: int, target: int = 1024) -> int:
-    """Blocks per tile of the chunked-prefill walk: the largest divisor of
-    the table's width that keeps a tile at ``target`` tokens or fewer."""
-    return max(g for g in range(1, n_tables + 1)
-               if n_tables % g == 0 and g * block_size <= max(target,
-                                                              block_size))
-
-
 def _paged_ops(cfg: MlaMoeConfig):
     from kubeflow_tpu.models.paged import PagedOps
 
@@ -505,45 +497,16 @@ def _paged_ops(cfg: MlaMoeConfig):
     def chunk_attention(lp, q, pools, layer, tables, q_start):
         """q of [B, C] rows at positions ``q_start[b] + i`` over each
         slot's blocks (its own rows already scattered): the non-absorbed
-        form, tile by tile up to the last query's position and no
-        further, online softmax in float32."""
-        q_nope, q_rope = q
-        pool = pools["kv"]
-        b, c = q_nope.shape[:2]
-        bs = pool.shape[2]
-        per_tile = _kv_tile(tables.shape[1], bs)
-        tile = per_tile * bs
-        q_pos = q_start[:, None] + jnp.arange(c)[None, :]          # [B, C]
-        n_tiles = (jnp.max(q_start) + c + tile - 1) // tile
+        form as one flash kernel over the paged pool, interpreted where
+        there is no TPU."""
+        from kubeflow_tpu.ops.pallas_paged_attention import (
+            paged_latent_prefill_attention,
+        )
 
-        def one_slot(qn, qr, rows, pos, kv0):
-            k_nope, k_rope, v = keys_values_from_rows(lp, rows, cfg)
-            s = _scores(qn, qr, k_nope, k_rope, cfg)
-            seen = kv0 + jnp.arange(tile)[None, :] <= pos[:, None]
-            return jnp.where(seen[None], s, NEG_INF), v
-
-        def body(i, carry):
-            m, l, acc = carry
-            ids = jax.lax.dynamic_slice_in_dim(tables, i * per_tile,
-                                               per_tile, axis=1)
-            rows = pool[layer, ids].reshape(b, tile, pool.shape[-1])
-            s, v = jax.vmap(one_slot, in_axes=(0, 0, 0, 0, None))(
-                q_nope, q_rope, rows, q_pos, i * tile)     # [B, H, C, T]
-            m_new = jnp.maximum(m, s.max(-1))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[..., None])
-            acc = acc * alpha[..., None] + jnp.einsum(
-                "bhqt,bthv->bhqv", p.astype(v.dtype), v,
-                preferred_element_type=jnp.float32)
-            return m_new, l * alpha + p.sum(-1), acc
-
-        h = cfg.n_heads
-        init = (jnp.full((b, h, c), NEG_INF, jnp.float32),
-                jnp.zeros((b, h, c), jnp.float32),
-                jnp.zeros((b, h, c, cfg.v_head_dim), jnp.float32))
-        _, l, acc = jax.lax.fori_loop(0, n_tiles, body, init)
-        o = acc / jnp.maximum(l, 1e-30)[..., None]
-        return jnp.transpose(o, (0, 2, 1, 3)).astype(cfg.dtype)
+        return paged_latent_prefill_attention(
+            jnp.concatenate(q, axis=-1), pools["kv"], lp["w_uk"], lp["w_uv"],
+            layer, tables, q_start, rope_dim=cfg.qk_rope_dim,
+            scale=_scale(cfg), interpret=jax.default_backend() != "tpu")
 
     return PagedOps(
         n_layers=cfg.n_layers,

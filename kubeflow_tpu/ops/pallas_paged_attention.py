@@ -560,3 +560,197 @@ def paged_decode_attention_sharded(q, k_pool, v_pool, layer, tables, kv_len,
 
     return _shard_map(kern, mesh, in_specs=in_specs,
                       out_specs=P(None, axis, None))(*args)
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) prefill chunk: a chunk's queries over the slot's rows, flash
+# ---------------------------------------------------------------------------
+
+def _latent_prefill_kernel(layer_ref, qstart_ref, tables_ref, q_ref, wk_ref,
+                           wv_ref, pool_hbm, o_ref, rows_buf, k_buf, m_ref,
+                           l_ref, acc_ref, sems, *, scale, block_size,
+                           latent_dim, rope_dim, q_tile, tile_blocks):
+    """One grid step = one (slot, head): walk the slot's rows a KV tile of
+    ``tile_blocks`` pool blocks at a time, copied by the table through a
+    double buffer, up to the tile that holds the last query's position.
+    A tile is up-projected once with this head's ``W_uk`` / ``W_uv`` and
+    then met by the chunk's query sub-tiles; the score tile of a (query
+    sub-tile, KV tile) pair lives only between its product and the value
+    product. m / l / acc of ALL the chunk's rows stay in VMEM across the
+    walk."""
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    q_start = qstart_ref[b]
+    chunk = q_ref.shape[2]
+    nope_dim = wk_ref.shape[1]
+    n_tables = tables_ref.shape[1]
+    kv_tile = tile_blocks * block_size
+    n_q = chunk // q_tile
+    # the walk ends at the tile that holds the last query's own position
+    n_tiles = (q_start + chunk - 1) // kv_tile + 1
+
+    def copies(t, buf):
+        # a table entry past the slot's last block names the scratch block
+        # (finite rows, every one of them masked): the tile is always whole
+        for g in range(tile_blocks):
+            blk = tables_ref[b, jnp.minimum(t * tile_blocks + g,
+                                            n_tables - 1)]
+            yield pltpu.make_async_copy(
+                pool_hbm.at[layer, blk],
+                rows_buf.at[buf, pl.ds(g * block_size, block_size)],
+                sems.at[buf])
+
+    def start(t, buf):
+        for copy in copies(t, buf):
+            copy.start()
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    start(0, 0)
+
+    def kv_step(t, _):
+        buf = t % 2
+
+        @pl.when(t + 1 < n_tiles)
+        def _():
+            start(t + 1, 1 - buf)
+
+        for copy in copies(t, buf):
+            copy.wait()
+        rows = rows_buf[buf]                                  # [tk, R]
+        latent = rows[:, :latent_dim]
+        # this head's keys and values of the tile, once: operands as
+        # stored, f32 accumulation, rounded to the pool's dtype as the
+        # plain form's einsum rounds them. The shared rotary key is the
+        # [tk, d_r] matrix it is, beside the head's own keys.
+        k_buf[:, :nope_dim] = jnp.dot(
+            latent, wk_ref[...],
+            preferred_element_type=jnp.float32).astype(k_buf.dtype)
+        k_buf[:, nope_dim:] = rows[:, latent_dim:latent_dim + rope_dim]
+        keys = k_buf[...]                                     # [tk, d_n+d_r]
+        values = jnp.dot(latent, wv_ref[...],
+                         preferred_element_type=jnp.float32
+                         ).astype(rows.dtype)                 # [tk, d_v]
+        kv0 = t * kv_tile
+
+        def pair(i, masked):
+            sub = pl.ds(pl.multiple_of(i * q_tile, q_tile), q_tile)
+            s = jax.lax.dot_general(
+                q_ref[0, 0, sub, :], keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [tq, tk]
+            if masked:
+                q_pos = q_start + i * q_tile + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 0)
+                kv_pos = kv0 + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1)
+                s = jnp.where(kv_pos <= q_pos, s, NEG_INF)
+            _softmax_accumulate(s, values, m_ref.at[sub], l_ref.at[sub],
+                                acc_ref.at[sub])
+
+        # query sub-tile i holds positions q_start + [i, i + 1) * q_tile:
+        # one wholly before the tile multiplies nothing, one the diagonal
+        # crosses builds a mask, one wholly past the tile needs none
+        first = jnp.clip((kv0 - q_start) // q_tile, 0, n_q)
+        clear = jnp.clip(pl.cdiv(kv0 + kv_tile - 1 - q_start, q_tile),
+                         first, n_q)
+        jax.lax.fori_loop(first, clear, lambda i, _: pair(i, True), None)
+        jax.lax.fori_loop(clear, n_q, lambda i, _: pair(i, False), None)
+
+    jax.lax.fori_loop(0, n_tiles, kv_step, None)
+    o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...][:, :1], 1e-30)
+                   ).astype(o_ref.dtype)
+
+
+def _prefill_tiles(chunk: int, block_size: int, n_tables: int):
+    """(query sub-tile rows, pool blocks a KV tile) from the shapes: score
+    tiles of 1,024 x 1,024 (4 MiB in f32; on the chip 4.70 ms a call at
+    2,048 queries over 12k rows where 512 x 512 takes 6.82, PERF.md section
+    6, PR 32), a narrower chunk one sub-tile of its own width in whole bf16
+    sublane tiles."""
+    q_tile = min(1024, -(-chunk // 16) * 16)
+    return q_tile, max(1, min(n_tables, 1024 // block_size))
+
+
+def paged_latent_prefill_attention(q, pool, w_uk, w_uv, layer, tables,
+                                   q_start, *, rope_dim: int, scale: float,
+                                   interpret: bool = False):
+    """Non-absorbed causal latent attention of a chunk of queries over ONE
+    layer of the whole pool: the chunked prefill's attention as one flash
+    kernel.
+
+    q: [B, C, H, d_n + d_r] (``q_nope`` beside the rotated ``q_rope``), row
+    i of slot b at position ``q_start[b] + i``; pool: [L, num_blocks,
+    block_size, R] with a token's row ``[c ; k_rope ; padding]`` (the
+    chunk's own rows already scattered in); w_uk: [latent, H, d_n], w_uv:
+    [latent, H, d_v]; layer / tables as ``paged_latent_decode_attention``;
+    q_start: [B] int32. Returns o [B, C, H, d_v] in q.dtype: ``softmax((q .
+    [W_uk c ; k_rope]) * scale) (W_uv c)`` over the rows at positions <= the
+    query's own, scores, exponentials, sums and the accumulator in float32,
+    the probabilities in the pool's dtype for the value product.
+
+    Grid (slot, head): a head's queries [C, d_n + d_r], its slices of
+    ``W_uk`` / ``W_uv`` and its m / l / acc stay in VMEM while the kernel
+    walks the slot's rows (module docstring's idiom: the whole pool in HBM,
+    blocks copied by the table, double-buffered). A context's keys and
+    values never exist anywhere: a tile's are made in VMEM from the rows,
+    once a (head, tile). Nothing masked is multiplied beyond the sub-tiles
+    the diagonal crosses (``_latent_prefill_kernel``). Rows past the last
+    true one (a final chunk's padding) attend like any other and give
+    finite output nobody reads."""
+    b, c, h, qk_dim = q.shape
+    block_size = pool.shape[2]
+    latent_dim, _, nope_dim = w_uk.shape
+    v_dim = w_uv.shape[2]
+    if qk_dim != nope_dim + rope_dim:
+        raise ValueError(f"q has {qk_dim} values a head, the keys "
+                         f"{nope_dim} + {rope_dim}")
+    q_tile, tile_blocks = _prefill_tiles(c, block_size, tables.shape[1])
+    padded = -(-c // q_tile) * q_tile
+    q = jnp.transpose(q, (0, 2, 1, 3))                      # [B, H, C, qk]
+    if padded != c:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, padded - c), (0, 0)))
+    kv_tile = tile_blocks * block_size
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, h),
+        in_specs=[
+            pl.BlockSpec((1, 1, padded, qk_dim),
+                         lambda bi, hi, *_: (bi, hi, 0, 0)),
+            # a head's slice of the up-projections: [latent, H * d] is the
+            # weights' own memory, head hi its hi-th block of columns
+            pl.BlockSpec((latent_dim, nope_dim), lambda bi, hi, *_: (0, hi)),
+            pl.BlockSpec((latent_dim, v_dim), lambda bi, hi, *_: (0, hi)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, 1, padded, v_dim),
+                               lambda bi, hi, *_: (bi, hi, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, kv_tile, pool.shape[3]), pool.dtype),
+            pltpu.VMEM((kv_tile, qk_dim), pool.dtype),
+            pltpu.VMEM((padded, 128), jnp.float32),
+            pltpu.VMEM((padded, 128), jnp.float32),
+            pltpu.VMEM((padded, v_dim), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    kernel = functools.partial(
+        _latent_prefill_kernel, scale=scale, block_size=block_size,
+        latent_dim=latent_dim, rope_dim=rope_dim, q_tile=q_tile,
+        tile_blocks=tile_blocks)
+    o = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, padded, v_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            # beside the queries, the accumulators and the double buffer,
+            # the f32 score and probability tiles: over the 16 MiB default
+            vmem_limit_bytes=64 * 2 ** 20),
+        interpret=interpret,
+    )(layer, q_start.astype(jnp.int32), tables.astype(jnp.int32), q,
+      w_uk.reshape(latent_dim, h * nope_dim).astype(pool.dtype),
+      w_uv.reshape(latent_dim, h * v_dim).astype(pool.dtype), pool)
+    return jnp.transpose(o[:, :, :c], (0, 2, 1, 3))

@@ -22,7 +22,8 @@ import pytest
 
 from kubeflow_tpu.models import mla_moe
 from kubeflow_tpu.ops.pallas_paged_attention import (
-    paged_latent_decode_attention,
+    _prefill_tiles, paged_latent_decode_attention,
+    paged_latent_prefill_attention,
 )
 from kubeflow_tpu.parallel import moe
 from kubeflow_tpu.serving import paged_kv
@@ -173,6 +174,115 @@ def test_decode_kernel_is_the_gather_path(lens):
     assert np.abs(np.asarray(out) - np.asarray(want)).max() < 1e-5
 
 
+BS = 8
+# the kernel's own tiles at this block size (1,024 queries x 1,024 rows)
+Q_TILE, _TILE_BLOCKS = _prefill_tiles(1 << 20, BS, 1 << 20)
+KV_TILE = _TILE_BLOCKS * BS
+
+
+@pytest.mark.parametrize("case", [
+    dict(name="from the first row", q_start=[0]),
+    dict(name="mid-block", q_start=[13]),
+    dict(name="several tiles deep", q_start=[2 * KV_TILE + 24]),
+    dict(name="ragged final chunk, pad rows past the table", q_start=[200],
+         n_tables=(200 + 2 * Q_TILE) // BS - 20),
+    dict(name="narrower than a query sub-tile", c=40, q_start=[77]),
+    dict(name="narrower than a sublane tile", c=5, q_start=[30]),
+    dict(name="two slots, two offsets", q_start=[64, KV_TILE + 391]),
+    dict(name="permuted table", q_start=[130], permute=True),
+    dict(name="row padded past row_dim", q_start=[96], row=128),
+], ids=lambda case: case["name"])
+def test_prefill_kernel_is_the_plain_masked_softmax(case):
+    """``paged_latent_prefill_attention`` in interpret mode against the plain
+    form: gather the slot's rows by its table, up-project them, ONE masked
+    float32 softmax over all of them."""
+    h, latent, d_n, d_r, d_v, bs = 2, 16, 8, 8, 8, BS
+    # two query sub-tiles unless the case says otherwise
+    c = case.get("c", 2 * Q_TILE)
+    q_start = jnp.asarray(case["q_start"], jnp.int32)
+    b = len(case["q_start"])
+    row = case.get("row", latent + d_r)
+    # one table width, so the cases share a compile; a final chunk's pad
+    # rows run past the last table entry
+    n_tables = case.get("n_tables", (2 * KV_TILE + 2 * Q_TILE) // BS + 4)
+    nb = b * n_tables + 1
+    ks = jax.random.split(jax.random.key(4), 4)
+    q = jax.random.normal(ks[0], (b, c, h, d_n + d_r))
+    pool = jax.random.normal(ks[1], (3, nb, bs, row))
+    w_uk = 0.3 * jax.random.normal(ks[2], (latent, h, d_n))
+    w_uv = 0.3 * jax.random.normal(ks[3], (latent, h, d_v))
+    ids = np.arange(1, nb)
+    if case.get("permute"):
+        ids = np.random.default_rng(0).permutation(ids)
+    tables = jnp.asarray(ids.reshape(b, n_tables), jnp.int32)
+    out = paged_latent_prefill_attention(
+        q, pool, w_uk, w_uv, 1, tables, q_start, rope_dim=d_r, scale=0.25,
+        interpret=True)
+    assert out.shape == (b, c, h, d_v)
+
+    rows = pool[1][tables].reshape(b, n_tables * bs, row)
+    k = jnp.concatenate([
+        jnp.einsum("btc,chk->bthk", rows[..., :latent], w_uk),
+        jnp.broadcast_to(rows[:, :, None, latent:latent + d_r],
+                         (b, n_tables * bs, h, d_r))], -1)
+    v = jnp.einsum("btc,chv->bthv", rows[..., :latent], w_uv)
+    s = jnp.einsum("bqhk,bthk->bhqt", q, k) * 0.25
+    q_pos = q_start[:, None] + jnp.arange(c)[None]
+    seen = jnp.arange(n_tables * bs)[None, None] <= q_pos[:, :, None]
+    p = jax.nn.softmax(jnp.where(seen[:, None], s, -1e30), -1)
+    want = jnp.einsum("bhqt,bthv->bqhv", p, v)
+    # rows at positions the table does not reach (a final chunk's padding)
+    # give finite output that nobody reads: compared up to the table's end
+    live = np.asarray(q_pos < n_tables * bs)
+    assert np.isfinite(np.asarray(out)).all()
+    assert (not live.all()) == ("pad rows" in case["name"])
+    assert np.abs(np.asarray(out) - np.asarray(want))[live].max() < 2e-5
+
+
+def test_prefill_roofline_counts_the_causal_pairs_of_the_chunks_that_ran():
+    """The benchmark's count for the kernel (``lib/latent_prefill.py``) and
+    its reader on a made-up trace: rows x (offset + (rows + 1) / 2) pairs a
+    chunk, the chunks whose span ended inside the trace brought to the
+    executions the device shows; nothing where the program has no such
+    kernel (the parent)."""
+    import types
+
+    from lib import latent_prefill, peaks
+    from readers import latent_prefill_roofline
+
+    cfg = {"num_attention_heads": 3, "qk_head_dim": 5, "v_head_dim": 2,
+           "num_hidden_layers": 2}
+    # 4 rows at offset 10 attend 11 + 12 + 13 + 14 rows
+    assert latent_prefill.chunk_attention_flops(cfg, 10, 4) \
+        == 50 * 3 * 2 * 7 * 2
+
+    def span(t1, offset, prompt):
+        return {"name": "prefill.chunk", "t0": t1 - 0.01, "t1": t1,
+                "attrs": {"offset": offset, "width": 8,
+                          "prompt_tokens": prompt}}
+
+    kernel = {"program": "jit__lambda(1)", "seconds": 1e-9, "count": 6.0,
+              "name": "closed_call.26 custom-call bf16[1,3,8,2]"}
+    other = [{"program": "jit__lambda(1)", "name": "gmm.13 custom-call",
+              "seconds": 5.0, "count": 4.0},
+             {"program": "jit__decode_impl(2)", "seconds": 7.0, "count": 9.0,
+              "name": "closed_call.72 custom-call bf16[24,32,512]"}]
+    run = types.SimpleNamespace(
+        trace={"ops": [kernel] + other}, t_trace=(100.0, 103.0), config=cfg,
+        device={"kind": "TPU v5 lite"},
+        # a whole chunk, a final chunk of 3 true rows, one outside the trace
+        spans=[span(100.5, 8, 40), span(101.0, 16, 19), span(99.0, 0, 40)])
+    flops = latent_prefill.chunk_attention_flops(cfg, 8, 8) \
+        + latent_prefill.chunk_attention_flops(cfg, 16, 3)
+    # six executions over two layers: three chunks ran where two spans ended
+    want = 100.0 * flops * 3 / 2 / peaks.peaks(
+        "TPU v5 lite")["bf16_flops_per_s"] / 1e-9
+    args = {"program": "^jit__lambda", "op": r"^closed_call\.\d+ custom-call"}
+    assert latent_prefill_roofline.read(run, **args) == pytest.approx(want)
+    run.trace = {"ops": other}
+    assert latent_prefill_roofline.read(run, **args) is None
+
+
 def _paged_logits(params, toks, n_prompt, chunk, kernel):
     """Prefill ``toks[:n_prompt]`` in chunks of ``chunk`` through the pool,
     then decode the rest one token a step: logits at rows n_prompt-1 .."""
@@ -198,16 +308,19 @@ def _paged_logits(params, toks, n_prompt, chunk, kernel):
     return np.stack([np.asarray(a) for a in out])
 
 
-@pytest.mark.parametrize("chunk,kernel", [(64, "gather"), (16, "gather"),
-                                          (16, "pallas")])
-def test_prefill_and_decode_through_the_pool(params, chunk, kernel):
-    """One chunk and several, then decode beside an idle slot, against the
-    reference's full forward, on logits."""
-    toks = _tokens(50, seed=2)
-    n_prompt = 41
+@pytest.mark.parametrize("chunk,kernel,n_prompt", [
+    (64, "gather", 41), (16, "gather", 41), (16, "pallas", 41),
+    # three chunks of the prefill kernel, the last one ragged, then the
+    # decode kernel over what they wrote
+    (32, "pallas", 90)])
+def test_prefill_and_decode_through_the_pool(params, chunk, kernel, n_prompt):
+    """One chunk and several (each through the prefill chunk kernel,
+    interpreted), then decode beside an idle slot, against the reference's
+    full forward, on logits."""
+    toks = _tokens(n_prompt + 9, seed=2)
     got = _paged_logits(params, toks, n_prompt, chunk, kernel)
     ref = reference.forward(params, toks, REF_CFG,
-                            rows=range(n_prompt - 1, 50))
+                            rows=range(n_prompt - 1, len(toks)))
     assert np.abs(got[:-1] - ref["logits"][:-1]).max() < LOGIT_TOL
 
 

@@ -289,6 +289,66 @@ def test_latent_decode_chunk_updates_the_pool_in_place(v5e, monkeypatch):
     assert experts * 2 * 3 * 2 * 1.01 < mem.argument_size_in_bytes
 
 
+def test_latent_prefill_chunk_is_one_kernel_a_layer_stack(v5e, monkeypatch):
+    """``paged_prefill_chunk`` of the latent model at the cell's engine
+    (chunk 2,048, 416 table entries, 7,168 blocks, the published widths, 1
+    dense + 2 expert layers) with the cache donated, as the engine jits it:
+    the chunk's attention is ONE Mosaic kernel per layer stack that takes
+    the pool itself, nothing of a score tile's or a context's shape is left
+    to XLA, and the program's temporaries are no more than they were with
+    the float32 walk in plain XLA."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c = LATENT
+    chunk = 2048
+    cfg = mla_moe.MlaMoeConfig(n_layers=3, n_predict_layers=0,
+                               max_seq=c["nbp"] * BS)
+    one = NamedSharding(Mesh(v5e[:1], ("x",)), P())
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one), tree)
+
+    params = jax.eval_shape(lambda: mla_moe.init_params(
+        jax.random.key(0), cfg, dtype=jnp.bfloat16))
+    cache = jax.eval_shape(lambda: paged_kv.init_paged_cache(
+        cfg, c["b"], c["nbp"] * BS, BS, c["nb"]))
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+
+    def prefill(params, tokens, cache, tables, slot, offset, length, share):
+        return paged_kv.paged_prefill_chunk(params, tokens, cfg, cache,
+                                            tables, slot, offset, length,
+                                            share)
+
+    compiled = jax.jit(prefill, donate_argnums=(2,)).lower(
+        on_chip(params),
+        jax.ShapeDtypeStruct((1, chunk), jnp.int32, sharding=one),
+        on_chip(cache),
+        jax.ShapeDtypeStruct((c["b"], c["nbp"]), jnp.int32, sharding=one),
+        scalar, scalar, scalar, scalar).compile()
+    hlo = compiled.as_text()
+    kernels = [line for line in hlo.splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    # a dense and an expert stack: two call sites of the attention kernel
+    # (the benchmark's reader finds it by this name), beside the grouped
+    # products
+    attention = [k for k in kernels if re.search(
+        r"%closed_call\.\d+ = bf16\[1,32,2048,128\]", k)]
+    assert len(attention) == 2, kernels
+    assert len([k for k in kernels if re.search(r"%gmm(\.\d+)? = ", k)]) == 3
+    assert paged_kv.pool_shaped_ops(hlo, [cache["kv"].shape]) == []
+    # the walk's score tiles [32, 2048, 1024] and a context's keys or values
+    # [26624, 32, 128]: neither is anywhere in the program
+    assert not re.search(r"f32\[(1,)?32,2048,\d+\]", hlo)
+    assert not re.search(r"\[(1,)?26624,32,\d+\]", hlo)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache["kv"].size * 2
+    # the parent's program (the walk in plain XLA) at these shapes
+    assert mem.temp_size_in_bytes <= PARENT_PREFILL_TEMP_BYTES
+
+
+PARENT_PREFILL_TEMP_BYTES = 289_382_400      # 94,674,944 with the kernel (PR 32)
+
+
 def test_pool_shaped_ops_finds_what_the_parent_did():
     """So that the checker cannot pass by seeing nothing: on the lines of
     the parent's program (layers as ``xs``/``ys``, pool slices fed to the
