@@ -434,9 +434,9 @@ def _paged_ops(cfg: LlamaConfig) -> PagedOps:
     programs see it."""
     inv_freq = rope_inv_freq(cfg)
 
-    def qkv(lp, x, positions):
+    def qkv(lp, x, positions, state):
         q, k, v = attention_inputs(lp, x, positions, cfg, inv_freq)
-        return q, {"k": k, "v": v}
+        return q, {"k": k, "v": v}, state      # no per-slot rows: {}
 
     def decode_attn(lp, q, pools, layer, tables, kv_len, kernel, mesh,
                     interpret):
@@ -475,9 +475,9 @@ def _paged_ops(cfg: LlamaConfig) -> PagedOps:
         embed=lambda params, tokens: embed_tokens(params, tokens, cfg),
         qkv=qkv, decode_attention=decode_attn,
         chunk_attention=chunk_attention,
-        out=lambda lp, x, o, token_mask: (
+        out=lambda lp, x, o, token_mask, carry: (
             attention_out_and_ffn(lp, x, o, cfg, token_mask=token_mask)[0],
-            {}),
+            carry, {}),
         head=lambda params, x_last: head_logits(
             params, x_last, cfg).astype(jnp.float32),
         bucket_prefill=lambda params, tokens, lengths: bucket_prefill(

@@ -461,13 +461,13 @@ def _paged_ops(cfg: MlaMoeConfig):
     def embed(params, tokens):
         return params["embed"].astype(cfg.dtype)[tokens]
 
-    def qkv(lp, x, positions):
+    def qkv(lp, x, positions, state):
         q_nope, q_rope, row = queries_and_row(lp, x, positions, cfg)
         pad = cfg.pool_row - cfg.row_dim
         if pad:
             row = jnp.concatenate(
                 [row, jnp.zeros((*row.shape[:-1], pad), row.dtype)], -1)
-        return (q_nope, q_rope), {"kv": row}
+        return (q_nope, q_rope), {"kv": row}, state    # no per-slot rows
 
     def decode_attention(lp, q, pools, layer, tables, kv_len, kernel, mesh,
                          interpret):
@@ -494,6 +494,10 @@ def _paged_ops(cfg: MlaMoeConfig):
                                view[..., :cfg.kv_lora_rank])
         return values_from_latent(lp, o_lat[:, None], cfg)
 
+    def out(lp, x, o, token_mask, carry):
+        x, stats = attention_out_and_ffn(lp, x, o, cfg, token_mask)
+        return x, carry, stats
+
     def chunk_attention(lp, q, pools, layer, tables, q_start):
         """q of [B, C] rows at positions ``q_start[b] + i`` over each
         slot's blocks (its own rows already scattered): the non-absorbed
@@ -513,8 +517,7 @@ def _paged_ops(cfg: MlaMoeConfig):
         pool_rows={"kv": (cfg.pool_row,)},
         layer_stacks=layer_stacks, embed=embed, qkv=qkv,
         decode_attention=decode_attention, chunk_attention=chunk_attention,
-        out=lambda lp, x, o, token_mask: attention_out_and_ffn(
-            lp, x, o, cfg, token_mask),
+        out=out,
         head=lambda params, x_last: lm_head(params, x_last, cfg),
         routed_per_token=cfg.moe_top_k * cfg.n_moe_layers,
         refuses={

@@ -206,13 +206,16 @@ class RouterConfig:
     scale: float = 1.0
 
 
-def route(tokens, router_w, bias, rc: RouterConfig):
+def route(tokens, router_w, bias, rc: RouterConfig, logits=None):
     """tokens [T, D] -> (experts [T, k] int32, weights [T, k] f32).
     Logits, scores and the choice are float32 at full matmul precision: a
-    bf16 product here moves the 8th and 9th score past each other."""
-    logits = jnp.dot(tokens.astype(jnp.float32),
-                     router_w.astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
+    bf16 product here moves the 8th and 9th score past each other.
+    ``logits`` [T, E] float32: a router that is more than one matrix hands
+    its own in (``tokens`` and ``router_w`` are then not read)."""
+    if logits is None:
+        logits = jnp.dot(tokens.astype(jnp.float32),
+                         router_w.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
     if rc.score_func == "sigmoid":
         scores = jax.nn.sigmoid(logits)
     elif rc.score_func == "softmax":
@@ -240,8 +243,9 @@ def grouped_matmul(rows, weights, group_sizes):
     were hit and nothing else. Chosen over ``jax.lax.ragged_dot`` on the
     chip (PERF.md section 6, PR 29: 0.66 against 1.06 ms for 192 rows over
     139 experts, 1.6 against 4.2 ms for 16,384 rows). Row tiles of 32 for
-    a decode step's tens of rows, 128 for a prefill chunk's thousands; on
-    the CPU the same kernel runs under the Pallas interpreter."""
+    a decode step's tens of rows, 128 for a prefill chunk's thousands,
+    weight tiles of up to 2,048 x 1,024; on the CPU the same kernel runs
+    under the Pallas interpreter."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     m, k = rows.shape
@@ -250,8 +254,13 @@ def grouped_matmul(rows, weights, group_sizes):
     pad = -m % tm
     if pad:
         rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    tk, tn = min(k, 2048), min(n, 2048)
+    if tk * tn > 2048 * 1024:
+        # a weight tile is double-buffered: two of 2,048 x 2,048 bf16 are
+        # the whole 16 MiB of scoped VMEM (experts as wide as the model)
+        tn = 1024
     out = gmm(rows, weights, group_sizes, preferred_element_type=rows.dtype,
-              tiling=(tm, min(k, 2048), min(n, 2048)),
+              tiling=(tm, tk, tn),
               interpret=jax.default_backend() == "cpu")
     return out[:m]
 

@@ -325,6 +325,7 @@ class LLMModel(Model):
             "kv_reclaimable_blocks": eng.paged.reclaimable_blocks,
             "prefix_cache_hits_total": eng.paged.prefix_hits,
             "kv_row_bytes": eng.kv_row_bytes(),
+            "slot_state_bytes": eng.slot_state_bytes,
             # expert layers (0 for a dense model): assignments routed and
             # distinct experts hit, summed over decode steps and layers
             "moe_routed_assignments_total": int(

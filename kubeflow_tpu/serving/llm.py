@@ -81,9 +81,11 @@ class SamplingParams:
     # any of these ends generation like eos (finish_reason "stop"); text
     # stop STRINGS live a layer up in LLMModel, which owns the tokenizer
     stop_token_ids: tuple = ()
-    # expert models: keep, per generated token, the experts every expert
-    # layer routed it to (``GenRequest.routing``) — what a check against a
-    # reference needs to tell a near-tie from a wrong router
+    # expert models: keep the experts every expert layer routed a token
+    # to, per generated token (``GenRequest.routing``) and per prompt row
+    # (``GenRequest.prompt_routing``) — what a check against a reference
+    # needs to tell a near-tie from a wrong router, and to follow the
+    # program through a near-tie in the prompt that later rows attend to
     record_routing: bool = False
 
 
@@ -97,6 +99,9 @@ class GenRequest:
     logprobs: list[float] = dataclasses.field(default_factory=list)
     # SamplingParams.record_routing: [expert layers, top_k] per token
     routing: list = dataclasses.field(default_factory=list)
+    # ... and [expert layers, prompt rows, top_k]: the prompt rows the
+    # chunked prefill computed (all of them without a shared prefix)
+    prompt_routing: Any = None
     done: bool = False
     aborted: bool = False
     # set by a text-level stop-string watcher before aborting: the abort
@@ -153,6 +158,8 @@ class _ChunkedPrefill:
     tables: Any
     x_last: Any = None
     stats: Any = None
+    # SamplingParams.record_routing: each chunk's choices, on the device
+    routing: list = dataclasses.field(default_factory=list)
 
 
 def _bucket(n: int, buckets: Sequence[int]) -> int:
@@ -220,7 +227,7 @@ def sample_logits(logits, rng, temperature, top_k, top_p,
 class LLMEngine:
     """Continuous-batching generation over a model's paged programs.
     ``cfg`` is any config with a ``paged_ops()`` method (``LlamaConfig``,
-    ``MlaMoeConfig``): what the engine needs of the model — the pool's
+    ``MlaMoeConfig``, ``CcaMoeConfig``): what the engine needs of the model — the pool's
     rows, the layer's pieces, the head, what it cannot be served with — it
     asks through that one object."""
 
@@ -258,11 +265,17 @@ class LLMEngine:
             "speculative decode": scheduler is not None
             and scheduler.spec_decode,
             "tensor mesh": mesh is not None,
+            # the default policy shares prefixes; for a model that cannot,
+            # only a policy handed in that asks for it is refused, and the
+            # default leaves it off (below)
+            "radix prefix cache": scheduler is not None
+            and scheduler.radix_cache,
         }
-        for mechanism, why in ops.refuses.items():
+        for mechanism in ops.refuses:
             if asked.get(mechanism):
-                raise ValueError(f"{type(cfg).__name__} cannot be served "
-                                 f"with {mechanism}: {why}")
+                self._refuse(mechanism)
+        if scheduler is None and "radix prefix cache" in ops.refuses:
+            scheduler = SchedulerConfig(radix_cache=False)
         # decode-attention path (paged_kv module docstring): the
         # block-resident Pallas kernel is the TPU default — including
         # under a mesh, where it runs shard_map'd over the heads/KV
@@ -354,6 +367,10 @@ class LLMEngine:
                              quant_kv=self.quant.kv_dtype,
                              scale_sharding=sc_sh)
         self.cache = self.paged.cache
+        # what the cache holds besides the pools: the rows a model keeps
+        # per slot and layer (``PagedOps.slot_rows``; 0 for most)
+        self.slot_state_bytes = sum(
+            self.cache[key].nbytes for key in ops.slot_rows)
         self._free: list[int] = list(range(max_batch))
         self._active: dict[int, GenRequest] = {}     # slot -> request
         self._waiting: list[GenRequest] = []
@@ -404,6 +421,16 @@ class LLMEngine:
         # process collector, plus the three request-latency histograms
         # /metrics serves as kft_model_request_{ttft,itl,e2e}_seconds
         self.obs = obs or obs_trace.collector()
+        sizes = {"pool_bytes": sum(self.cache[key].nbytes
+                                   for key in ops.pool_rows),
+                 "slot_state_bytes": self.slot_state_bytes}
+        self.obs.end(self.obs.start("engine.build", attrs={
+            "kv_num_blocks": kv_num_blocks, "kv_block_size": kv_block_size,
+            "max_batch": max_batch, **sizes}))
+        logger.info("paged cache: %d blocks of %d rows, pools %d bytes, "
+                    "per-slot state %d bytes over %d slots", kv_num_blocks,
+                    kv_block_size, sizes["pool_bytes"],
+                    self.slot_state_bytes, max_batch)
         # the engine thread's timeline (_phase): the open ``engine.step``
         # span and, inside it, the one open phase (name, span, annotation)
         self._step_span: Optional[obs_trace.Span] = None
@@ -516,7 +543,7 @@ class LLMEngine:
             token, cache = carry
             logits, cache, stats = paged_decode_step(
                 params, token, self.cfg, cache, tables, kernel=kernel,
-                mesh=self.mesh)
+                mesh=self.mesh, active=active)
             nxt = sample_logits(logits, rng_step, temperature, top_k,
                                 top_p, greedy_only=greedy_only)
             # chosen-token logprob under the MODEL distribution (OpenAI
@@ -550,6 +577,16 @@ class LLMEngine:
 
     # ---------------- public API ----------------
 
+    def _refuse(self, mechanism: str) -> None:
+        """Raise for a mechanism the model cannot be served with
+        (``PagedOps.refuses``), naming it and the reason."""
+        raise ValueError(f"{type(self.cfg).__name__} cannot be served with "
+                         f"{mechanism}: {self.model.refuses[mechanism]}")
+
+    def _refuse_tiers(self) -> None:
+        if "disaggregated tiers" in self.model.refuses:
+            self._refuse("disaggregated tiers")
+
     def precompile(self, depot=None, stats=None, wait_s: float = 0.0,
                    tier: str = "") -> str:
         """Split the decode compile from request #1 (the serving analogue
@@ -570,6 +607,8 @@ class LLMEngine:
         from kubeflow_tpu.parallel.depot import load_or_compile
 
         b = self.max_batch
+        if tier:
+            self._refuse_tiers()
         if tier == "prefill":
             # the prefill tier's steady-state program is the CHUNKED
             # prefill (long prompts stream through it; bucketed admission
@@ -665,6 +704,8 @@ class LLMEngine:
         prefill tier — park after prefill + first token for KV export
         instead of decoding."""
         sampling = sampling or SamplingParams()
+        if hold_after_prefill:
+            self._refuse_tiers()
         self.validate_prompt(prompt, sampling)
         req = GenRequest(id=next(self._ids), prompt=list(map(int, prompt)),
                          sampling=sampling,
@@ -769,6 +810,7 @@ class LLMEngine:
         local re-prefill)."""
         from kubeflow_tpu.serving.paged_kv import scatter_kv_blocks
 
+        self._refuse_tiers()
         with self._lock:
             if not self._free:
                 return None
@@ -1226,8 +1268,10 @@ class LLMEngine:
     def _note_expert_stats(self, stats, decode: bool = False) -> dict:
         """Fold a program's expert counts (``PagedOps.out``; nothing for
         a dense model) into the engine's counters. Returns the span attrs
-        of a decode chunk: assignments made and distinct experts hit,
-        both summed over its steps and expert layers."""
+        of a decode chunk: assignments made and distinct experts hit (and
+        tokens that chose no expert, where the router has that choice: it
+        is the counters' last column), summed over its steps and expert
+        layers."""
         if not stats:
             return {}
         per_expert = np.asarray(stats["tokens_per_expert"])      # [Lm, E]
@@ -1238,8 +1282,11 @@ class LLMEngine:
             return {}
         hit = int(np.asarray(stats["experts_hit"]).sum())
         self.moe_experts_hit += hit
-        return {"routed_assignments": int(per_expert.sum()),
-                "experts_hit": hit}
+        attrs = {"routed_assignments": int(per_expert.sum()),
+                 "experts_hit": hit}
+        if "skipped" in stats:       # a router with a choice of no expert
+            attrs["skipped"] = int(np.asarray(stats["skipped"]).sum())
+        return attrs
 
     def _spec_step(self) -> list[GenRequest]:
         """One speculative draft+verify round over the active batch.
@@ -1393,9 +1440,13 @@ class LLMEngine:
         if self.model.routed_per_token:
             attrs["routed_assignments"] = \
                 min(W, L - st.offset) * self.model.routed_per_token
+        if self.model.slot_rows:
+            # the chunk began from what the slot's previous chunk left,
+            # not from zeros
+            attrs["state_carried"] = st.offset > 0
         pspan = self._dispatch_span(
             "prefill.chunk", [req], slot=slot, offset=st.offset,
-            width=W, prompt_tokens=L, **attrs)
+            width=W, chunk_index=st.offset // W, prompt_tokens=L, **attrs)
         piece = np.zeros((1, W), np.int32)
         part = req.prompt[st.offset:st.offset + W]
         piece[0, :len(part)] = part
@@ -1406,9 +1457,11 @@ class LLMEngine:
             jnp.int32(st.share_len))
         # the chunks' expert counts stay on the device until the last
         # chunk's token is read back: no wait of their own
+        experts = stats.pop("experts", None)   # [layers, W, k], not a count
+        if experts is not None and req.sampling.record_routing:
+            st.routing.append(experts)
         st.stats = stats if st.stats is None else {
-            key: val if key == "experts" else st.stats[key] + val
-            for key, val in stats.items()}
+            key: st.stats[key] + val for key, val in stats.items()}
         st.offset += W
         self.sched.note_prefill_chunk(W)
         self.obs.end(pspan, final=st.offset >= L)
@@ -1421,8 +1474,12 @@ class LLMEngine:
             logits = self._chunk_lm_head(self.params, st.x_last)
             tok, lp = self._sample_rows(logits, [req])
             self._note_expert_stats(st.stats)
-            if st.stats and req.sampling.record_routing:
-                req.routing.append(np.asarray(st.stats["experts"]))
+            if st.routing:
+                # chunks of [layers, W, k]; the last one's pad rows cut
+                rows = np.concatenate(
+                    [np.asarray(e) for e in st.routing], axis=1)
+                req.prompt_routing = rows[:, :rows.shape[1] - (st.offset - L)]
+                req.routing.append(req.prompt_routing[:, -1])
             self.cache = self._set_len(
                 self.cache, jnp.int32(L), jnp.int32(slot))
             del self._chunked[slot]

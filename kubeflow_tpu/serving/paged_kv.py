@@ -82,7 +82,10 @@ def init_paged_cache(cfg, max_batch: int, max_seq: int,
     (num_blocks * block_size), independent of max_batch * max_seq. The
     pools and their rows are the model's (``PagedOps.pool_rows``): K and V
     of ``[KV, D]`` rows for a GQA model, one ``kv`` pool of latent rows for
-    a latent-attention one.
+    a latent-attention one. What a model's layers keep per SLOT and not per
+    token (``PagedOps.slot_rows``) lies beside the pools, ``[n_layers,
+    max_batch, *row]``: a second kind of state in the one cache, carried,
+    donated and updated in place with the pools.
     ``kv_sharding`` allocates the pool DIRECTLY with that sharding — a
     pod-sized pool must never transit one chip unsharded.
 
@@ -112,6 +115,8 @@ def init_paged_cache(cfg, max_batch: int, max_seq: int,
             cache[name + "_scale"] = jnp.zeros(
                 (ops.n_layers, num_blocks, row[0]), jnp.float32,
                 device=scale_sharding)
+    for name, row in ops.slot_rows.items():
+        cache[name] = jnp.zeros((ops.n_layers, max_batch, *row), dtype)
     cache["len"] = jnp.zeros((max_batch,), jnp.int32, device=len_sharding)
     return cache
 
@@ -569,20 +574,24 @@ def _scatter_rows(pools, layer, blk, off, rows):
         v_pool, v_sc = quant_scatter_rows(pools["v"], pools["v_scale"],
                                           layer, blk, off, rows["v"])
         return {"k": k_pool, "v": v_pool, "k_scale": k_sc, "v_scale": v_sc}
-    return {key: pools[key].at[layer, blk, off].set(
-                rows[key].astype(pools[key].dtype)) for key in pools}
+    return {**pools, **{key: pools[key].at[layer, blk, off].set(
+        val.astype(pools[key].dtype)) for key, val in rows.items()}}
 
 
 def _scan_layers(ops: PagedOps, params, x, cache, layer_fn):
-    """Run ``layer_fn(lp, x, pools, layer) -> (x, pools, stats)`` over the
-    layers with the layer index and the layer's weights scanned and the
-    pools (and the scale tables of a quantized pool) in the CARRY, so each
-    layer's rows are scattered into the one buffer that came in. A model
+    """Run ``layer_fn(lp, x, extra, pools, layer) -> (x, extra, pools,
+    stats)`` over the layers with the layer index and the layer's weights
+    scanned and the pools (the scale tables of a quantized pool and a
+    model's per-slot rows with them) in the CARRY, so each layer's rows are
+    scattered into the one buffer that came in. ``extra`` is what the
+    model's layers hand one another beside ``x``
+    (``PagedOps.layer_carry(x)`` as it enters the first layer, ``{}`` for a
+    model that has none: an empty carry adds nothing to the loop). A model
     whose layers are of several kinds has one stack per kind
     (``ops.layer_stacks``): the stacks are scanned one after the other,
     the layer index running on, the same carry through all of them.
-    Returns (x, pools, stats) with each stat stacked over the layers that
-    report it.
+    Returns (x, extra, pools, stats) with each stat stacked over the
+    layers that report it.
 
     A stack comes as ``(stacked tree, whole)``: the weights named in
     ``whole`` are NOT scanned but handed to every layer as the stack they
@@ -590,6 +599,7 @@ def _scan_layers(ops: PagedOps, params, x, cache, layer_fn):
     kernel addresses by (layer, group) itself, which a scan would slice
     out, a copy, layer by layer."""
     pools = {key: cache[key] for key in _pool_keys(cache)}
+    extra = ops.layer_carry(x) if ops.layer_carry else {}
     first, stats = 0, {}
     for stack, whole in ops.layer_stacks(params):
         kept = {key: stack[key] for key in whole}
@@ -603,15 +613,15 @@ def _scan_layers(ops: PagedOps, params, x, cache, layer_fn):
             layer, lp = xs[:2]
             if kept:
                 lp = dict(lp, **kept, stack_index=xs[2])
-            x, pools, ys = layer_fn(lp, *carry, layer)
-            return (x, pools), ys
+            *carry, ys = layer_fn(lp, *carry, layer)
+            return tuple(carry), ys
 
-        (x, pools), ys = jax.lax.scan(body, (x, pools), xs)
+        (x, extra, pools), ys = jax.lax.scan(body, (x, extra, pools), xs)
         first += n
         for key, val in ys.items():
             stats[key] = (val if key not in stats
                           else jnp.concatenate([stats[key], val]))
-    return x, pools, stats
+    return x, extra, pools, stats
 
 
 def paged_insert_batch(cache, k_new, v_new, blk_ids, lengths, slots):
@@ -716,7 +726,7 @@ def resolve_decode_kernel(kernel: str, mesh=None,
 
 
 def paged_decode_step(params, token, cfg, cache, tables,
-                      kernel: str = "gather", mesh=None):
+                      kernel: str = "gather", mesh=None, active=None):
     """One decode step over the paged pool. token: [B] int32; tables:
     [B, max_blocks_per_seq] int32 -> (logits [B, V], cache, stats: the
     layers' counts from ``PagedOps.out``, ``{}`` for a dense model). The
@@ -725,7 +735,14 @@ def paged_decode_step(params, token, cfg, cache, tables,
     picks the attention path: "gather" | "pallas" | "auto"; with ``mesh``
     the pallas path runs shard_map'd over the heads/KV tensor axis
     (per-shard pool blocks, replicated tables). ``cfg`` is any config
-    with a ``paged_ops()`` method."""
+    with a ``paged_ops()`` method.
+
+    Per-slot rows (``PagedOps.slot_rows``): a layer reads what the slot's
+    previous token left and the step's one token leaves its own, in
+    place. A slot that holds no sequence (``len`` 0) or is not ``active``
+    ([B] bool: the engine's dispatch mask; a slot freed or mid-prefill
+    whose ``len`` is stale for one more step) writes nothing: its row must
+    stay what its next chunk or step expects."""
     ops = paged_ops(cfg)
     kernel, _ = resolve_decode_kernel(kernel, mesh=mesh,
                                       n_kv_heads=cfg.n_kv_heads)
@@ -741,18 +758,25 @@ def paged_decode_step(params, token, cfg, cache, tables,
     off = pos % bs                                       # [B] row in block
     # idle slots hold len 0: keep their garbage rows out of expert routing
     token_mask = (pos > 0)[:, None]
+    live = token_mask
+    if ops.slot_rows and active is not None:
+        live = token_mask & active[:, None]
 
-    def layer_fn(lp, x, pools, layer):
-        q, rows = ops.qkv(lp, x, positions)
+    def layer_fn(lp, x, extra, pools, layer):
+        state = {name: pools[name][layer] for name in ops.slot_rows}
+        q, rows, left = ops.qkv(lp, x, positions, state)
         # scatter this step's row into each slot's current block
         pools = _scatter_rows(pools, layer, blk, off,
                               {key: r[:, 0] for key, r in rows.items()})
+        for name, val in left.items():
+            pools[name] = pools[name].at[layer].set(
+                jnp.where(live, val[:, 0], state[name]))
         o = ops.decode_attention(lp, q, pools, layer, tables, pos + 1,
                                  kernel, mesh, interpret)
-        x, stats = ops.out(lp, x, o, token_mask)
-        return x, pools, stats
+        x, extra, stats = ops.out(lp, x, o, token_mask, extra)
+        return x, extra, pools, stats
 
-    x, pools, stats = _scan_layers(ops, params, x, cache, layer_fn)
+    x, _, pools, stats = _scan_layers(ops, params, x, cache, layer_fn)
     logits = ops.head(params, x[:, 0])
     cache = {**pools, "len": cache["len"] + 1}
     return logits, cache, stats
@@ -778,9 +802,17 @@ def paged_prefill_chunk(params, tokens, cfg, cache,
     chunk's last TRUE row — the head applies final_norm; the caller runs
     it ONCE on the final chunk's value rather than paying a full-vocab
     matmul per chunk — the updated cache, and the layers' counts as in
-    ``paged_decode_step``). cache["len"] for the slot is NOT advanced
+    ``paged_decode_step``, an expert model's choices ``experts`` at every
+    row of the chunk [layers, C, k]: a pad row's mean nothing).
+    cache["len"] for the slot is NOT advanced
     here; the engine sets it once after the last chunk (decode masks by
-    len, so partial writes stay invisible)."""
+    len, so partial writes stay invisible).
+
+    Per-slot rows (``PagedOps.slot_rows``): the chunk's first token reads
+    what the slot's previous chunk left, and ZEROS at ``offset`` 0, inside
+    the program, so admission resets nothing on the host and a reused slot
+    never sees its predecessor; the chunk's last TRUE row leaves the slot's
+    new rows, pad rows past ``length`` nothing."""
     ops = paged_ops(cfg)
     _, c = tokens.shape
     bs = cache[next(iter(ops.pool_rows))].shape[2]
@@ -797,21 +829,25 @@ def paged_prefill_chunk(params, tokens, cfg, cache,
     positions = pos[None, :]
     x = ops.embed(params, tokens)
     q_start = jnp.reshape(offset, (1,))
+    last_row = jnp.clip(length - offset - 1, 0, c - 1)
 
-    def layer_fn(lp, x, pools, layer):
-        q, rows = ops.qkv(lp, x, positions)
+    def layer_fn(lp, x, extra, pools, layer):
+        state = {name: jnp.where(offset > 0, pools[name][layer, slot], 0)[None]
+                 for name in ops.slot_rows}
+        q, rows, left = ops.qkv(lp, x, positions, state)
         pools = _scatter_rows(pools, layer, blk, off,
                               {key: r[0] for key, r in rows.items()})
+        for name, val in left.items():
+            pools[name] = pools[name].at[layer, slot].set(val[0, last_row])
         o = ops.chunk_attention(lp, q, pools, layer, tables[slot][None],
                                 q_start)
-        x, stats = ops.out(lp, x, o, valid[None, :])
-        return x, pools, stats
+        x, extra, stats = ops.out(lp, x, o, valid[None, :], extra)
+        return x, extra, pools, stats
 
-    last_row = jnp.clip(length - offset - 1, 0, c - 1)
-    x, pools, stats = _scan_layers(ops, params, x, cache, layer_fn)
+    x, _, pools, stats = _scan_layers(ops, params, x, cache, layer_fn)
     cache = {**pools, "len": cache["len"]}
-    if "experts" in stats:       # [layers, 1, C, k]: the last true row's
-        stats["experts"] = stats["experts"][:, 0, last_row]
+    if "experts" in stats:       # [layers, 1, C, k] -> every row's choice
+        stats["experts"] = stats["experts"][:, 0]
     return x[:, last_row], cache, stats
 
 
@@ -841,8 +877,16 @@ def paged_verify_step(params, tokens, cfg, cache, tables, limit):
     decode the pallas kernel exists for) — under a mesh XLA
     auto-partitions it like the chunked-prefill program.
 
+    A model with per-slot rows has no verify: a rejected draft's rows sit
+    beyond ``len`` and are rewritten, but what it left per slot would have
+    to be rewound.
+
     Returns (logits [B, S, V] f32, cache)."""
     ops = paged_ops(cfg)
+    if ops.slot_rows:
+        raise ValueError(f"{type(cfg).__name__} keeps per-slot rows "
+                         f"{sorted(ops.slot_rows)}: the verify step cannot "
+                         "rewind them past a rejected draft")
     b, s = tokens.shape
     bs = cache[next(iter(ops.pool_rows))].shape[2]
     start = cache["len"]                                   # [B]
@@ -857,8 +901,8 @@ def paged_verify_step(params, tokens, cfg, cache, tables, limit):
     off = pos % bs
     x = ops.embed(params, tokens)                          # [B, S, D]
 
-    def layer_fn(lp, x, pools, layer):
-        q, rows = ops.qkv(lp, x, pos)
+    def layer_fn(lp, x, extra, pools, layer):
+        q, rows, _ = ops.qkv(lp, x, pos, {})
         # duplicate blk entries (several rows of one slot's block in a
         # single verify) are safe in a quantized pool: quant_scatter_rows
         # folds their amaxes via scatter-max before any content write
@@ -867,9 +911,9 @@ def paged_verify_step(params, tokens, cfg, cache, tables, limit):
         # rows <= start[b]+s — this step's own earlier rows included,
         # every stale/rejected row beyond them masked
         o = ops.chunk_attention(lp, q, pools, layer, tables, start)
-        x, stats = ops.out(lp, x, o, valid)
-        return x, pools, stats
+        x, extra, stats = ops.out(lp, x, o, valid, extra)
+        return x, extra, pools, stats
 
-    x, pools, _ = _scan_layers(ops, params, x, cache, layer_fn)
+    x, _, pools, _ = _scan_layers(ops, params, x, cache, layer_fn)
     logits = ops.head(params, x.reshape(b * s, x.shape[-1])).reshape(b, s, -1)
     return logits, {**pools, "len": cache["len"]}

@@ -192,7 +192,9 @@ def test_idle_step_records_nothing_and_stall_is_counted(tiny):
     eng = LLMEngine(params, cfg, max_batch=2, max_seq=32,
                     prefill_buckets=(16,), kv_block_size=16,
                     kv_num_blocks=3, obs=col)
-    assert eng.step() == [] and col.snapshot() == []
+    # the engine's construction left its one span; an idle step none
+    assert eng.step() == []
+    assert [s["name"] for s in col.snapshot()] == ["engine.build"]
     a = eng.add_request([1, 2, 3], SamplingParams(max_tokens=20))
     b = eng.add_request([4, 5, 6], SamplingParams(max_tokens=20))
     eng.step()

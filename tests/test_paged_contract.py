@@ -12,9 +12,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import Literal
 
 import kubeflow_tpu
-from kubeflow_tpu.models import llama, mla_moe
+from kubeflow_tpu.models import cca_moe, llama, mla_moe
 from kubeflow_tpu.models.paged import PagedOps
 from kubeflow_tpu.serving import paged_kv
 from kubeflow_tpu.serving.quant import _LAYER_WEIGHTS, quantize_weights
@@ -26,8 +27,9 @@ PACKAGE = pathlib.Path(kubeflow_tpu.__file__).parent
 
 @pytest.mark.parametrize(
     "model, tiny",
-    [(llama, llama.llama_tiny), (mla_moe, mla_moe.mla_moe_tiny)],
-    ids=["llama_tiny", "mla_moe_tiny"])
+    [(llama, llama.llama_tiny), (mla_moe, mla_moe.mla_moe_tiny),
+     (cca_moe, cca_moe.cca_moe_tiny)],
+    ids=["llama_tiny", "mla_moe_tiny", "cca_moe_tiny"])
 def test_paged_ops_is_the_configs_own(model, tiny):
     cfg = tiny(dtype=jnp.float32)
     ops = paged_kv.paged_ops(cfg)
@@ -41,9 +43,14 @@ def test_paged_ops_is_the_configs_own(model, tiny):
     b, max_seq, bs, nb = 2, 32, 8, 9
     cache = jax.eval_shape(
         lambda: paged_kv.init_paged_cache(cfg, b, max_seq, bs, nb))
-    assert set(cache) == set(ops.pool_rows) | {"len"}
+    assert set(cache) == set(ops.pool_rows) | set(ops.slot_rows) | {"len"}
     for name, row in ops.pool_rows.items():
         assert cache[name].shape == (ops.n_layers, nb, bs, *row)
+    for name, row in ops.slot_rows.items():
+        assert cache[name].shape == (ops.n_layers, b, *row)
+    # per-slot rows and a layer-to-layer carry are the CCA model's alone
+    assert bool(ops.slot_rows) == bool(ops.layer_carry) \
+        == (model is cca_moe)
 
     if ops.bucket_prefill is None:
         return
@@ -58,6 +65,58 @@ def test_paged_ops_is_the_configs_own(model, tiny):
     assert set(rows) == set(ops.pool_rows)
     for name, row in ops.pool_rows.items():
         assert rows[name].shape == (ops.n_layers, b, 2 * bs, *row)
+
+
+@pytest.mark.parametrize(
+    "model, tiny, program",
+    [(llama, llama.llama_tiny, "decode"), (llama, llama.llama_tiny, "chunk"),
+     (llama, llama.llama_tiny, "verify"),
+     (mla_moe, mla_moe.mla_moe_tiny, "decode"),
+     (mla_moe, mla_moe.mla_moe_tiny, "chunk")],      # it has no verify step
+    ids=lambda v: v if isinstance(v, str) else getattr(v, "__name__", ""))
+def test_models_without_slot_state_pay_nothing_for_the_seam(model, tiny,
+                                                            program):
+    """The seam grew per-slot rows and a layer-to-layer carry (PR 34). For
+    a model that has neither, the cache holds its pools and ``len`` and
+    nothing else, and each program's jaxpr takes exactly the weights, the
+    cache and its own operands: no state array, no carry, and the decode
+    step's ``active`` mask is not read."""
+    cfg = tiny(dtype=jnp.float32)
+    ops = paged_kv.paged_ops(cfg)
+    b, bs, nbp = 2, 8, 4
+    params = jax.eval_shape(
+        lambda: model.init_params(jax.random.key(0), cfg))
+    cache = jax.eval_shape(
+        lambda: paged_kv.init_paged_cache(cfg, b, nbp * bs, bs, b * nbp + 1))
+    assert set(cache) == set(ops.pool_rows) | {"len"}
+    tables = jax.ShapeDtypeStruct((b, nbp), jnp.int32)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    if program == "decode":
+        fn = lambda p, tok, c, t, active: paged_kv.paged_decode_step(
+            p, tok, cfg, c, t, active=active)
+        args = (params, jax.ShapeDtypeStruct((b,), jnp.int32), cache, tables,
+                jax.ShapeDtypeStruct((b,), bool))
+    elif program == "chunk":
+        fn = lambda p, toks, c, t, slot, off, n: paged_kv.paged_prefill_chunk(
+            p, toks, cfg, c, t, slot, off, n)
+        args = (params, jax.ShapeDtypeStruct((1, 16), jnp.int32), cache,
+                tables, scalar, scalar, scalar)
+    else:
+        fn = lambda p, toks, c, t, limit: paged_kv.paged_verify_step(
+            p, toks, cfg, c, t, limit)
+        args = (params, jax.ShapeDtypeStruct((b, 4), jnp.int32), cache,
+                tables, jax.ShapeDtypeStruct((b,), jnp.int32))
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    assert len(jaxpr.invars) == len(jax.tree.leaves(args))
+    if program == "decode":             # the mask: an input nothing reads
+        used = {v for eqn in jaxpr.eqns for v in eqn.invars
+                if not isinstance(v, Literal)}
+        assert jaxpr.invars[-1] not in used
+    # the layer loops carry x and the pools: as many values as the cache
+    # has pools, plus one
+    loops = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    assert loops and all(
+        e.params["num_carry"] == 1 + len(ops.pool_rows) for e in loops)
 
 
 def _imports(path):

@@ -25,7 +25,7 @@ from kubeflow_tpu.ops.pallas_paged_attention import (
     paged_decode_attention, paged_decode_attention_sharded,
     paged_latent_decode_attention,
 )
-from kubeflow_tpu.models import llama, mla_moe
+from kubeflow_tpu.models import cca_moe, llama, mla_moe
 from kubeflow_tpu.parallel.aot import topology_devices
 from kubeflow_tpu.serving import paged_kv
 
@@ -344,6 +344,96 @@ def test_latent_prefill_chunk_is_one_kernel_a_layer_stack(v5e, monkeypatch):
     assert mem.alias_size_in_bytes >= cache["kv"].size * 2
     # the parent's program (the walk in plain XLA) at these shapes
     assert mem.temp_size_in_bytes <= PARENT_PREFILL_TEMP_BYTES
+
+
+# zaya1-8b-l20.reasoning-offline (benchmarks/traffic): 64 slots of 72
+# blocks of 64 rows, 3,072 blocks, chunks of 512, the published widths
+CCA = dict(b=64, nb=3072, nbp=72, chunk=512)
+
+
+def _cca_on_chip(v5e, n_layers):
+    cfg = cca_moe.CcaMoeConfig(n_layers=n_layers, max_seq=CCA["nbp"] * BS)
+    one = NamedSharding(Mesh(v5e[:1], ("x",)), P())
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one), tree)
+
+    params = on_chip(jax.eval_shape(lambda: cca_moe.init_params(
+        jax.random.key(0), cfg, dtype=jnp.bfloat16)))
+    cache = on_chip(jax.eval_shape(lambda: paged_kv.init_paged_cache(
+        cfg, CCA["b"], CCA["nbp"] * BS, BS, CCA["nb"])))
+    return cfg, params, cache, lambda shape, dt=jnp.int32: \
+        jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+
+def test_cca_decode_chunk_updates_pool_and_state_in_place(v5e, monkeypatch):
+    """``paged_decode_step`` of the CCA / top-1-expert model at the cell's
+    engine (2 layers of the published widths) under a 4-step scan with the
+    cache donated and the engine's dispatch mask: the GQA decode kernel as
+    it stands, three grouped products whose weight tiles fit the scoped
+    VMEM (experts as wide as the model: 2,048 x 2,048), K, V and the
+    per-slot state updated in place, no layer's experts or projections
+    copied out of their stack."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c = CCA
+    cfg, params, cache, sds = _cca_on_chip(v5e, 2)
+    assert set(cache) == {"k", "v", "cca", "len"}
+    assert cache["cca"].shape == (2, c["b"], 2688)
+    held = sum(cache[key].size * 2 for key in ("k", "v", "cca"))
+
+    def chunk(params, token, cache, tables, active):
+        def one_step(carry, _):
+            token, cache = carry
+            logits, cache, stats = paged_kv.paged_decode_step(
+                params, token, cfg, cache, tables, kernel="pallas",
+                active=active)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), cache), stats
+        return jax.lax.scan(one_step, (token, cache), None, length=4)
+
+    compiled = jax.jit(chunk, donate_argnums=(2,)).lower(
+        params, sds((c["b"],)), cache, sds((c["b"], c["nbp"])),
+        sds((c["b"],), bool)).compile()
+    hlo = compiled.as_text()
+    # the readers find the kernel and the grouped products by these names
+    assert len(re.findall(r"%closed_call\.\d+ = \S+ custom-call\(", hlo)) == 1
+    assert len(re.findall(r"%gmm(\.\d+)? = \S+ custom-call\(", hlo)) == 3
+    assert paged_kv.pool_shaped_ops(hlo, [cache["k"].shape]) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held            # updated in place
+    # neither a pool, nor a layer's experts (134 MB a matrix), nor a stack
+    # of projections among the temporaries
+    assert mem.temp_size_in_bytes < 2 ** 25
+    assert not [line for line in hlo.splitlines() if re.search(
+        r" = bf16\[(1,)?16,2048,2048\]\S* (fusion|copy|dynamic-slice)\(",
+        line)], "a layer's experts were sliced out"
+
+
+def test_cca_prefill_chunk_at_the_cells_shapes(v5e, monkeypatch):
+    """``paged_prefill_chunk`` of the same model with the cache donated, as
+    the engine jits it: a chunk of 512 over 72 table entries compiles for
+    the chip, K, V and the slot's state land in place, and the gathered
+    views and score tiles stay far under a pool's size."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c = CCA
+    cfg, params, cache, sds = _cca_on_chip(v5e, 2)
+
+    def prefill(params, tokens, cache, tables, slot, offset, length, share):
+        return paged_kv.paged_prefill_chunk(params, tokens, cfg, cache,
+                                            tables, slot, offset, length,
+                                            share)
+
+    compiled = jax.jit(prefill, donate_argnums=(2,)).lower(
+        params, sds((1, c["chunk"])), cache, sds((c["b"], c["nbp"])),
+        sds(()), sds(()), sds(()), sds(())).compile()
+    hlo = compiled.as_text()
+    assert len(re.findall(r"%gmm(\.\d+)? = \S+ custom-call\(", hlo)) == 3
+    assert paged_kv.pool_shaped_ops(
+        hlo, [cache["k"].shape, cache["cca"].shape]) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        cache[key].size * 2 for key in ("k", "v", "cca"))
+    assert mem.temp_size_in_bytes < 2 ** 28
 
 
 PARENT_PREFILL_TEMP_BYTES = 289_382_400      # 94,674,944 with the kernel (PR 32)
