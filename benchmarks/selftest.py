@@ -7,8 +7,10 @@ Not collected by ``tests/``. Checks, in this order:
    (rule 2) and the percentile numpy would give;
 2. every run replays the same work: each pass of a traffic file's frozen list
    has the same prompt tokens, output tokens and time to fall due in, the
-   window holds whole passes of an open loop's list, and the seed changes
-   the token ids only (rule 1);
+   window holds whole passes of an open loop's list, those passes leave ten
+   requests or more beyond the 90th percentile, ``rate_why`` opens with
+   the ``rate_rps`` the file holds, and the seed changes the token ids only
+   (rule 1);
 3. the trace reduction gives hand-computed busy time, program and kernel time,
    exposed collective time and idle gaps on a synthetic event list, and the
    recorded values on the small recorded trace in ``fixtures/``;
@@ -24,6 +26,7 @@ Not collected by ``tests/``. Checks, in this order:
 from __future__ import annotations
 
 import json
+import math
 import os
 import re
 import subprocess
@@ -50,7 +53,9 @@ def check_window():
 
 
 def check_traffic(bench):
-    for name in sorted({w["traffic"] for w in bench["workloads"]}):
+    # every file there, a cell's or not yet
+    for name in sorted(f[:-5] for f in os.listdir(os.path.join(HERE, "traffic"))
+                       if f.endswith(".json")):
         tr = traffic.load(name)
         if "pairs" not in tr:
             continue
@@ -65,8 +70,14 @@ def check_traffic(bench):
             period = traffic.period_s(tr)
             assert abs(rows[3 * n][1] - rows[0][1] - 3 * period) < 1e-6
             # the window holds whole passes: the same requests in every run
-            assert abs(bench["run_seconds"] / period
-                       - round(bench["run_seconds"] / period)) < 1e-3, period
+            whole = round(bench["run_seconds"] / period)
+            assert abs(bench["run_seconds"] / period - whole) < 1e-3, period
+            # a p90 with neighbours: ten judged requests or more beyond it
+            judged = whole * n
+            assert judged - math.ceil(0.9 * judged) >= 10, (name, judged)
+            # a rate edited without its sentence is a rate nobody re-derived:
+            # the sentence opens with the rate, "6.0 rps = 0.625 of ..."
+            assert tr["rate_why"].startswith(f"{tr['rate_rps']} rps = "), name
         # the seed makes the ids and nothing else; the same seed, the same ids
         assert traffic.token_ids(7, 3, 16, 1000) == \
             traffic.token_ids(7, 3, 16, 1000)
