@@ -35,16 +35,28 @@ Design (decode only — one query token per slot):
   block, 1,180 of 1,280 steps of a call were dead on the chat cell, a
   sixth of a live step's time each: PERF.md section 6, PR 30.)
 - ``G`` comes from the shapes alone (``_chunk_blocks``: a chunk's K and
-  V as f32 matrices of 2 MiB each, 8 blocks of 64 x 8 x 128): the chip
-  pays a fixed cost a softmax update, and two products over one block are
-  too small to hide it.
-- The pool is read in the layout it is stored in: a block arrives as
-  ``(block, KV_H, D)`` and is used as the ``[block * KV_H, D]`` matrix it
-  is in memory (merging leading dims of an f32 tile is free). A merged
-  ``[..., KV_H * D]`` view, whose per-head tiles would be lane slices,
-  is NOT a free reshape of the pool on the chip — both shapes are tiled
-  over their last two dims, so it is a relayout of everything reshaped
-  (PR 27: 2 of the 8 whole-pool operations a step).
+  V as f32 matrices of at most 2 MiB each and at most 1,024 tokens: 8
+  blocks of 64 x 8 x 128, 16 of 64 x 2 x 128): the chip pays a fixed cost
+  a softmax update, and two products over one block are too small to hide
+  it; the products also run over a chunk's dead rows, so the longest
+  chunk that fits is not the fastest.
+- A block is read as the ``[block * KV_H, D]`` matrix of (token, kv head)
+  rows it is in HBM, and lands in VMEM dense. VMEM tiles a buffer's last
+  two dims into 8 sublane rows of 128 lanes, so which VIEW of the pool the
+  copies address depends on ``KV_H``. Whole tiles of kv heads (``KV_H`` a
+  multiple of 8: the dense cells) arrive as the stored ``(block, KV_H,
+  D)`` and merging the leading dims of full tiles is free. Any other
+  count (2 kv heads: the CCA model, or 8 split over ``tensor`` = 4) would
+  leave every token a tile that is three quarters padding, and ``reshape(rows, D)`` a
+  compaction of the whole chunk on the vector unit between the copy and
+  the products (29.7% of the roofline where 8 heads read 79.6%, PERF.md
+  section 6, PR 37); there the wrapper hands the kernel the pool as
+  ``[L, NB, block * KV_H, D]``, which is the same bytes in HBM (a bitcast
+  in the compiled program), and a copy brings a block in as the matrix
+  the products take. A merged ``[..., KV_H * D]`` view, whose per-head
+  tiles would be lane slices, is NOT a free reshape of the pool on the
+  chip: it is a relayout of everything reshaped (PR 27: 2 of the 8
+  whole-pool operations a step).
 - GQA in-kernel, without per-head tiles: ONE ``[H, D] x [D, G*block*KV_H]``
   product scores every query head against every (token, kv head) row of
   the chunk and a mask keeps each head's own kv head (column ``c`` is kv
@@ -278,12 +290,23 @@ def _decode_kernel(layer_ref, kvlen_ref, tables_ref, q_ref, k_hbm, v_hbm,
 
 
 def _chunk_blocks(block_size, kvh, head_dim, n_tables):
-    """Pool blocks a softmax update: the chunk's K and V as f32 matrices
-    (the products' operands) are held to 2 MiB each, beside which the two
-    double-buffered copies in the pool's dtype and the [H, rows] scores
-    fit the default scoped VMEM. A tile pads the kv-head dim to 8."""
-    block_f32 = block_size * -(-kvh // 8) * 8 * head_dim * 4
-    return max(1, min(n_tables, (2 * 2 ** 20) // block_f32))
+    """Pool blocks a softmax update, by two limits. The chunk's K and V as
+    f32 matrices (the products' operands) are held to 2 MiB each, beside
+    which the two double-buffered copies in the pool's dtype and the
+    [H, rows] scores fit the default scoped VMEM; a block counts as it lies
+    in VMEM, and it lies there dense, ``block * KV_H`` rows whatever
+    ``KV_H`` is, because a kv-head dim that would pad a tile is read merged
+    (``paged_decode_attention``). And a chunk is at most 1,024 tokens: the
+    products run over a whole chunk whatever part of it is live, half a
+    chunk of dead rows a slot on average, against a fixed cost a chunk of
+    about 12 blocks' worth at 2 kv heads. At 64 x 2 x 128, slots of 4-70
+    live blocks: 8 / 16 / 32 blocks a chunk read 68.3 / 70.7 / 64.4% of the
+    roofline in the cell, the kernel alone 67.3 / 69.6 / 62.6 and 71.3 at
+    12, 70.9 at 24, 54.7 at 48 (PERF.md section 6, PR 37). 8 kv heads
+    reach the first limit at 8 blocks, 512 tokens."""
+    block_f32 = block_size * kvh * head_dim * 4
+    return max(1, min(n_tables, (2 * 2 ** 20) // block_f32,
+                      1024 // block_size))
 
 
 def paged_decode_attention(q, k_pool, v_pool, layer, tables, kv_len, *,
@@ -324,11 +347,18 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, kv_len, *,
     kv_len = kv_len.astype(jnp.int32)
     tables = tables.astype(jnp.int32)
 
-    if kvh * k_pool.dtype.itemsize % 4:
-        # a kv-head dim that does not fill a 32-bit sublane (int8 at
-        # tensor = 4: 2 heads a shard) is padded in memory, and a copy
-        # cannot name the padding: such a pool is read with a block's
-        # (token, kv head) rows merged (ROADMAP A3d: a relayout of it)
+    if kvh % 8:
+        # a kv-head dim that does not fill whole sublane tiles: the block's
+        # (token, kv head) rows merged, so that it lands in VMEM as the
+        # dense matrix the products take (module docstring). What the v5e
+        # compile makes of the reshape (tests/test_pallas_tpu_lowering.py):
+        # for bf16 at 2 heads a bitcast, the stored bytes of a T(2,128)(2,1)
+        # pool being the merged matrix already; for int8 at 2 heads (one
+        # chip, or 8 over tensor = 4) XLA keeps the pool token-minor
+        # ({4,1,3,2,0:T(8,128)(4,1)}) and two copies of each pool a call
+        # bring it to the row-major merged form (a pool STORED merged would
+        # need neither), where unmerged a copy could not name the padding
+        # of a kv-head dim under 32 bits at all
         k_pool, v_pool = (p.reshape(n_layers, num_blocks, block_size * kvh, d)
                           for p in (k_pool, v_pool))
     whole = pl.BlockSpec(memory_space=pl.ANY)

@@ -9,6 +9,8 @@ Tolerances follow tests/test_pallas_attention.py: 2e-5 for f32 inputs,
 2e-2 for bf16.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -138,93 +140,124 @@ def _dequant(pool, scale):
     return pool.astype(jnp.float32) * scale[:, :, None, :, None]
 
 
+# blocks of the serving cells' shape (64 tokens x KV_H x 128): 8 kv heads
+# under 16 query heads (the dense cells) and 2 under 8 (the CCA model, whose
+# pool the kernel reads with a block's (token, kv head) rows merged), under a
+# table of two chunks and four blocks of whatever chunk the wrapper derives
+# for the shape (8 blocks at 8 heads, 16 at 2: the table grows with it)
+CELL_BS, CELL_D = 64, 128
+CELL_HEADS = {8: 16, 2: 8}
+
+
+def _cell_table(kvh):
+    """(G, table entries): the blocks a chunk of the kernel's walk at the
+    cells' block of ``kvh`` heads, and a table that holds two and more."""
+    g = _chunk_blocks(CELL_BS, kvh, CELL_D, 10 ** 6)
+    nbp = 2 * g + 4
+    assert 1 < g == _chunk_blocks(CELL_BS, kvh, CELL_D, nbp)
+    return g, nbp
+
+
+def _cell_block_case(pool_dtype, kvh, key, kv_len, layers=2):
+    """A batch of the lengths ``kv_len(G, table entries)`` over a pool of
+    the cells' blocks; for an int8 pool also the scales to hand the kernel
+    and the dequantized pools the oracle reads."""
+    g, nbp = _cell_table(kvh)
+    kv_len = kv_len(g, nbp)
+    dtype = jnp.float32 if pool_dtype == "f32" else jnp.bfloat16
+    q, kp, vp, tables, kvl = _pool_case(
+        key, b=len(kv_len), h=CELL_HEADS[kvh], kvh=kvh, d=CELL_D, bs=CELL_BS,
+        nbp=nbp, kv_len=kv_len, dtype=dtype, layers=layers,
+        num_blocks=sum(-(-n // CELL_BS) for n in kv_len) + 1)
+    return q, kp, vp, tables, kvl
+
+
+def _maybe_int8(pool_dtype, kp, vp):
+    """(pools for the kernel, its scale arguments, pools for the oracle)."""
+    if pool_dtype != "int8":
+        return kp, vp, {}, kp, vp
+    (kq, ks), (vq, vs) = _quantize_pool(kp), _quantize_pool(vp)
+    return (kq, vq, dict(k_scale=ks, v_scale=vs),
+            _dequant(kq, ks), _dequant(vq, vs))
+
+
+# one trace and compile per shape: the kernel in interpret mode takes ten
+# seconds to build at a chunk of 16 blocks and no time to run, and the layer
+# is an operand
+_kernel = jax.jit(functools.partial(paged_decode_attention, interpret=True))
+
+
+def _live_rows(x, kvl):
+    return np.asarray(x.astype(jnp.float32))[np.asarray(kvl) > 0]
+
+
 @pytest.mark.parametrize("layer", [0, 1, 2])
 @pytest.mark.parametrize("pool_dtype", ["bf16", "int8"])
-def test_layer_addressed_kernel_reads_its_own_layer(pool_dtype, layer):
+@pytest.mark.parametrize("block", ["8x2x32", "64x8x128", "64x2x128"])
+def test_layer_addressed_kernel_reads_its_own_layer(block, pool_dtype, layer):
     """The kernel is given the WHOLE pool and a layer index. Three layers
     with different contents, ragged lengths, idle slots, lengths on and
-    across a block boundary: each layer's output is that layer's oracle,
-    and no other's."""
-    kv_len = [0, 1, 7, 8, 9, 24, 0, 13]
-    q, kp, vp, tables, kvl = _pool_case(
-        jax.random.key(20), b=8, h=4, kvh=2, d=32, bs=8, nbp=3,
-        kv_len=kv_len, dtype=jnp.bfloat16, layers=3)
-    scales = {}
-    if pool_dtype == "int8":
-        (kp, ks), (vp, vs) = _quantize_pool(kp), _quantize_pool(vp)
-        scales = dict(k_scale=ks, v_scale=vs)
-        kd = _dequant(kp, ks).astype(q.dtype)
-        vd = _dequant(vp, vs).astype(q.dtype)
+    across a block boundary (at the cells' blocks across a chunk's too):
+    each layer's output is that layer's oracle, and no other's."""
+    if block == "8x2x32":
+        kv_len = [0, 1, 7, 8, 9, 24, 0, 13]
+        q, kp, vp, tables, kvl = _pool_case(
+            jax.random.key(20), b=8, h=4, kvh=2, d=32, bs=8, nbp=3,
+            kv_len=kv_len, dtype=jnp.bfloat16, layers=3)
     else:
-        kd, vd = kp, vp
-    out = paged_decode_attention(q, kp, vp, jnp.int32(layer), tables, kvl,
-                                 interpret=True, **scales)
-    live = np.asarray(kv_len) > 0
-    got = np.asarray(out.astype(jnp.float32))[live]
+        q, kp, vp, tables, kvl = _cell_block_case(
+            "bf16", int(block.split("x")[1]), jax.random.key(21),
+            lambda g, nbp: [0, 1, CELL_BS, CELL_BS + 1, 0,
+                            g * CELL_BS + 13], layers=3)
+    kp, vp, scales, kd, vd = _maybe_int8(pool_dtype, kp, vp)
+    out = _kernel(q, kp, vp, jnp.int32(layer), tables, kvl, **scales)
+    got = _live_rows(out, kvl)
     assert np.isfinite(np.asarray(out.astype(jnp.float32))).all()
     for other in range(3):
-        ref = np.asarray(_gather_ref(q, kd, vd, other, tables,
-                                     kvl).astype(jnp.float32))[live]
+        ref = _live_rows(_gather_ref(q, kd.astype(q.dtype),
+                                     vd.astype(q.dtype), other, tables, kvl),
+                         kvl)
         if other == layer:
             np.testing.assert_allclose(got, ref, rtol=2e-2, atol=2e-2)
         else:
             assert np.abs(got - ref).max() > 0.1
 
 
-# blocks of the serving cells' shape (64 tokens x 8 kv heads x 128) under a
-# table of 20, so that the chunk the wrapper derives is shorter than the table
-CELL_BS, CELL_KVH, CELL_D, CELL_NBP = 64, 8, 128, 20
-
-
-def _cell_block_case(pool_dtype, key, kv_len):
-    """A batch of the lengths ``kv_len(G)`` over a 2-layer pool of the
-    cells' blocks, G the blocks a chunk of the kernel's walk."""
-    bs, kvh, d, nbp = CELL_BS, CELL_KVH, CELL_D, CELL_NBP
-    g = _chunk_blocks(bs, kvh, d, nbp)
-    assert 1 < g and 2 * g <= nbp, "the table must hold two chunks and more"
-    kv_len = kv_len(g)
-    dtype = jnp.float32 if pool_dtype == "f32" else jnp.bfloat16
-    q, kp, vp, tables, kvl = _pool_case(
-        key, b=len(kv_len), h=16, kvh=kvh, d=d, bs=bs, nbp=nbp,
-        kv_len=kv_len, dtype=dtype, layers=2,
-        num_blocks=sum(-(-n // bs) for n in kv_len) + 1)
-    return q, kp, vp, tables, kvl
-
-
+@pytest.mark.parametrize("kvh", [8, 2])
 @pytest.mark.parametrize("pool_dtype", ["f32", "bf16", "int8"])
-def test_lengths_on_every_boundary_of_the_walk(pool_dtype):
+def test_lengths_on_every_boundary_of_the_walk(pool_dtype, kvh):
     """A slot is walked in chunks of G pool blocks, G from the shapes:
     idle, one token, a block, a block and one, a chunk, a chunk and one,
     one short of two chunks, the table's end — mixed in one batch, read
-    from layer 1 of the pool."""
+    from layer 1 of the pool. Every kv head holds rows of its own: the
+    oracle on a pool with the kv heads in reverse order (a query head
+    reading another group's rows) is far from what the kernel gives."""
     bs = CELL_BS
     q, kp, vp, tables, kvl = _cell_block_case(
-        pool_dtype, jax.random.key(30),
-        lambda g: [0, 1, bs, bs + 1, g * bs, g * bs + 1, 2 * g * bs - 1,
-                   CELL_NBP * bs])
-    scales = {}
-    kd, vd = kp, vp
-    if pool_dtype == "int8":
-        (kp, ks), (vp, vs) = _quantize_pool(kp), _quantize_pool(vp)
-        scales = dict(k_scale=ks, v_scale=vs)
-        kd, vd = _dequant(kp, ks), _dequant(vp, vs)
-    out = paged_decode_attention(q, kp, vp, jnp.int32(1), tables, kvl,
-                                 interpret=True, **scales)
+        pool_dtype, kvh, jax.random.key(30),
+        lambda g, nbp: [0, 1, bs, bs + 1, g * bs, g * bs + 1,
+                        2 * g * bs - 1, nbp * bs])
+    kp, vp, scales, kd, vd = _maybe_int8(pool_dtype, kp, vp)
+    out = _kernel(q, kp, vp, jnp.int32(1), tables, kvl, **scales)
     assert out.dtype == q.dtype and bool(jnp.isfinite(out).all())
-    live = np.asarray(kvl) > 0
     tol = 2e-5 if pool_dtype == "f32" else 2e-2
-    got = np.asarray(out.astype(jnp.float32))[live]
+    got = _live_rows(out, kvl)
+    qf = q.astype(jnp.float32)
     for layer in (0, 1):
-        ref = np.asarray(_gather_ref(q.astype(jnp.float32), kd, vd, layer,
-                                     tables, kvl).astype(jnp.float32))[live]
+        ref = _live_rows(_gather_ref(qf, kd, vd, layer, tables, kvl), kvl)
         if layer == 1:
             np.testing.assert_allclose(got, ref, rtol=tol, atol=tol)
         else:
             assert np.abs(got - ref).max() > 0.05
+    swapped = _live_rows(_gather_ref(qf, kd[..., ::-1, :], vd[..., ::-1, :],
+                                     1, tables, kvl), kvl)
+    assert np.abs(got - swapped).max() > 0.05
 
 
+@pytest.mark.parametrize("kvh", [8, 2])
 @pytest.mark.parametrize("pool_dtype", ["f32", "int8"])
-def test_dead_blocks_are_never_read_and_stale_rows_never_count(pool_dtype):
+def test_dead_blocks_are_never_read_and_stale_rows_never_count(pool_dtype,
+                                                               kvh):
     """Every pool block that no LIVE table entry names is NaN (block 0,
     which the dead entries name, among them; for an int8 pool its scales
     are), and short slots follow long ones, so the buffer they are copied
@@ -232,8 +265,8 @@ def test_dead_blocks_are_never_read_and_stale_rows_never_count(pool_dtype):
     is finite and the oracle's on the clean pool."""
     bs = CELL_BS
     q, kp, vp, tables, kvl = _cell_block_case(
-        pool_dtype, jax.random.key(31),
-        lambda g: [2 * g * bs, 3, 0, CELL_NBP * bs, bs + 1, (g + 1) * bs, 1])
+        pool_dtype, kvh, jax.random.key(31),
+        lambda g, nbp: [2 * g * bs, 3, 0, nbp * bs, bs + 1, (g + 1) * bs, 1])
     named = np.zeros(kp.shape[1], bool)
     for s, n in enumerate(np.asarray(kvl)):
         named[np.asarray(tables)[s, :-(-int(n) // bs)]] = True
@@ -242,27 +275,20 @@ def test_dead_blocks_are_never_read_and_stale_rows_never_count(pool_dtype):
     pad = [(0, 0), (0, 3)] + [(0, 0)] * 3
     kp, vp = jnp.pad(kp, pad), jnp.pad(vp, pad)
     dead = jnp.asarray(np.concatenate([~named, [True] * 3]))
-    scales = {}
+    kp, vp, scales, kd, vd = _maybe_int8(pool_dtype, kp, vp)
+    ref = _gather_ref(q, kd, vd, 1, tables, kvl)
     if pool_dtype == "int8":
-        (kq, ks), (vq, vs) = _quantize_pool(kp), _quantize_pool(vp)
-        ref = _gather_ref(q, _dequant(kq, ks), _dequant(vq, vs), 1, tables,
-                          kvl)
         poison = dead[None, :, None]
-        scales = dict(k_scale=jnp.where(poison, jnp.nan, ks),
-                      v_scale=jnp.where(poison, jnp.nan, vs))
-        kp, vp = kq, vq
+        scales = {name: jnp.where(poison, jnp.nan, sc)
+                  for name, sc in scales.items()}
     else:
-        ref = _gather_ref(q, kp, vp, 1, tables, kvl)
         poison = dead[None, :, None, None, None]
         kp, vp = jnp.where(poison, jnp.nan, kp), jnp.where(poison, jnp.nan, vp)
-    out = paged_decode_attention(q, kp, vp, jnp.int32(1), tables, kvl,
-                                 interpret=True, **scales)
+    out = _kernel(q, kp, vp, jnp.int32(1), tables, kvl, **scales)
     assert bool(jnp.isfinite(out).all())
-    live = np.asarray(kvl) > 0
     tol = 2e-5 if pool_dtype == "f32" else 2e-2
-    np.testing.assert_allclose(
-        np.asarray(out.astype(jnp.float32))[live],
-        np.asarray(ref.astype(jnp.float32))[live], rtol=tol, atol=tol)
+    np.testing.assert_allclose(_live_rows(out, kvl), _live_rows(ref, kvl),
+                               rtol=tol, atol=tol)
 
 
 def test_rejects_bad_shapes():

@@ -39,7 +39,13 @@ CELLS = {"chat": dict(h=32, b=32, nb=545, nbp=40),
          # long tables (8 x 8,192 tokens; 8 x 26,624, the longctx cell's
          # max_seq): the kernel's chunk is derived from the shapes
          "long128": dict(h=32, b=8, nb=8 * 128 + 1, nbp=128),
-         "long416": dict(h=32, b=8, nb=8 * 416 + 1, nbp=416)}
+         "long416": dict(h=32, b=8, nb=8 * 416 + 1, nbp=416),
+         # zaya1-8b-l20.reasoning-offline: 8 query heads over 2 KV heads
+         "reasoning": dict(h=8, kvh=2, b=64, nb=3072, nbp=72)}
+# the CCA model refuses a quantized pool, and 2 KV heads a tensor mesh of 4
+CELL_VARIANTS = [(cell, variant) for cell in sorted(CELLS)
+                 for variant in ("bf16", "int8", "tensor4")
+                 if cell != "reasoning" or variant == "bf16"]
 
 
 @pytest.fixture(scope="module")
@@ -127,17 +133,20 @@ def test_paged_decode_sharded_over_four_chips(v5e, quantized):
     assert "all-reduce" not in hlo and "all-gather" not in hlo
 
 
-@pytest.mark.parametrize("variant", ["bf16", "int8", "tensor4"])
-@pytest.mark.parametrize("cell", sorted(CELLS))
+@pytest.mark.parametrize("cell,variant", CELL_VARIANTS)
 def test_paged_decode_at_the_cells_shapes(v5e, cell, variant):
-    """The layer-addressed kernel reads (bs, KV, D) blocks of the pool as
-    it is stored, at both serving cells' heads, batch, tables and pool,
-    and where a slot's table is hundreds of blocks long."""
+    """The layer-addressed kernel reads blocks of the pool as it is stored,
+    at the serving cells' heads, batch, tables and pool, and where a slot's
+    table is hundreds of blocks long. WHICH view of the pool it is handed
+    is decided by the shape, once a compiled program, and read here from
+    the program's text: (bs, KV, D) blocks where 8 KV heads fill a sublane
+    tile, (bs * KV, D) where 2 do not, and that view a bitcast of the
+    stored pool."""
     quantized = variant == "int8"
-    shapes = _paged_shapes(jnp.int8 if quantized else jnp.bfloat16,
-                           **CELLS[cell])
+    c = CELLS[cell]
+    shapes = _paged_shapes(jnp.int8 if quantized else jnp.bfloat16, **c)
     if quantized:
-        shapes += _scales(nb=CELLS[cell]["nb"])
+        shapes += _scales(nb=c["nb"])
     if variant == "tensor4":
         mesh = Mesh(v5e, ("tensor",))
         fn = _positional(paged_decode_attention_sharded, mesh=mesh)
@@ -149,6 +158,18 @@ def test_paged_decode_at_the_cells_shapes(v5e, cell, variant):
     assert hlo.count("tpu_custom_call") == 1
     # the kernel takes the pool itself: no slice, reshape or copy of it
     assert not paged_kv.pool_shaped_ops(hlo, [shapes[1][0]])
+    if variant != "bf16":
+        return
+    kernel, = [line for line in hlo.splitlines()
+               if " custom-call(" in line and "tpu_custom_call" in line]
+    operands = kernel.split("operand_layout_constraints=")[1]
+    kvh = c.get("kvh", KVH)
+    view = f"{BS},{kvh},{D}" if kvh == KVH else f"{BS * kvh},{D}"
+    pool = f"bf16[{L},{c['nb']},{view}]"
+    assert operands.count(pool + "{") == 2, operands
+    if kvh != KVH:
+        made = re.findall(r" = " + re.escape(pool) + r"\S* ([\w\-]+)\(", hlo)
+        assert made == ["bitcast"] * 2, made
 
 
 def _decode_chunk_hlo(v5e, monkeypatch, quant_kv):
