@@ -5,7 +5,7 @@ of the serving layer: ``serving/paged_kv.py`` writes its three programs
 (decode, chunked prefill, speculative verify) once over these pieces, and
 a config class builds its own in a ``paged_ops()`` method from the layer
 pieces its ``forward`` uses (``models/llama.py``, ``models/mla_moe.py``,
-``models/cca_moe.py``).
+``models/cca_moe.py``, ``models/qwen3_next.py``).
 The pool array helpers a model's attention may need are in
 ``ops/paged_pool.py``.
 """
@@ -53,7 +53,28 @@ class PagedOps:
     None where every prompt streams through ``paged_prefill_chunk``.
     ``routed_per_token``: expert assignments one token makes over all
     layers (0: no experts). ``refuses``: mechanism -> why the engine must
-    not be built with it."""
+    not be built with it.
+
+    Layers of two kinds. ``period`` (empty for a model whose every layer
+    attends over the pools): the kinds of the layers of ONE step of the
+    scan, in published order, each ``"attention"`` or ``"recurrent"``;
+    ``layer_stacks`` then gives one stack of periods and
+    ``period_layer(lp, j)`` cuts the weights of the period's ``j``-th layer
+    out of a step's. Each pool and each state array belongs to the layers
+    of one kind and holds a row for those only: ``pool_rows`` (and
+    ``slot_rows``) to the attention layers, ``[layers_of("attention"), ...]``,
+    the attention layer ``i`` of its kind at index ``i``; ``state_rows``
+    (name -> (the shape of one slot's state in one layer, dtype)) to the
+    recurrent layers, ``[layers_of("recurrent"), max_batch, *row]``. A
+    recurrent layer owns no pool row: its pieces are ``recurrent(lp, x,
+    positions, state, valid)`` -> ``(o, left)`` over [B, S] rows from
+    ``state`` (``{name: [B, *row]}``, zeros before a sequence's first
+    token), ``left`` the state at each slot's last TRUE row (pad rows leave
+    it as it was), and ``recurrent_decode(lp, x, positions, states, layer,
+    live, kernel, interpret)`` -> ``(o, states)`` for one new row a slot,
+    ``states`` the WHOLE arrays, updated in place for the ``live`` [B] slots
+    only. ``out`` takes either kind's ``o``. A pool whose row holds several
+    kv heads wider than a lane tile is stored merged (``stored_merged``)."""
 
     n_layers: int
     pool_rows: dict
@@ -69,3 +90,26 @@ class PagedOps:
     slot_rows: dict = dataclasses.field(default_factory=dict)
     layer_carry: Optional[Callable] = None
     refuses: dict = dataclasses.field(default_factory=dict)
+    period: tuple = ()
+    period_layer: Optional[Callable] = None
+    state_rows: dict = dataclasses.field(default_factory=dict)
+    recurrent: Optional[Callable] = None
+    recurrent_decode: Optional[Callable] = None
+
+    def layers_of(self, kind: str) -> int:
+        """How many layers of ``kind`` the model has: the first index of
+        the arrays that belong to that kind."""
+        if not self.period:
+            return self.n_layers if kind == "attention" else 0
+        return self.n_layers // len(self.period) * self.period.count(kind)
+
+
+def stored_merged(row) -> bool:
+    """Whether a pool of token rows ``row`` = (kv heads, head_dim) keeps a
+    block's (token, kv head) rows merged, ``[layers, num_blocks, block_size
+    * row[0], row[1]]``, token ``t``'s head ``g`` at row ``t * row[0] + g``:
+    the matrix the paged decode kernel reads. The 5-D form is that matrix in
+    HBM only while a head is one lane tile wide (128 values); a wider head's
+    stored tiles interleave the heads, and the kernel's view of it would be
+    a copy of the whole pool every call."""
+    return len(row) == 2 and row[0] > 1 and row[1] > 128

@@ -311,7 +311,7 @@ def _chunk_blocks(block_size, kvh, head_dim, n_tables):
 
 def paged_decode_attention(q, k_pool, v_pool, layer, tables, kv_len, *,
                            interpret: bool = False,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None, kv_heads=None):
     """Block-resident paged GQA decode attention over ONE layer of the
     whole pool, addressed by (layer, block).
 
@@ -332,11 +332,23 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, kv_len, *,
     the layer's scale table rides whole in VMEM and each copied block
     dequants by its own row of it (upcast * scale) between the copy and
     the products.
+
+    A pool stored merged (``models/paged.stored_merged``) comes as ``[L,
+    num_blocks, block_size * KV_H, D]`` with ``kv_heads`` = KV_H: the matrix
+    the kernel reads, so no view of it is taken. Heads wider than one lane
+    tile (D = 256) need it: their 5-D tiles interleave the heads.
     """
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
     b, h, d = q.shape
-    n_layers, num_blocks, block_size, kvh, d_k = k_pool.shape
+    if k_pool.ndim == 4:
+        if kv_heads is None:
+            raise ValueError("a pool stored merged needs kv_heads")
+        n_layers, num_blocks, rows, d_k = k_pool.shape
+        kvh = kv_heads
+        block_size = rows // kvh
+    else:
+        n_layers, num_blocks, block_size, kvh, d_k = k_pool.shape
     if d != d_k:
         raise ValueError(f"head_dim mismatch: q has {d}, pool has {d_k}")
     if h % kvh:
@@ -347,7 +359,7 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, kv_len, *,
     kv_len = kv_len.astype(jnp.int32)
     tables = tables.astype(jnp.int32)
 
-    if kvh % 8:
+    if kvh % 8 and k_pool.ndim == 5:
         # a kv-head dim that does not fill whole sublane tiles: the block's
         # (token, kv head) rows merged, so that it lands in VMEM as the
         # dense matrix the products take (module docstring). What the v5e

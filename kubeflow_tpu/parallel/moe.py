@@ -266,7 +266,7 @@ def grouped_matmul(rows, weights, group_sizes):
 
 
 def routed_experts(tokens, experts, weights, w_gate, w_up, w_down,
-                   valid=None, n_experts=None, first_group=0):
+                   valid=None, n_experts=None, first_group=0, held_from=None):
     """Sum over each token's chosen experts of ``w * SwiGLU_e(token)``.
 
     tokens [T, D]; experts/weights [T, k] over ``n_experts`` experts;
@@ -281,12 +281,26 @@ def routed_experts(tokens, experts, weights, w_gate, w_up, w_down,
     multiply nothing and give zeros). Returns (y [T, D] in tokens.dtype,
     tokens_per_expert [n_experts] int32). No token is dropped and none is
     coupled to another: a row's output depends on its own experts alone,
-    whatever else is in the batch."""
+    whatever else is in the batch.
+
+    ``held_from``: this chip holds only a share of the layer's experts
+    (expert parallelism), the ``n_experts`` from ``held_from`` on, and
+    ``experts`` name experts of the whole layer. A pick outside the share
+    multiplies nothing and adds nothing (its row sorts behind every group,
+    as an invalid token's does); what the absent chips would add is not
+    stood in for. Counts are of the held experts."""
     t, k = experts.shape
     groups = w_gate.shape[0]
     e = groups if n_experts is None else n_experts
     flat = experts.reshape(t * k)
-    if valid is not None:
+    live_pick = None
+    if held_from is not None:
+        flat = flat - held_from
+        live_pick = (flat >= 0) & (flat < e)
+        if valid is not None:
+            live_pick = live_pick & jnp.repeat(valid, k)
+        flat = jnp.where(live_pick, flat, e)
+    elif valid is not None:
         flat = jnp.where(jnp.repeat(valid, k), flat, e)    # behind all groups
     order = jnp.argsort(flat, stable=True)                 # [T*k] by expert
     counts = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
@@ -300,6 +314,10 @@ def routed_experts(tokens, experts, weights, w_gate, w_up, w_down,
     # not a second sort); rows of no group may hold anything
     back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
     out = out[back].reshape(t, k, -1).astype(jnp.float32)
+    if live_pick is not None:
+        out = jnp.where(live_pick.reshape(t, k, 1), out * weights[..., None],
+                        0.0)
+        return out.sum(1).astype(tokens.dtype), counts
     live = jnp.ones((t,), bool) if valid is None else valid
     out = jnp.where(live[:, None, None], out * weights[..., None], 0.0)
     return out.sum(1).astype(tokens.dtype), counts

@@ -227,7 +227,7 @@ def sample_logits(logits, rng, temperature, top_k, top_p,
 class LLMEngine:
     """Continuous-batching generation over a model's paged programs.
     ``cfg`` is any config with a ``paged_ops()`` method (``LlamaConfig``,
-    ``MlaMoeConfig``, ``CcaMoeConfig``): what the engine needs of the model — the pool's
+    ``MlaMoeConfig``, ``CcaMoeConfig``, ``Qwen3NextConfig``): what the engine needs of the model — the pool's
     rows, the layer's pieces, the head, what it cannot be served with — it
     asks through that one object."""
 
@@ -368,9 +368,11 @@ class LLMEngine:
                              scale_sharding=sc_sh)
         self.cache = self.paged.cache
         # what the cache holds besides the pools: the rows a model keeps
-        # per slot and layer (``PagedOps.slot_rows``; 0 for most)
+        # per slot and layer (``PagedOps.slot_rows``, ``state_rows``; 0 for
+        # most)
         self.slot_state_bytes = sum(
-            self.cache[key].nbytes for key in ops.slot_rows)
+            self.cache[key].nbytes for key in (*ops.slot_rows,
+                                               *ops.state_rows))
         self._free: list[int] = list(range(max_batch))
         self._active: dict[int, GenRequest] = {}     # slot -> request
         self._waiting: list[GenRequest] = []
@@ -395,6 +397,9 @@ class LLMEngine:
         # with the tokens they belong to
         self.moe_tokens_per_expert: Optional[np.ndarray] = None
         self.moe_experts_hit = 0
+        # a layer that holds a share of its experts: picks routed to the
+        # experts this chip does not hold (they add nothing here)
+        self.moe_absent_picks = 0
         self.generated_tokens = 0
         self.prefill_dispatches = 0       # observability: admission batching
         # multi-step decode: one dispatch runs `decode_chunk` decode+sample
@@ -659,8 +664,9 @@ class LLMEngine:
             text = None
         if not text:
             return
+        # a recurrent layer's state arrays count as pools
         shapes = [self.cache[key].sharding.shard_shape(self.cache[key].shape)
-                  for key in self.model.pool_rows]
+                  for key in (*self.model.pool_rows, *self.model.state_rows)]
         found = pool_shaped_ops(text, shapes)
         self.decode_pool_shaped_ops = len(found)
         log = logger.warning if found else logger.info
@@ -930,8 +936,8 @@ class LLMEngine:
     def kv_row_bytes(self) -> int:
         """Bytes ONE token caches over all layers and pools, as stored
         (a quantized pool's scale tables left out)."""
-        return sum(int(np.prod(self.cache[key].shape[3:]))
-                   * self.cache[key].dtype.itemsize * self.cache[key].shape[0]
+        return sum(self.cache[key].nbytes // (self.cache[key].shape[1]
+                                              * self.paged.block_size)
                    for key in self.model.pool_rows)
 
     def has_work(self) -> bool:
@@ -1109,9 +1115,13 @@ class LLMEngine:
             self._min_deterministic_remaining(),
             pressure=bool(self._waiting))
         self.sched.note_decode_dispatch(chunk_len)
+        attrs = {}
+        if self.model.state_rows:
+            # slots whose recurrent state a step reads and writes
+            attrs["state_slots"] = len(self._active)
         dspan = self._dispatch_span(
             "decode.step", [r for _, r in self._active.items()],
-            chunk_len=chunk_len, batch=len(self._active))
+            chunk_len=chunk_len, batch=len(self._active), **attrs)
         self._rng, step_rng = jax.random.split(self._rng)
         # static: an all-greedy batch skips the per-step full-vocab
         # sort (two compile variants total)
@@ -1278,12 +1288,18 @@ class LLMEngine:
         if self.moe_tokens_per_expert is None:
             self.moe_tokens_per_expert = np.zeros(per_expert.shape, np.int64)
         self.moe_tokens_per_expert += per_expert
+        absent = (int(np.asarray(stats["absent_picks"]).sum())
+                  if "absent_picks" in stats else None)
+        if absent is not None:
+            self.moe_absent_picks += absent
         if not decode:
             return {}
         hit = int(np.asarray(stats["experts_hit"]).sum())
         self.moe_experts_hit += hit
         attrs = {"routed_assignments": int(per_expert.sum()),
                  "experts_hit": hit}
+        if absent is not None:       # experts held on other chips
+            attrs["absent_picks"] = absent
         if "skipped" in stats:       # a router with a choice of no expert
             attrs["skipped"] = int(np.asarray(stats["skipped"]).sum())
         return attrs
@@ -1440,7 +1456,7 @@ class LLMEngine:
         if self.model.routed_per_token:
             attrs["routed_assignments"] = \
                 min(W, L - st.offset) * self.model.routed_per_token
-        if self.model.slot_rows:
+        if self.model.slot_rows or self.model.state_rows:
             # the chunk began from what the slot's previous chunk left,
             # not from zeros
             attrs["state_carried"] = st.offset > 0

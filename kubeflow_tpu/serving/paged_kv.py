@@ -61,7 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kubeflow_tpu.models.paged import PagedOps
+from kubeflow_tpu.models.paged import PagedOps, stored_merged
 from kubeflow_tpu.ops.paged_pool import (
     kv_qmax, kv_store, quant_scatter_rows,
 )
@@ -85,7 +85,10 @@ def init_paged_cache(cfg, max_batch: int, max_seq: int,
     a latent-attention one. What a model's layers keep per SLOT and not per
     token (``PagedOps.slot_rows``) lies beside the pools, ``[n_layers,
     max_batch, *row]``: a second kind of state in the one cache, carried,
-    donated and updated in place with the pools.
+    donated and updated in place with the pools. In a model of two layer
+    kinds (``PagedOps.period``) each array has rows for the layers of the
+    kind that owns it only: the pools and ``slot_rows`` for the attention
+    layers, ``state_rows`` (in their own dtype) for the recurrent ones.
     ``kv_sharding`` allocates the pool DIRECTLY with that sharding — a
     pod-sized pool must never transit one chip unsharded.
 
@@ -105,18 +108,26 @@ def init_paged_cache(cfg, max_batch: int, max_seq: int,
         raise ValueError(f"{type(cfg).__name__} has no quantized KV pool: "
                          + ops.refuses["quantized KV pool"])
     cache = {}
+    n_attn = ops.layers_of("attention")
     for name, row in ops.pool_rows.items():
+        if stored_merged(row):     # a token's kv heads: consecutive rows
+            row = (block_size * row[0], *row[1:])
+        else:
+            row = (block_size, *row)
         cache[name] = jnp.zeros(
-            (ops.n_layers, num_blocks, block_size, *row),
+            (n_attn, num_blocks, *row),
             kv_store_dtype(quant_kv) if quantized else dtype,
             device=kv_sharding)
     if quantized:
         for name, row in ops.pool_rows.items():
             cache[name + "_scale"] = jnp.zeros(
-                (ops.n_layers, num_blocks, row[0]), jnp.float32,
+                (n_attn, num_blocks, row[0]), jnp.float32,
                 device=scale_sharding)
     for name, row in ops.slot_rows.items():
-        cache[name] = jnp.zeros((ops.n_layers, max_batch, *row), dtype)
+        cache[name] = jnp.zeros((n_attn, max_batch, *row), dtype)
+    for name, (row, state_dtype) in ops.state_rows.items():
+        cache[name] = jnp.zeros(
+            (ops.layers_of("recurrent"), max_batch, *row), state_dtype)
     cache["len"] = jnp.zeros((max_batch,), jnp.int32, device=len_sharding)
     return cache
 
@@ -564,21 +575,38 @@ def pool_shaped_ops(hlo_text: str, pool_shapes) -> list:
 
 # ------------------------------------------------------------ jitted bodies
 
+def _block_size(ops: PagedOps, cache) -> int:
+    """Tokens a pool block holds."""
+    name, row = next(iter(ops.pool_rows.items()))
+    return cache[name].shape[2] // (row[0] if stored_merged(row) else 1)
+
+
 def _scatter_rows(pools, layer, blk, off, rows):
     """This step's rows (``{pool: [..., *row]}``) into layer ``layer`` of
     the carried pools at (blk, off); quantize-on-write where the pool is
-    quantized (``k_scale`` present). Returns the updated pools dict."""
+    quantized (``k_scale`` present). A pool stored merged
+    (``models/paged.stored_merged``) takes a token's kv heads as
+    consecutive rows of its block. Returns the updated pools dict."""
     if "k_scale" in pools:
         k_pool, k_sc = quant_scatter_rows(pools["k"], pools["k_scale"],
                                           layer, blk, off, rows["k"])
         v_pool, v_sc = quant_scatter_rows(pools["v"], pools["v_scale"],
                                           layer, blk, off, rows["v"])
         return {"k": k_pool, "v": v_pool, "k_scale": k_sc, "v_scale": v_sc}
-    return {**pools, **{key: pools[key].at[layer, blk, off].set(
-        val.astype(pools[key].dtype)) for key, val in rows.items()}}
+
+    def put(pool, val):
+        if not stored_merged(val.shape[blk.ndim:]):
+            return pool.at[layer, blk, off].set(val.astype(pool.dtype))
+        kvh = val.shape[-2]
+        row = off[..., None] * kvh + jnp.arange(kvh)
+        return pool.at[layer, jnp.broadcast_to(blk[..., None], row.shape),
+                       row].set(val.astype(pool.dtype))
+    return {**pools, **{key: put(pools[key], val)
+                        for key, val in rows.items()}}
 
 
-def _scan_layers(ops: PagedOps, params, x, cache, layer_fn):
+def _scan_layers(ops: PagedOps, params, x, cache, layer_fn,
+                 recurrent_fn=None):
     """Run ``layer_fn(lp, x, extra, pools, layer) -> (x, extra, pools,
     stats)`` over the layers with the layer index and the layer's weights
     scanned and the pools (the scale tables of a quantized pool and a
@@ -597,15 +625,23 @@ def _scan_layers(ops: PagedOps, params, x, cache, layer_fn):
     ``whole`` are NOT scanned but handed to every layer as the stack they
     are, with the layer's place in it as ``stack_index`` — for weights a
     kernel addresses by (layer, group) itself, which a scan would slice
-    out, a copy, layer by layer."""
+    out, a copy, layer by layer.
+
+    A model of two layer kinds (``PagedOps.period``) has one stack of
+    periods: a step of the scan runs the period's layers in published
+    order, each by the function of its kind (``layer_fn`` for attention,
+    ``recurrent_fn`` for a recurrent layer) and with its index among the
+    layers of that kind, the index of its rows in the arrays that kind
+    owns. Its stats come back in the order of all layers."""
     pools = {key: cache[key] for key in _pool_keys(cache)}
     extra = ops.layer_carry(x) if ops.layer_carry else {}
     first, stats = 0, {}
+    fns = {"attention": layer_fn, "recurrent": recurrent_fn}
     for stack, whole in ops.layer_stacks(params):
         kept = {key: stack[key] for key in whole}
-        n = jax.tree.leaves(stack)[0].shape[0]
-        xs = (first + jnp.arange(n),
-              {key: val for key, val in stack.items() if key not in whole})
+        scanned = {key: val for key, val in stack.items() if key not in whole}
+        n = jax.tree.leaves(scanned)[0].shape[0]
+        xs = (first + jnp.arange(n), scanned)
         if kept:
             xs += (jnp.arange(n),)
 
@@ -613,15 +649,35 @@ def _scan_layers(ops: PagedOps, params, x, cache, layer_fn):
             layer, lp = xs[:2]
             if kept:
                 lp = dict(lp, **kept, stack_index=xs[2])
+            if ops.period:
+                return _period(ops, fns, lp, carry, layer)
             *carry, ys = layer_fn(lp, *carry, layer)
             return tuple(carry), ys
 
         (x, extra, pools), ys = jax.lax.scan(body, (x, extra, pools), xs)
         first += n
         for key, val in ys.items():
+            if ops.period:        # [periods, layers a period, ...]
+                val = val.reshape(-1, *val.shape[2:])
             stats[key] = (val if key not in stats
                           else jnp.concatenate([stats[key], val]))
     return x, extra, pools, stats
+
+
+def _period(ops: PagedOps, fns, lp, carry, p):
+    """One period of layers (``_scan_layers``): ``p`` is the period's
+    index, a layer's index among those of its kind is ``p`` times the kind's
+    count a period plus the kind's layers before it in the period."""
+    per = {kind: ops.period.count(kind) for kind in ops.period}
+    before = dict.fromkeys(per, 0)
+    ys = []
+    for j, kind in enumerate(ops.period):
+        *carry, y = fns[kind](ops.period_layer(lp, j), *carry,
+                              p * per[kind] + before[kind])
+        before[kind] += 1
+        ys.append(y)
+    return tuple(carry), {key: jnp.stack([y[key] for y in ys])
+                          for key in ys[0]}
 
 
 def paged_insert_batch(cache, k_new, v_new, blk_ids, lengths, slots):
@@ -742,13 +798,15 @@ def paged_decode_step(params, token, cfg, cache, tables,
     place. A slot that holds no sequence (``len`` 0) or is not ``active``
     ([B] bool: the engine's dispatch mask; a slot freed or mid-prefill
     whose ``len`` is stale for one more step) writes nothing: its row must
-    stay what its next chunk or step expects."""
+    stay what its next chunk or step expects. The same holds for a
+    recurrent layer's state (``PagedOps.state_rows``), which the model's
+    ``recurrent_decode`` updates in place for the live slots alone."""
     ops = paged_ops(cfg)
     kernel, _ = resolve_decode_kernel(kernel, mesh=mesh,
                                       n_kv_heads=cfg.n_kv_heads)
     interpret = jax.default_backend() == "cpu"
     b = token.shape[0]
-    bs = cache[next(iter(ops.pool_rows))].shape[2]
+    bs = _block_size(ops, cache)
     pos = cache["len"]                                   # [B]
     positions = pos[:, None]
     x = ops.embed(params, token[:, None])
@@ -759,7 +817,7 @@ def paged_decode_step(params, token, cfg, cache, tables,
     # idle slots hold len 0: keep their garbage rows out of expert routing
     token_mask = (pos > 0)[:, None]
     live = token_mask
-    if ops.slot_rows and active is not None:
+    if (ops.slot_rows or ops.state_rows) and active is not None:
         live = token_mask & active[:, None]
 
     def layer_fn(lp, x, extra, pools, layer):
@@ -776,7 +834,15 @@ def paged_decode_step(params, token, cfg, cache, tables,
         x, extra, stats = ops.out(lp, x, o, token_mask, extra)
         return x, extra, pools, stats
 
-    x, _, pools, stats = _scan_layers(ops, params, x, cache, layer_fn)
+    def recurrent_fn(lp, x, extra, pools, layer):
+        o, states = ops.recurrent_decode(
+            lp, x, positions, {name: pools[name] for name in ops.state_rows},
+            layer, live[:, 0], kernel, interpret)
+        x, extra, stats = ops.out(lp, x, o, token_mask, extra)
+        return x, extra, {**pools, **states}, stats
+
+    x, _, pools, stats = _scan_layers(ops, params, x, cache, layer_fn,
+                                      recurrent_fn)
     logits = ops.head(params, x[:, 0])
     cache = {**pools, "len": cache["len"] + 1}
     return logits, cache, stats
@@ -812,10 +878,12 @@ def paged_prefill_chunk(params, tokens, cfg, cache,
     what the slot's previous chunk left, and ZEROS at ``offset`` 0, inside
     the program, so admission resets nothing on the host and a reused slot
     never sees its predecessor; the chunk's last TRUE row leaves the slot's
-    new rows, pad rows past ``length`` nothing."""
+    new rows, pad rows past ``length`` nothing. A recurrent layer's state
+    (``PagedOps.state_rows``) is read and left the same way: the model's
+    ``recurrent`` returns it at the chunk's last true row."""
     ops = paged_ops(cfg)
     _, c = tokens.shape
-    bs = cache[next(iter(ops.pool_rows))].shape[2]
+    bs = _block_size(ops, cache)
     pos = offset + jnp.arange(c)                          # [C] absolute
     valid = pos < length
     # destination rows: real rows land in the slot's table blocks; pad
@@ -844,7 +912,24 @@ def paged_prefill_chunk(params, tokens, cfg, cache,
         x, extra, stats = ops.out(lp, x, o, valid[None, :], extra)
         return x, extra, pools, stats
 
-    x, _, pools, stats = _scan_layers(ops, params, x, cache, layer_fn)
+    def recurrent_fn(lp, x, extra, pools, layer):
+        # the slot's state gathered and scattered one leading row a window,
+        # as the decode step writes it: a [rows, width] window at once has
+        # XLA lay an array of few wide rows out anew, twice a call
+        def at(name):
+            n = ops.state_rows[name][0][0]
+            return layer, jnp.full((n,), slot), jnp.arange(n)
+
+        state = {name: jnp.where(offset > 0, pools[name][at(name)], 0)[None]
+                 for name in ops.state_rows}
+        o, left = ops.recurrent(lp, x, positions, state, valid[None, :])
+        pools = {**pools, **{name: pools[name].at[at(name)].set(val[0])
+                             for name, val in left.items()}}
+        x, extra, stats = ops.out(lp, x, o, valid[None, :], extra)
+        return x, extra, pools, stats
+
+    x, _, pools, stats = _scan_layers(ops, params, x, cache, layer_fn,
+                                      recurrent_fn)
     cache = {**pools, "len": cache["len"]}
     if "experts" in stats:       # [layers, 1, C, k] -> every row's choice
         stats["experts"] = stats["experts"][:, 0]
@@ -883,12 +968,13 @@ def paged_verify_step(params, tokens, cfg, cache, tables, limit):
 
     Returns (logits [B, S, V] f32, cache)."""
     ops = paged_ops(cfg)
-    if ops.slot_rows:
+    if ops.slot_rows or ops.state_rows:
         raise ValueError(f"{type(cfg).__name__} keeps per-slot rows "
-                         f"{sorted(ops.slot_rows)}: the verify step cannot "
-                         "rewind them past a rejected draft")
+                         f"{sorted({**ops.slot_rows, **ops.state_rows})}: "
+                         "the verify step cannot rewind them past a "
+                         "rejected draft")
     b, s = tokens.shape
-    bs = cache[next(iter(ops.pool_rows))].shape[2]
+    bs = _block_size(ops, cache)
     start = cache["len"]                                   # [B]
     pos = start[:, None] + jnp.arange(s)[None, :]          # [B, S] absolute
     valid = pos < limit[:, None]

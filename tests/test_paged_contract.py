@@ -179,3 +179,96 @@ def test_int8_tree_runs_the_float_trees_pieces(program, tied):
                    else step(seq[:, 8]))
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(got[1]),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("head_dim", [128, 256])
+def test_pools_and_state_belong_to_the_layers_of_their_kind(head_dim):
+    """A model of two layer kinds (PR 38: ``PagedOps.period``): K and V
+    have rows for the attention layers only, stored merged (a block's
+    (token, kv head) rows, the matrix the decode kernel reads) where a head
+    is wider than a lane tile, and the recurrent state has rows for the
+    recurrent layers only, in its own dtype; the layer loop is ONE scan of
+    periods that carries x and the four arrays."""
+    from kubeflow_tpu.models import qwen3_next
+
+    cfg = qwen3_next.qwen3_next_tiny(dtype=jnp.float32, head_dim=head_dim)
+    ops = paged_kv.paged_ops(cfg)
+    assert ops.period == ("recurrent",) * 3 + ("attention",)
+    assert (ops.layers_of("attention"), ops.layers_of("recurrent")) == (2, 6)
+    b, bs, nbp = 2, 8, 4
+    cache = jax.eval_shape(
+        lambda: paged_kv.init_paged_cache(cfg, b, nbp * bs, bs, b * nbp + 1))
+    assert set(cache) == {"k", "v", "gdn_s", "gdn_conv", "len"}
+    row = ((bs * cfg.n_kv_heads,) if head_dim > 128
+           else (bs, cfg.n_kv_heads))
+    for key in ("k", "v"):
+        assert cache[key].shape == (2, b * nbp + 1, *row, cfg.head_dim)
+    assert cache["gdn_s"].shape == (6, b, cfg.n_v_heads, cfg.k_head_dim,
+                                    cfg.v_head_dim)
+    assert cache["gdn_conv"].shape == (6, b, cfg.conv_kernel - 1,
+                                       cfg.conv_dim)
+    assert {cache[key].dtype for key in ("gdn_s", "gdn_conv")} == {
+        jnp.dtype(jnp.float32)}
+    params = jax.eval_shape(
+        lambda: qwen3_next.init_params(jax.random.key(0), cfg))
+    tables = jax.ShapeDtypeStruct((b, nbp), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda p, tok, c, t, active: paged_kv.paged_decode_step(
+            p, tok, cfg, c, t, active=active))(
+        params, jax.ShapeDtypeStruct((b,), jnp.int32), cache, tables,
+        jax.ShapeDtypeStruct((b,), bool)).jaxpr
+    loops = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    assert [e.params["length"] for e in loops] == [2]
+    assert loops[0].params["num_carry"] == 1 + 4
+
+
+# The lowered text of the older models' programs at the parent of PR 38
+# (``jax.jit(...).lower(...).as_text()``, sha256, the first 16 hex digits):
+# the seam grew layer kinds, recurrent state and merged pools, and a model
+# with none of them must not pay for it in a single operation.
+PARENT_PROGRAM_TEXT = {
+    ("llama", "decode", "gather"): "b0475e89eec8d63d",
+    ("llama", "decode", "pallas"): "f04fb7a5792d1fad",
+    ("llama", "chunk", ""): "4c2f06c79a52c54e",
+    ("llama", "verify", ""): "0a6c3acd03b3f90a",
+    ("mla_moe", "decode", "gather"): "3e9cd8ffdac457c6",
+    ("mla_moe", "decode", "pallas"): "b02d7f911ab49aa3",
+    ("mla_moe", "chunk", ""): "f4299967ea8a7162",
+    ("cca_moe", "decode", "gather"): "2ad3046ed7b5304a",
+    ("cca_moe", "decode", "pallas"): "951ec1b1bd23ca34",
+    ("cca_moe", "chunk", ""): "569cc8b136a92fa1",
+}
+
+
+@pytest.mark.parametrize("name,program,kernel", sorted(PARENT_PROGRAM_TEXT),
+                         ids=lambda v: v or "-")
+def test_the_older_models_programs_are_the_parents(name, program, kernel):
+    import hashlib
+
+    model = {"llama": llama, "mla_moe": mla_moe, "cca_moe": cca_moe}[name]
+    cfg = getattr(model, f"{name}_tiny")(dtype=jnp.float32)
+    b, bs, nbp = 2, 8, 4
+    params = jax.eval_shape(
+        lambda: model.init_params(jax.random.key(0), cfg))
+    cache = jax.eval_shape(
+        lambda: paged_kv.init_paged_cache(cfg, b, nbp * bs, bs, b * nbp + 1))
+    tables = jax.ShapeDtypeStruct((b, nbp), jnp.int32)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    if program == "decode":
+        fn = lambda p, tok, c, t, active: paged_kv.paged_decode_step(
+            p, tok, cfg, c, t, kernel=kernel, active=active)
+        args = (params, jax.ShapeDtypeStruct((b,), jnp.int32), cache, tables,
+                jax.ShapeDtypeStruct((b,), bool))
+    elif program == "chunk":
+        fn = lambda p, toks, c, t, slot, off, n: paged_kv.paged_prefill_chunk(
+            p, toks, cfg, c, t, slot, off, n)
+        args = (params, jax.ShapeDtypeStruct((1, 16), jnp.int32), cache,
+                tables, scalar, scalar, scalar)
+    else:
+        fn = lambda p, toks, c, t, limit: paged_kv.paged_verify_step(
+            p, toks, cfg, c, t, limit)
+        args = (params, jax.ShapeDtypeStruct((b, 4), jnp.int32), cache,
+                tables, jax.ShapeDtypeStruct((b,), jnp.int32))
+    text = jax.jit(fn).lower(*args).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == PARENT_PROGRAM_TEXT[(name, program, kernel)]

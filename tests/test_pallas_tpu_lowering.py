@@ -457,6 +457,164 @@ def test_cca_prefill_chunk_at_the_cells_shapes(v5e, monkeypatch):
     assert mem.temp_size_in_bytes < 2 ** 28
 
 
+# qwen3-next-80b-a3b-l8.longgen-offline (benchmarks/traffic): 256 slots of
+# 96 blocks of 64 rows, the published widths, the cell's 8 layers (at 4, the
+# conv ring is small enough that XLA parks all of it in VMEM for a call and
+# writes it back: a move of the whole array); a pool cut to 1,100 blocks
+QWEN = dict(b=256, nb=1100, nbp=96, layers=8)
+
+
+def test_gdn_decode_kernel_at_the_cells_shapes(v5e):
+    """``ops/pallas_gdn.gdn_decode`` over the cell's state (6 GDN layers x
+    256 slots x 32 heads x 128 x 128 float32, 3.2 GB): Mosaic takes the
+    [32, 128, 128] block a slot and the two gates as rows, the state is
+    aliased from input to output (updated in place, no copy of it), and
+    the custom call carries the kernel's name, which the readers match."""
+    from kubeflow_tpu.ops.pallas_gdn import gdn_decode
+
+    one = NamedSharding(Mesh(v5e[:1], ("x",)), P())
+    b, h, d, layers = QWEN["b"], 32, 128, 6
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one)
+    state = sds((layers, b, h, d, d))
+    compiled = jax.jit(gdn_decode, donate_argnums=(5,)).lower(
+        sds((b, h, d)), sds((b, h, d)), sds((b, h, d)), sds((b, h)),
+        sds((b, h)), state, sds((), jnp.int32), sds((b,), bool)).compile()
+    hlo = compiled.as_text()
+    assert len(re.findall(r"%gdn_decode(\.\d+)? = \(", hlo)) == 1
+    assert paged_kv.pool_shaped_ops(hlo, [state.shape]) == []
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        layers * b * h * d * d * 4
+
+
+def test_qwen3_next_decode_chunk_updates_pools_and_state_in_place(
+        v5e, monkeypatch):
+    """``paged_decode_step`` of the Qwen3-Next model at the cell's engine
+    (two periods of the published widths) under a 4-step scan with the cache
+    donated and the engine's dispatch mask: the GDN kernel once a GDN layer,
+    the GQA kernel as it stands over a pool stored merged (at head_dim 256
+    the 5-D pool's view would be a copy of both pools every call), three
+    grouped products a layer, and K, V, S and the conv ring updated in
+    place: nothing as large as any of them, nor as one of their layers."""
+    from kubeflow_tpu.models import qwen3_next
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q = QWEN
+    cfg = qwen3_next.Qwen3NextConfig(n_layers=q["layers"], vocab_size=18992,
+                                     n_experts_held=64,
+                                     max_seq=q["nbp"] * BS)
+    one = NamedSharding(Mesh(v5e[:1], ("x",)), P())
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one), tree)
+
+    params = on_chip(jax.eval_shape(lambda: qwen3_next.init_params(
+        jax.random.key(0), cfg, dtype=jnp.bfloat16)))
+    cache = on_chip(jax.eval_shape(lambda: paged_kv.init_paged_cache(
+        cfg, q["b"], q["nbp"] * BS, BS, q["nb"])))
+    sds = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt,
+                                                            sharding=one)
+
+    def chunk(params, token, cache, tables, active):
+        def one_step(carry, _):
+            token, cache = carry
+            logits, cache, stats = paged_kv.paged_decode_step(
+                params, token, cfg, cache, tables, kernel="pallas",
+                active=active)
+            return (jnp.argmax(logits, -1).astype(jnp.int32), cache), stats
+        return jax.lax.scan(one_step, (token, cache), None, length=4)
+
+    compiled = jax.jit(chunk, donate_argnums=(2,)).lower(
+        params, sds((q["b"],)), cache, sds((q["b"], q["nbp"])),
+        sds((q["b"],), bool)).compile()
+    hlo = compiled.as_text()
+    assert len(re.findall(r"%gdn_decode(\.\d+)? = \(", hlo)) == 3
+    assert len(re.findall(r"%closed_call\.\d+ = \S+ custom-call\(", hlo)) == 1
+    assert len(re.findall(r"%gmm(\.\d+)? = \S+ custom-call\(", hlo)) == 12
+    keys = ("k", "v", "gdn_s", "gdn_conv")
+    assert paged_kv.pool_shaped_ops(
+        hlo, [cache[key].shape for key in keys]) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        cache[key].size * cache[key].dtype.itemsize for key in keys)
+    assert mem.temp_size_in_bytes < 2 ** 30
+
+
+
+def test_gdn_prefill_metric_reads_the_chunk_scan(v5e, monkeypatch):
+    """``gdn_prefill.time_share_pct.longgen`` matches operations of the chunk
+    program by their result type (the reduced trace keeps an operation's
+    name, opcode and type, not the name scope: ``benchmarks/lib/xplane.
+    short_name``). At the cell's shapes, every operation of the chunk scan
+    (``jax.named_scope("gdn_chunk_scan")``) whose result holds 2^15 values
+    or more is matched, and what else is matched moves the scan's state
+    in or its outputs' buffers: the slot's S gathered and selected, copies,
+    broadcasts."""
+    import json
+    import os
+    import sys
+
+    from kubeflow_tpu.models import qwen3_next
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "benchmarks"))
+    from lib import xplane
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "metrics", "gdn_prefill.time_share_pct.longgen"
+                           ".json")) as f:
+        matched = re.compile(json.load(f)["args"]["op"])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q = QWEN
+    cfg = qwen3_next.Qwen3NextConfig(n_layers=q["layers"], vocab_size=18992,
+                                     n_experts_held=64,
+                                     max_seq=q["nbp"] * BS)
+    one = NamedSharding(Mesh(v5e[:1], ("x",)), P())
+    on_chip = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one), tree)
+    params = on_chip(jax.eval_shape(lambda: qwen3_next.init_params(
+        jax.random.key(0), cfg, dtype=jnp.bfloat16)))
+    cache = on_chip(jax.eval_shape(lambda: paged_kv.init_paged_cache(
+        cfg, q["b"], q["nbp"] * BS, BS, q["nb"])))
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+    def chunk(params, tokens, cache, tables, slot, offset, length):
+        return paged_kv.paged_prefill_chunk(params, tokens, cfg, cache,
+                                            tables, slot, offset, length)
+
+    hlo = jax.jit(chunk, donate_argnums=(2,)).lower(
+        params, sds((1, 512)), cache, sds((q["b"], q["nbp"])), sds(()),
+        sds(()), sds(())).compile().as_text()
+    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
+    free = ("parameter", "constant", "get-tuple-element", "tuple", "bitcast")
+    comp, ops = None, []          # what runs: no fusion's inside
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            comp = head.group(2)
+        elif comp not in fused and re.match(r"^  (ROOT )?%", line):
+            text = line.strip().removeprefix("ROOT ")
+            name = xplane.short_name(text)
+            scope = re.search(r'op_name="([^"]*)"', text)
+            if name.split(" ")[1] not in free:
+                ops.append((name, scope.group(1) if scope else ""))
+    in_scan = [(n, s) for n, s in ops if "gdn_chunk_scan" in s]
+    assert len(in_scan) > 50
+    for name, _ in in_scan:
+        dims = re.search(r"\[([\d,]*)\]", name)
+        size = 1
+        for d in (dims.group(1).split(",") if dims and dims.group(1)
+                  else []):
+            size *= int(d)
+        assert size < 2 ** 15 or matched.search(name), name
+    for name, scope in ops:
+        if matched.search(name) and "gdn_chunk_scan" not in scope:
+            assert (name.split(" ")[1] in ("copy-start", "copy-done",
+                                           "broadcast")
+                    or scope.rsplit("/", 1)[-1] in ("gather", "select_n")
+                    ), (name, scope)
+
 PARENT_PREFILL_TEMP_BYTES = 289_382_400      # 94,674,944 with the kernel (PR 32)
 
 
