@@ -54,7 +54,10 @@ chips.
 Layout: three stacks, one a kind (``linear``, ``full``) and one of the
 expert blocks of every layer (``moe``), scanned as one stack of periods
 (``PagedOps.period``); the experts' matrices are handed to the grouped
-products whole (``[layers, E_held, ...]``), never sliced by layer.
+products whole (``[layers, E_held, ...]``), never sliced by layer, and each
+projection matrix to its product as ONE dynamic index into its whole stack
+(``WHOLE_OF``), in the layout it is stored in: a product whose columns come
+per head is split as it is (``_by_part``), not reshaped into heads.
 """
 
 from __future__ import annotations
@@ -65,12 +68,17 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from kubeflow_tpu.ops.attention import attention, decode_attention
 from kubeflow_tpu.ops.rotary import apply_rope, rope_frequencies
 from kubeflow_tpu.parallel import moe
 
 EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
+# the matrices handed to every period whole, by the stack that holds them
+WHOLE_OF = {"linear": ("w_qkvz", "w_out"),
+            "full": ("w_q", "w_k", "w_v", "w_o"), "moe": EXPERT_MATRICES}
+WHOLE = sum(WHOLE_OF.values(), ())
 HIGHEST = jax.lax.Precision.HIGHEST
 SUB_CHUNK = 64
 
@@ -276,6 +284,20 @@ def _proj(x, w, dt):
     return jnp.einsum("bsd,dk->bsk", x, w.astype(dt))
 
 
+def _by_part(y, heads, widths):
+    """A product whose columns come per head as ``[part 0 | part 1 | ...]``
+    (``widths`` wide) -> ``[part 0 of every head | part 1 of every head |
+    ...]``: static slices of the flat product put together (on a v5e a
+    gather of its columns costs 0.7 ms more a decode step). The product is
+    never reshaped into heads first: a reshape-and-split has the compiler
+    store the weight transposed, a copy of the whole stack every call."""
+    per = sum(widths)
+    starts = np.cumsum((0,) + tuple(widths[:-1]))
+    return jnp.concatenate([y[..., h * per + s:h * per + s + w]
+                            for s, w in zip(starts, widths)
+                            for h in range(heads)], -1)
+
+
 def gdn_inputs(lp, x, cfg: Qwen3NextConfig):
     """x [B, S, D] -> (u [B, S, conv_dim] the convolution's input, z [B, S,
     n_v, d_v], b and a [B, S, n_v]), in the model dtype: the normed input
@@ -286,12 +308,10 @@ def gdn_inputs(lp, x, cfg: Qwen3NextConfig):
     r = cfg.n_v_heads // cfg.n_k_heads
     dk, dv = cfg.k_head_dim, cfg.v_head_dim
     h = zc_norm(x, lp["in_norm"], cfg.norm_eps)
-    qkvz = _proj(h, lp["w_qkvz"], dt).reshape(bsz, s, cfg.n_k_heads, -1)
-    q, k, v, z = jnp.split(qkvz, [dk, 2 * dk, 2 * dk + r * dv], axis=-1)
+    u, z = jnp.split(_by_part(_proj(h, lp["w_qkvz"], dt), cfg.n_k_heads,
+                              (dk, dk, r * dv, r * dv)), [cfg.conv_dim], -1)
     ba = _proj(h, lp["w_ba"], dt).reshape(bsz, s, cfg.n_k_heads, 2 * r)
     b, a = ba[..., :r], ba[..., r:]
-    u = jnp.concatenate([q.reshape(bsz, s, -1), k.reshape(bsz, s, -1),
-                         v.reshape(bsz, s, -1)], -1)
     return (u, z.reshape(bsz, s, cfg.n_v_heads, dv),
             b.reshape(bsz, s, -1), a.reshape(bsz, s, -1))
 
@@ -476,13 +496,13 @@ def attn_inputs(lp, x, positions, cfg: Qwen3NextConfig):
     bsz, s, _ = x.shape
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     hid = zc_norm(x, lp["in_norm"], cfg.norm_eps)
-    qg = _proj(hid, lp["w_q"], dt).reshape(bsz, s, h, 2 * hd)
-    q = zc_norm(qg[..., :hd], lp["q_norm"], cfg.norm_eps)
+    q, gate = (t.reshape(bsz, s, h, hd) for t in jnp.split(
+        _by_part(_proj(hid, lp["w_q"], dt), h, (hd, hd)), 2, -1))
+    q = zc_norm(q, lp["q_norm"], cfg.norm_eps)
     k = zc_norm(_proj(hid, lp["w_k"], dt).reshape(bsz, s, kv, hd),
                 lp["k_norm"], cfg.norm_eps)
     v = _proj(hid, lp["w_v"], dt).reshape(bsz, s, kv, hd)
-    return (_rope(q, positions, cfg), qg[..., hd:], _rope(k, positions, cfg),
-            v)
+    return _rope(q, positions, cfg), gate, _rope(k, positions, cfg), v
 
 
 def attn_out(lp, o, gate, cfg: Qwen3NextConfig):
@@ -550,29 +570,35 @@ def lm_head(params, x_last, cfg: Qwen3NextConfig):
 
 
 def _stack(params, cfg: Qwen3NextConfig):
-    """The three stacks as one stack of periods, experts whole."""
+    """The three stacks as one stack of periods, the matrices of ``WHOLE``
+    left whole beside it (``paged_kv._scan_layers``)."""
     per = cfg.full_attention_interval
     n = cfg.n_layers // per
-    cut = lambda tree, k: jax.tree.map(
-        lambda a: a.reshape(n, k, *a.shape[1:]), tree)
-    return {"linear": cut(params["linear"], per - 1),
-            "full": cut(params["full"], 1),
-            "moe": cut({key: val for key, val in params["moe"].items()
-                        if key not in EXPERT_MATRICES}, per),
-            **{key: params["moe"][key] for key in EXPERT_MATRICES}}
+    cut = lambda tree, k: {key: a.reshape(n, k, *a.shape[1:])
+                           for key, a in params[tree].items()
+                           if key not in WHOLE}
+    return {"linear": cut("linear", per - 1), "full": cut("full", 1),
+            "moe": cut("moe", per),
+            **{key: params[tree][key] for tree, keys in WHOLE_OF.items()
+               for key in keys}}
 
 
 def period_layer(lp, j, cfg: Qwen3NextConfig):
-    """Layer ``j`` of a period's weights (``_stack``, one step of the
-    scan), the experts' stacks whole and the layer's index among all."""
+    """Layer ``j`` of a period's weights (``_stack``, one step of the scan,
+    with the stacks of ``WHOLE`` and the period's ``stack_index``): each
+    projection matrix by ONE dynamic index into its stack, which the
+    compiler fuses into the product (a period's slice of it, then the
+    layer's, would be two copies), the experts' stacks whole and the
+    layer's index among all."""
     per = cfg.full_attention_interval
     kind, i = ("linear", j) if j < per - 1 else ("full", 0)
+    at = lp["stack_index"] * (per - 1 if kind == "linear" else 1) + i
     pick = lambda tree, i: jax.tree.map(lambda a: a[i], tree)
-    out = {**pick(lp[kind], i), **pick(lp["moe"], j)}
-    if "stack_index" in lp:
-        out.update({key: lp[key] for key in EXPERT_MATRICES},
-                   layer_index=lp["stack_index"] * per + j)
-    return out
+    return {**pick(lp[kind], i), **pick(lp["moe"], j),
+            **{key: jax.lax.dynamic_index_in_dim(lp[key], at, keepdims=False)
+               for key in WHOLE_OF[kind]},
+            **{key: lp[key] for key in EXPERT_MATRICES},
+            "layer_index": lp["stack_index"] * per + j}
 
 
 # ---------------------------------------------------------------------------
@@ -589,13 +615,13 @@ def forward(params, tokens, cfg: Qwen3NextConfig):
              "gdn_conv": jnp.zeros((b, cfg.conv_kernel - 1, cfg.conv_dim),
                                    jnp.float32)}
     valid = jnp.ones((b, s), bool)
-    stack = {key: val for key, val in _stack(params, cfg).items()
-             if key not in EXPERT_MATRICES}
+    stack = _stack(params, cfg)
+    whole = {key: stack.pop(key) for key in WHOLE}
 
-    def period(x, lp):
+    def period(x, xs):
+        lp = dict(xs[1], **whole, stack_index=xs[0])
         for j, kind in enumerate(cfg.period):
             lj = period_layer(lp, j, cfg)
-            lj.update({key: lp["experts"][key][j] for key in EXPERT_MATRICES})
             if kind == "recurrent":
                 o, _ = gdn_chunk(lj, x, positions, zeros, valid, cfg)
             else:
@@ -604,10 +630,8 @@ def forward(params, tokens, cfg: Qwen3NextConfig):
             x, _ = layer_out(lj, x, o, None, cfg)
         return x, None
 
-    per = cfg.full_attention_interval
-    stack["experts"] = {key: params["moe"][key].reshape(
-        -1, per, *params["moe"][key].shape[1:]) for key in EXPERT_MATRICES}
-    x, _ = jax.lax.scan(period, x, stack)
+    n = cfg.n_layers // cfg.full_attention_interval
+    x, _ = jax.lax.scan(period, x, (jnp.arange(n), stack))
     return lm_head(params, x.reshape(b * s, cfg.dim), cfg).reshape(b, s, -1)
 
 
@@ -671,7 +695,7 @@ def _paged_ops(cfg: Qwen3NextConfig):
         recurrent_decode=lambda lp, x, positions, states, layer, live,
         kernel, interpret: gdn_decode(lp, x, positions, states, layer, live,
                                       kernel, interpret, cfg),
-        layer_stacks=lambda params: [(_stack(params, cfg), EXPERT_MATRICES)],
+        layer_stacks=lambda params: [(_stack(params, cfg), WHOLE)],
         embed=lambda params, tokens: embed_tokens(params, tokens, cfg),
         qkv=qkv, decode_attention=decode_attn,
         chunk_attention=chunk_attention,

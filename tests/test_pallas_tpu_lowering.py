@@ -13,6 +13,7 @@ optimized HLO, and must find the ten such instructions of the program as
 it was (``PARENT_HLO``, the lines of PR 26's program that matter).
 """
 
+import math
 import re
 
 import jax
@@ -487,28 +488,22 @@ def test_gdn_decode_kernel_at_the_cells_shapes(v5e):
         layers * b * h * d * d * 4
 
 
-def test_qwen3_next_decode_chunk_updates_pools_and_state_in_place(
-        v5e, monkeypatch):
-    """``paged_decode_step`` of the Qwen3-Next model at the cell's engine
-    (two periods of the published widths) under a 4-step scan with the cache
-    donated and the engine's dispatch mask: the GDN kernel once a GDN layer,
-    the GQA kernel as it stands over a pool stored merged (at head_dim 256
-    the 5-D pool's view would be a copy of both pools every call), three
-    grouped products a layer, and K, V, S and the conv ring updated in
-    place: nothing as large as any of them, nor as one of their layers."""
+@pytest.fixture(scope="module")
+def qwen(v5e):
+    """The Qwen3-Next model's two serving programs compiled for the chip at
+    the cell's engine (two periods of the published widths) as the engine
+    jits them, the cache donated: a decode chunk (a 4-step scan with the
+    engine's dispatch mask) and the 512-token prefill chunk. Returns (config,
+    cache shapes, {"decode": compiled, "chunk": compiled})."""
     from kubeflow_tpu.models import qwen3_next
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     q = QWEN
     cfg = qwen3_next.Qwen3NextConfig(n_layers=q["layers"], vocab_size=18992,
                                      n_experts_held=64,
                                      max_seq=q["nbp"] * BS)
     one = NamedSharding(Mesh(v5e[:1], ("x",)), P())
-
-    def on_chip(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one), tree)
-
+    on_chip = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one), tree)
     params = on_chip(jax.eval_shape(lambda: qwen3_next.init_params(
         jax.random.key(0), cfg, dtype=jnp.bfloat16)))
     cache = on_chip(jax.eval_shape(lambda: paged_kv.init_paged_cache(
@@ -516,7 +511,7 @@ def test_qwen3_next_decode_chunk_updates_pools_and_state_in_place(
     sds = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt,
                                                             sharding=one)
 
-    def chunk(params, token, cache, tables, active):
+    def decode(params, token, cache, tables, active):
         def one_step(carry, _):
             token, cache = carry
             logits, cache, stats = paged_kv.paged_decode_step(
@@ -525,9 +520,31 @@ def test_qwen3_next_decode_chunk_updates_pools_and_state_in_place(
             return (jnp.argmax(logits, -1).astype(jnp.int32), cache), stats
         return jax.lax.scan(one_step, (token, cache), None, length=4)
 
-    compiled = jax.jit(chunk, donate_argnums=(2,)).lower(
-        params, sds((q["b"],)), cache, sds((q["b"], q["nbp"])),
-        sds((q["b"],), bool)).compile()
+    def chunk(params, tokens, cache, tables, slot, offset, length):
+        return paged_kv.paged_prefill_chunk(params, tokens, cfg, cache,
+                                            tables, slot, offset, length)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        programs = {
+            "decode": jax.jit(decode, donate_argnums=(2,)).lower(
+                params, sds((q["b"],)), cache, sds((q["b"], q["nbp"])),
+                sds((q["b"],), bool)).compile(),
+            "chunk": jax.jit(chunk, donate_argnums=(2,)).lower(
+                params, sds((1, 512)), cache, sds((q["b"], q["nbp"])),
+                sds(()), sds(()), sds(())).compile()}
+    return cfg, cache, programs
+
+
+def test_qwen3_next_decode_chunk_updates_pools_and_state_in_place(qwen):
+    """``paged_decode_step`` of the Qwen3-Next model at the cell's engine
+    (the ``qwen`` fixture's decode chunk): the GDN kernel once a GDN layer,
+    the GQA kernel as it stands over a pool stored merged (at head_dim 256
+    the 5-D pool's view would be a copy of both pools every call), three
+    grouped products a layer, and K, V, S and the conv ring updated in
+    place: nothing as large as any of them, nor as one of their layers."""
+    _, cache, programs = qwen
+    compiled = programs["decode"]
     hlo = compiled.as_text()
     assert len(re.findall(r"%gdn_decode(\.\d+)? = \(", hlo)) == 3
     assert len(re.findall(r"%closed_call\.\d+ = \S+ custom-call\(", hlo)) == 1
@@ -541,8 +558,75 @@ def test_qwen3_next_decode_chunk_updates_pools_and_state_in_place(
     assert mem.temp_size_in_bytes < 2 ** 30
 
 
+def weight_copies(hlo, sizes):
+    """Operations of an optimized HLO text, outside any fusion's body, that
+    compute or copy a bf16 result (or tuple element) of one of the element
+    counts ``sizes``: fusions, copies, slices, transposes. The moves of an
+    operand into the chip's fast memory for its product (``copy-start``,
+    ``slice-start``, their ``-done``, ``ConcatBitcast``) are how a product
+    reads its weight, and are not listed. -> ["name opcode bf16[dims]"]"""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", hlo))
+    comp, found = None, []
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            comp = head.group(2)
+            continue
+        op = re.match(r"^  (?:ROOT )?%(\S+) = (.*?) ([a-z\-]+)\(", line)
+        if comp in fused or not op or op.group(3) not in (
+                "fusion", "copy", "dynamic-slice", "slice", "transpose"):
+            continue
+        for dims in re.findall(r"bf16\[([\d,]+)\]", op.group(2)):
+            if math.prod(int(d) for d in dims.split(",")) in sizes:
+                found.append(f"{op.group(1)} {op.group(3)} bf16[{dims}]")
+    return found
 
-def test_gdn_prefill_metric_reads_the_chunk_scan(v5e, monkeypatch):
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_qwen3_next_projections_reach_their_products_as_stored(qwen,
+                                                               program):
+    """The projection matrices go to their products in the buffer and the
+    layout they are stored in: no layer, period or stack of ``w_qkvz`` (its
+    product is permuted, not reshaped into heads, so the stack is not
+    transposed once a call), no period or stack of ``w_out``, and no layer
+    or stack of the full layers' ``w_q`` is computed or copied (each
+    layer's matrix is ONE dynamic index into its whole stack, fused into
+    the product). The programs as they were held eight such operations
+    each: the stacks of ``w_qkvz`` and ``w_q`` transposed once a call, a
+    period's three ``w_qkvz`` and ``w_out`` sliced out, layers of ``w_qkvz``
+    sliced out of that, a layer of ``w_q`` sliced out; and 597.6 MB of
+    temporaries in the decode program, 722.5 MB in the chunk program."""
+    cfg, _, programs = qwen
+    compiled = programs[program]
+    layer = {"w_qkvz": cfg.dim * (2 * cfg.key_dim + 2 * cfg.value_dim),
+             "w_out": cfg.value_dim * cfg.dim,
+             "w_q": cfg.dim * 2 * cfg.n_heads * cfg.head_dim}
+    n_gdn = cfg.n_layers // cfg.full_attention_interval * 3
+    sizes = ({layer["w_qkvz"] * n for n in (1, 3, n_gdn)}
+             | {layer["w_out"] * n for n in (3, n_gdn)}
+             | {layer["w_q"] * n for n in (1, 2)})
+    assert weight_copies(compiled.as_text(), sizes) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+
+
+def test_weight_copies_finds_what_the_parent_did():
+    """So that the check cannot pass by seeing nothing: on the lines of the
+    parent's decode program it finds the stack of ``w_qkvz`` transposed in
+    ENTRY, the period's slice and the two layers sliced out of that, and
+    not the move of a layer into fast memory nor what is inside a
+    fusion."""
+    found = weight_copies(PARENT_QWEN_HLO,
+                          {2048 * 12288 * n for n in (1, 3, 6)})
+    assert found == [
+        "dynamic-slice_bitcast_fusion.47 fusion bf16[3,2048,12288]",
+        "fusion.918 fusion bf16[1,2048,12288]",
+        "fusion.918 fusion bf16[1,2048,12288]",
+        "copy.538 copy bf16[6,2048,12288]"]
+    assert weight_copies(PARENT_QWEN_HLO, {2048 * 12288 * 2}) == []
+
+
+
+def test_gdn_prefill_metric_reads_the_chunk_scan(qwen):
     """``gdn_prefill.time_share_pct.longgen`` matches operations of the chunk
     program by their result type (the reduced trace keeps an operation's
     name, opcode and type, not the name scope: ``benchmarks/lib/xplane.
@@ -555,8 +639,6 @@ def test_gdn_prefill_metric_reads_the_chunk_scan(v5e, monkeypatch):
     import os
     import sys
 
-    from kubeflow_tpu.models import qwen3_next
-
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
                                     "benchmarks"))
     from lib import xplane
@@ -565,27 +647,7 @@ def test_gdn_prefill_metric_reads_the_chunk_scan(v5e, monkeypatch):
                            "metrics", "gdn_prefill.time_share_pct.longgen"
                            ".json")) as f:
         matched = re.compile(json.load(f)["args"]["op"])
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    q = QWEN
-    cfg = qwen3_next.Qwen3NextConfig(n_layers=q["layers"], vocab_size=18992,
-                                     n_experts_held=64,
-                                     max_seq=q["nbp"] * BS)
-    one = NamedSharding(Mesh(v5e[:1], ("x",)), P())
-    on_chip = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-        x.shape, x.dtype, sharding=one), tree)
-    params = on_chip(jax.eval_shape(lambda: qwen3_next.init_params(
-        jax.random.key(0), cfg, dtype=jnp.bfloat16)))
-    cache = on_chip(jax.eval_shape(lambda: paged_kv.init_paged_cache(
-        cfg, q["b"], q["nbp"] * BS, BS, q["nb"])))
-    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
-
-    def chunk(params, tokens, cache, tables, slot, offset, length):
-        return paged_kv.paged_prefill_chunk(params, tokens, cfg, cache,
-                                            tables, slot, offset, length)
-
-    hlo = jax.jit(chunk, donate_argnums=(2,)).lower(
-        params, sds((1, 512)), cache, sds((q["b"], q["nbp"])), sds(()),
-        sds(()), sds(())).compile().as_text()
+    hlo = qwen[2]["chunk"].as_text()
     fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
     free = ("parameter", "constant", "get-tuple-element", "tuple", "bitcast")
     comp, ops = None, []          # what runs: no fusion's inside
@@ -692,5 +754,28 @@ PARENT_HLO = """\
   %copy.65 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} copy(%get-tuple-element.1017)
   %get-tuple-element.1019 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} get-tuple-element(%while.34), index=3
   %copy.67 = bf16[4,640,64,8,128]{4,3,2,1,0:T(8,128)(2,1)} copy(%get-tuple-element.1019)
+}
+"""
+
+
+# the lines of the parent's Qwen3-Next decode program (PR 38) that carry
+# w_qkvz, shortened
+PARENT_QWEN_HLO = """\
+%fused_computation.52 (param_0.1: bf16[2,3,2048,12288], param_1.2: s32[]) -> bf16[3,2048,12288] {
+  %param_0.1 = bf16[2,3,2048,12288]{2,3,1,0:T(8,128)(2,1)} parameter(0)
+  %dynamic-slice.9 = bf16[1,3,2048,12288]{2,3,1,0:T(8,128)(2,1)} dynamic-slice(%param_0.1, %param_1.2, %constant.1, %constant.1, %constant.1), dynamic_slice_sizes={1,3,2048,12288}
+  ROOT %bitcast.7 = bf16[3,2048,12288]{1,2,0:T(8,128)(2,1)} bitcast(%dynamic-slice.9)
+}
+%wide.region_1.143 (arg: (s32[], bf16[2,3,2048,12288])) -> (s32[], bf16[2,3,2048,12288]) {
+  %get-tuple-element.5794 = bf16[2,3,2048,12288]{2,3,1,0:T(8,128)(2,1)} get-tuple-element(%arg), index=1
+  %dynamic-slice_bitcast_fusion.47 = bf16[3,2048,12288]{1,2,0:T(8,128)(2,1)} fusion(%get-tuple-element.5794, %get-tuple-element.5692), kind=kLoop, calls=%fused_computation.52
+  %fusion.918 = (bf16[1,2048,12288]{1,2,0:T(8,128)(2,1)}, bf16[1,2048,12288]{1,2,0:T(8,128)(2,1)S(1)}) fusion(%dynamic-slice_bitcast_fusion.47), kind=kLoop, calls=%fused_computation.53
+  %copy-start.4 = (bf16[1,2048,12288]{1,2,0:T(8,128)(2,1)S(1)}, bf16[1,2048,12288]{1,2,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%get-tuple-element.5217)
+  %copy-done.4 = bf16[1,2048,12288]{1,2,0:T(8,128)(2,1)S(1)} copy-done(%copy-start.4)
+}
+ENTRY %main.151 (params__linear____w_qkvz__.1: bf16[6,2048,12288]) -> bf16[256] {
+  %params__linear____w_qkvz__.1 = bf16[6,2048,12288]{2,1,0:T(8,128)(2,1)} parameter(15)
+  %copy.538 = bf16[6,2048,12288]{1,2,0:T(8,128)(2,1)} copy(%params__linear____w_qkvz__.1)
+  %bitcast.39 = bf16[2,3,2048,12288]{2,3,1,0:T(8,128)(2,1)} bitcast(%copy.538)
 }
 """

@@ -91,6 +91,80 @@ def test_bfloat16_fails_the_float32_tolerance(params):
     assert np.abs(got - np.asarray(ref["logits"])).max() > 100 * LOGIT_TOL
 
 
+def _published_split(qkvz, qg, cfg):
+    """The published model's split of the two products: reshaped into
+    heads, split, and (u) put together again."""
+    bsz, s, _ = qkvz.shape
+    r = cfg.n_v_heads // cfg.n_k_heads
+    dk, dv, hd = cfg.k_head_dim, cfg.v_head_dim, cfg.head_dim
+    q, k, v, z = jnp.split(qkvz.reshape(bsz, s, cfg.n_k_heads, -1),
+                           [dk, 2 * dk, 2 * dk + r * dv], axis=-1)
+    u = jnp.concatenate([t.reshape(bsz, s, -1) for t in (q, k, v)], -1)
+    qg = qg.reshape(bsz, s, cfg.n_heads, 2 * hd)
+    return (u, z.reshape(bsz, s, cfg.n_v_heads, dv), qg[..., :hd],
+            qg[..., hd:])
+
+
+@pytest.mark.parametrize("widths", [
+    {}, {"n_v_heads": 2},
+    # the published GDN and attention widths (value heads twice key heads)
+    {"dim": 2048, "n_k_heads": 16, "n_v_heads": 32, "k_head_dim": 128,
+     "v_head_dim": 128, "n_heads": 16}])
+def test_the_permuted_split_is_the_published_split(widths):
+    """``gdn_inputs`` and ``attn_inputs`` take ``u``, ``z``, the query and
+    the gate from the flat products by a constant permutation of columns:
+    the same bfloat16 values as the published reshape-and-split, bit for
+    bit."""
+    cfg = q3.qwen3_next_tiny(dtype=jnp.bfloat16, n_layers=4, **widths)
+    p = q3.init_params(jax.random.key(5), cfg, dtype=jnp.bfloat16)
+    lp = {**jax.tree.map(lambda a: a[0], p["linear"]),
+          **jax.tree.map(lambda a: a[0], p["full"])}
+    x = jax.random.normal(jax.random.key(6), (2, 3, cfg.dim)).astype(
+        jnp.bfloat16)
+    h = q3.zc_norm(x, lp["in_norm"], cfg.norm_eps)
+    want_u, want_z, want_q, want_gate = _published_split(
+        q3._proj(h, lp["w_qkvz"], cfg.dtype), q3._proj(h, lp["w_q"],
+                                                      cfg.dtype), cfg)
+    u, z, _, _ = q3.gdn_inputs(lp, x, cfg)
+    assert u.dtype == z.dtype == jnp.bfloat16
+    assert np.array_equal(np.asarray(u), np.asarray(want_u))
+    assert np.array_equal(np.asarray(z), np.asarray(want_z))
+    positions = jnp.arange(3)[None]
+    q, gate, _, _ = q3.attn_inputs(lp, x, positions, cfg)
+    want_q = q3._rope(q3.zc_norm(want_q, lp["q_norm"], cfg.norm_eps),
+                      positions, cfg)
+    assert np.array_equal(np.asarray(q), np.asarray(want_q))
+    assert np.array_equal(np.asarray(gate), np.asarray(want_gate))
+
+
+@pytest.mark.parametrize("period,j", [(p, j) for p in range(2)
+                                      for j in range(4)])
+def test_a_layer_is_handed_its_published_weights(params, period, j):
+    """What the paged programs hand layer ``j`` of period ``period``
+    (``layer_stacks``, scanned as ``_scan_layers`` does, then
+    ``period_layer``): published layer ``period * 4 + j``'s weights, the
+    projections by one index into their whole stack, the experts' stacks
+    whole with the layer's index among all."""
+    ops = CFG.paged_ops()
+    [(stack, whole)] = ops.layer_stacks(params)
+    lp = dict(jax.tree.map(lambda a: a[period], {
+        key: val for key, val in stack.items() if key not in whole}),
+        **{key: stack[key] for key in whole}, stack_index=period)
+    got = ops.period_layer(lp, j)
+    layer = period * CFG.full_attention_interval + j
+    tree, index = (("linear", period * 3 + j) if ops.period[j] == "recurrent"
+                   else ("full", period))
+    want = {key: val[index] for key, val in params[tree].items()}
+    want.update({key: val[layer] for key, val in params["moe"].items()
+                 if key not in q3.EXPERT_MATRICES})
+    assert set(got) == set(want) | set(q3.EXPERT_MATRICES) | {"layer_index"}
+    for key, val in want.items():
+        assert np.array_equal(np.asarray(got[key]), np.asarray(val)), key
+    for key in q3.EXPERT_MATRICES:
+        assert got[key] is params["moe"][key]
+    assert int(got["layer_index"]) == layer
+
+
 def _token_by_token(q, k, v, g, beta, s):
     """The three lines of the recurrence, one token at a time, numpy."""
     out = []
