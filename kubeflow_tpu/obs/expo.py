@@ -38,6 +38,11 @@ HELP: dict[str, str] = {
         "Inter-token latency per generated token (chunk-amortized)",
     "kft_model_request_e2e_seconds":
         "End-to-end request latency (enqueue -> finish)",
+    # the interpreter's collections (obs/trace.py gc hook), by generation
+    "kft_model_gc_collections_total":
+        "Garbage collections of the serving process, by generation",
+    "kft_model_gc_seconds_total":
+        "Seconds the serving process spent in garbage collection",
     # trial swarm (hpo/swarm.py SwarmTrialRunner + warm-pool reclaim arc)
     "kft_swarm_trials_running_total":
         "HPO trials that entered RUNNING (per experiment)",

@@ -17,8 +17,10 @@ spans the same way.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
+import gc
 import os
 import random
 import threading
@@ -135,6 +137,7 @@ class SpanCollector:
         self._next = 0                 # total closed spans ever appended
         self.dropped = 0
         self._open: dict[str, Span] = {}
+        self._gc_seen = time.time()    # record_gc_pauses: the last t0 taken
 
     # ------------------------------------------------------- lifecycle --
 
@@ -181,6 +184,24 @@ class SpanCollector:
             self._ring[self._next % self.capacity] = span
             self._next += 1
         return span
+
+    def record_gc_pauses(self) -> int:
+        """Append the long collections (``gc_pauses``) that began since
+        this collector last looked, as closed root ``host.gc`` spans
+        (attrs ``generation``, ``collected``): a device idle gap that one
+        covers is then named by it. Returns how many."""
+        if not gc_pauses or gc_pauses[-1][0] <= self._gc_seen:
+            return 0
+        with self._lock:
+            new = [p for p in list(gc_pauses) if p[0] > self._gc_seen]
+            if new:
+                self._gc_seen = new[-1][0]
+        for t0, t1, gen, collected in new:
+            span = self.start("host.gc", attrs={"generation": gen,
+                                                "collected": collected})
+            span.t0, span.t1 = t0, t1
+            self.end(span)
+        return len(new)
 
     @contextlib.contextmanager
     def span(self, name: str, *, parent: Parent = None,
@@ -244,3 +265,49 @@ def collector() -> SpanCollector:
     """The per-process default collector every instrumented surface
     (engine, server, router) records into unless handed its own."""
     return _global
+
+
+# ------------------------------------------------ the interpreter's collector --
+#
+# One ``gc.callbacks`` hook a process times every collection: plain totals
+# per generation, and each LONG pause (every generation-2 collection, and any
+# of ``GC_LONG_S`` or more) as ``(t0, t1, generation, collected)`` on the
+# spans' clock in a bounded buffer, which ``record_gc_pauses`` turns into
+# ``host.gc`` spans. The hook takes no lock: a collection can start inside
+# ``SpanCollector.end`` with the collector's lock held by the same thread.
+
+GC_LONG_S = 1e-3
+
+gc_counts = [0, 0, 0]                 # collections, by generation
+gc_seconds = [0.0, 0.0, 0.0]          # their time.perf_counter seconds
+gc_pauses: collections.deque = collections.deque(maxlen=64)
+_gc_start: Optional[tuple] = None     # (perf_counter, time) of the open one
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    global _gc_start
+    if phase == "start":
+        _gc_start = (time.perf_counter(), time.time())
+        return
+    if _gc_start is None:             # installed during a collection
+        return
+    dt = time.perf_counter() - _gc_start[0]
+    t0, _gc_start = _gc_start[1], None
+    gen = info["generation"]
+    gc_counts[gen] += 1
+    gc_seconds[gen] += dt
+    if gen == 2 or dt >= GC_LONG_S:
+        gc_pauses.append((t0, t0 + dt, gen, info["collected"]))
+
+
+def install_gc_hook() -> None:
+    """Start timing the interpreter's collections (idempotent)."""
+    if _gc_hook not in gc.callbacks:
+        gc.callbacks.append(_gc_hook)
+
+
+def gc_stats() -> dict:
+    """The process totals by generation, for a model's stats:
+    ``{"0": {"collections_total": n, "seconds_total": s}, ...}``."""
+    return {str(g): {"collections_total": gc_counts[g],
+                     "seconds_total": gc_seconds[g]} for g in range(3)}

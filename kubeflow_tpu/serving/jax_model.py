@@ -21,6 +21,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import numpy as np
 
+from kubeflow_tpu.obs import trace as obs_trace
 from kubeflow_tpu.serving.llm import LLMEngine, SamplingParams
 from kubeflow_tpu.serving.model import Model
 from kubeflow_tpu.serving.protocol import InferRequest, InferResponse
@@ -357,6 +358,10 @@ class LLMModel(Model):
             # bench/autoscaler read without parsing exposition text
             "request_histograms": {
                 k: h.snapshot() for k, h in eng.request_hists.items()},
+            # the interpreter's collections (the process's, timed since
+            # the first engine was built), by generation: the server
+            # renders kft_model_gc_{collections,seconds}_total{generation}
+            "gc": obs_trace.gc_stats(),
         }
         if self.tier:
             # tier attribution (disagg): stats consumers and the /metrics

@@ -440,6 +440,8 @@ class LLMEngine:
         # span and, inside it, the one open phase (name, span, annotation)
         self._step_span: Optional[obs_trace.Span] = None
         self._cur_phase: Optional[tuple] = None
+        # the interpreter's collections, timed process-wide (gc_ms, host.gc)
+        obs_trace.install_gc_hook()
         self.request_hists = {"ttft": Histogram(_REQ_LAT_BUCKETS),
                               "itl": Histogram(_REQ_LAT_BUCKETS),
                               "e2e": Histogram(_REQ_LAT_BUCKETS)}
@@ -995,9 +997,12 @@ class LLMEngine:
 
         A step that has anything to do records one ``engine.step`` span
         whose children (``_phase``) tile it: ``step.admit``,
-        ``prefill.wait``, ``step.dispatch``, ``step.wait``,
+        ``admit.paged``, ``admit.launch``, ``prefill.wait``,
+        ``step.dispatch``, ``dispatch.launch``, ``step.wait``,
         ``step.commit``; what no phase covers (the abort sweep, queued
-        control ops) is its self time."""
+        control ops) is its self time. The step's ``gc_ms`` attr is the
+        interpreter's collections inside it; the long pauses since the
+        last look follow as root ``host.gc`` spans."""
         with self._lock:
             waiting = len(self._waiting)
             pending = bool(self._ctl or self._aborted)
@@ -1007,6 +1012,7 @@ class LLMEngine:
         sched = self.sched
         before = (sched.admitted + sched.chunked_started,
                   sched.admission_stalls)
+        gc_s = sum(obs_trace.gc_seconds)
         self._step_span = self.obs.start("engine.step", attrs={
             "waiting": waiting, "active": len(self._active),
             "free_slots": len(self._free)})
@@ -1018,7 +1024,9 @@ class LLMEngine:
             self.obs.end(
                 span,
                 admitted=sched.admitted + sched.chunked_started - before[0],
-                stalled=int(sched.admission_stalls > before[1]))
+                stalled=int(sched.admission_stalls > before[1]),
+                gc_ms=1000.0 * (sum(obs_trace.gc_seconds) - gc_s))
+            self.obs.record_gc_pauses()
 
     def _step(self) -> list[GenRequest]:
         self.sched.note_step()
@@ -1101,15 +1109,6 @@ class LLMEngine:
             temp[slot] = req.sampling.temperature
             top_k[slot] = req.sampling.top_k
             top_p[slot] = req.sampling.top_p
-        if self._inflight is None or self._fresh.all():
-            token_in = jnp.asarray(self._tokens)
-        else:
-            # device carry from the in-flight chunk; fresh host tokens
-            # (admissions since that dispatch) override their slots
-            token_in = self._merge_tok(
-                self._inflight["next"], jnp.asarray(self._tokens),
-                jnp.asarray(self._fresh))
-        self._fresh[:] = False
         tab = self._dispatch_tables()
         chunk_len = self.sched.decode_chunk_len(
             self._min_deterministic_remaining(),
@@ -1122,26 +1121,34 @@ class LLMEngine:
         dspan = self._dispatch_span(
             "decode.step", [r for _, r in self._active.items()],
             chunk_len=chunk_len, batch=len(self._active), **attrs)
-        self._rng, step_rng = jax.random.split(self._rng)
         # static: an all-greedy batch skips the per-step full-vocab
         # sort (two compile variants total)
         greedy_only = not bool((temp > 0).any())
-        if (self._compiled_decode is not None and greedy_only
-                and chunk_len == self.decode_chunk):
-            # the precompile()d executable (depot fast path): same
-            # program as the jitted call below, acquired without a
-            # cold compile on a scale-up replica
-            toks, lps, next_tok, self.cache, stats = self._compiled_decode(
-                self.params, token_in, self.cache, jnp.asarray(tab),
-                jnp.asarray(active_mask), jnp.asarray(temp),
-                jnp.asarray(top_k), jnp.asarray(top_p), step_rng)
-        else:
-            toks, lps, next_tok, self.cache, stats = self._decode(
-                self.params, token_in, self.cache, jnp.asarray(tab),
-                jnp.asarray(active_mask), jnp.asarray(temp),
-                jnp.asarray(top_k), jnp.asarray(top_p), step_rng,
-                greedy_only=greedy_only,
-                kernel=self.kernel, chunk_len=chunk_len)
+        with self._phase("dispatch.launch"):
+            if self._inflight is None or self._fresh.all():
+                token_in = jnp.asarray(self._tokens)
+            else:
+                # device carry from the in-flight chunk; fresh host tokens
+                # (admissions since that dispatch) override their slots
+                token_in = self._merge_tok(
+                    self._inflight["next"], jnp.asarray(self._tokens),
+                    jnp.asarray(self._fresh))
+            self._fresh[:] = False
+            self._rng, step_rng = jax.random.split(self._rng)
+            args = (self.params, token_in, self.cache, jnp.asarray(tab),
+                    jnp.asarray(active_mask), jnp.asarray(temp),
+                    jnp.asarray(top_k), jnp.asarray(top_p), step_rng)
+            if (self._compiled_decode is not None and greedy_only
+                    and chunk_len == self.decode_chunk):
+                # the precompile()d executable (depot fast path): same
+                # program as the jitted call below, acquired without a
+                # cold compile on a scale-up replica
+                toks, lps, next_tok, self.cache, stats = \
+                    self._compiled_decode(*args)
+            else:
+                toks, lps, next_tok, self.cache, stats = self._decode(
+                    *args, greedy_only=greedy_only,
+                    kernel=self.kernel, chunk_len=chunk_len)
         return {
             "toks": toks, "lps": lps, "next": next_tok, "stats": stats,
             "chunk_len": chunk_len, "span": dspan,
@@ -1367,9 +1374,11 @@ class LLMEngine:
             "decode.verify", [r for _, r in self._active.items()],
             width=width, drafted=sum(len(d) for d in drafts.values()),
             batch=len(self._active))
-        toks, lps, self.cache = self._verify(
-            self.params, jnp.asarray(tokens), self.cache,
-            jnp.asarray(self._dispatch_tables()), jnp.asarray(limit))
+        tab = self._dispatch_tables()
+        with self._phase("dispatch.launch"):
+            toks, lps, self.cache = self._verify(
+                self.params, jnp.asarray(tokens), self.cache,
+                jnp.asarray(tab), jnp.asarray(limit))
         return drafts, vspan, toks, lps
 
     def _spec_commit(self, drafts: dict, toks, lps) -> tuple[list, int]:
@@ -1437,9 +1446,10 @@ class LLMEngine:
         W = self._chunk_width
         share_len = n_shared * self.paged.block_size
         start = min((share_len // W) * W, ((L - 1) // W) * W)
+        with self._phase("admit.launch"):
+            tables = jnp.asarray(self.paged.tables)
         self._chunked[slot] = _ChunkedPrefill(
-            req=req, offset=start, share_len=share_len,
-            tables=jnp.asarray(self.paged.tables))
+            req=req, offset=start, share_len=share_len, tables=tables)
         self.sched.note_chunked_started()
 
     def _advance_chunked(self, slot: int) -> int:
@@ -1467,27 +1477,30 @@ class LLMEngine:
         part = req.prompt[st.offset:st.offset + W]
         piece[0, :len(part)] = part
         chunk_fn = self._compiled_prefill_chunk or self._prefill_chunk
-        st.x_last, self.cache, stats = chunk_fn(
-            self.params, jnp.asarray(piece), self.cache, st.tables,
-            jnp.int32(slot), jnp.int32(st.offset), jnp.int32(L),
-            jnp.int32(st.share_len))
-        # the chunks' expert counts stay on the device until the last
-        # chunk's token is read back: no wait of their own
-        experts = stats.pop("experts", None)   # [layers, W, k], not a count
+        with self._phase("admit.launch"):
+            st.x_last, self.cache, stats = chunk_fn(
+                self.params, jnp.asarray(piece), self.cache, st.tables,
+                jnp.int32(slot), jnp.int32(st.offset), jnp.int32(L),
+                jnp.int32(st.share_len))
+            # the chunks' expert counts stay on the device until the last
+            # chunk's token is read back: no wait of their own
+            experts = stats.pop("experts", None)   # [layers, W, k]
+            st.stats = stats if st.stats is None else {
+                key: st.stats[key] + val for key, val in stats.items()}
         if experts is not None and req.sampling.record_routing:
             st.routing.append(experts)
-        st.stats = stats if st.stats is None else {
-            key: st.stats[key] + val for key, val in stats.items()}
         st.offset += W
         self.sched.note_prefill_chunk(W)
         self.obs.end(pspan, final=st.offset >= L)
         # publish completed read-only blocks: every position < offset is
         # written and its write DISPATCHED, so a later sharer's reads are
         # device-ordered behind the content
-        self.paged.publish_prompt_blocks(slot, req.prompt,
-                                         min(st.offset, L))
+        with self._phase("admit.paged"):
+            self.paged.publish_prompt_blocks(slot, req.prompt,
+                                             min(st.offset, L))
         if st.offset >= L:
-            logits = self._chunk_lm_head(self.params, st.x_last)
+            with self._phase("admit.launch"):
+                logits = self._chunk_lm_head(self.params, st.x_last)
             tok, lp = self._sample_rows(logits, [req])
             self._note_expert_stats(st.stats)
             if st.routing:
@@ -1496,8 +1509,9 @@ class LLMEngine:
                     [np.asarray(e) for e in st.routing], axis=1)
                 req.prompt_routing = rows[:, :rows.shape[1] - (st.offset - L)]
                 req.routing.append(req.prompt_routing[:, -1])
-            self.cache = self._set_len(
-                self.cache, jnp.int32(L), jnp.int32(slot))
+            with self._phase("admit.launch"):
+                self.cache = self._set_len(
+                    self.cache, jnp.int32(L), jnp.int32(slot))
             del self._chunked[slot]
             self.sched.note_chunked_admitted()
             self._post_admit(req, slot, int(tok[0]), float(lp[0]))
@@ -1525,7 +1539,8 @@ class LLMEngine:
         private blocks return immediately; blocks it already published
         stay cached (their KV is valid — a pure function of the tokens)."""
         del self._chunked[slot]
-        self.paged.release(slot)
+        with self._phase("admit.paged"):
+            self.paged.release(slot)
         self._free.append(slot)
         self.sched.note_preempt()
 
@@ -1564,10 +1579,11 @@ class LLMEngine:
             # chunk (defer_publish) instead of at reserve time
             chunked = (self._prefill is None
                        or len(req.prompt) > self.buckets[-1])
-            n_shared = self.paged.reserve(
-                slot, len(req.prompt), req.sampling.max_tokens,
-                min_blocks=blocks_for(len(req.prompt), bs),
-                prompt=req.prompt, defer_publish=chunked)
+            with self._phase("admit.paged"):
+                n_shared = self.paged.reserve(
+                    slot, len(req.prompt), req.sampling.max_tokens,
+                    min_blocks=blocks_for(len(req.prompt), bs),
+                    prompt=req.prompt, defer_publish=chunked)
             if n_shared is None:
                 with self._lock:
                     self._waiting.insert(0, req)
@@ -1597,10 +1613,11 @@ class LLMEngine:
                         break
                     self._waiting.pop(0)
                     s2 = self._free.pop()
-                ns2 = self.paged.reserve(
-                    s2, len(nxt.prompt), nxt.sampling.max_tokens,
-                    min_blocks=blocks_for(len(nxt.prompt), bs),
-                    prompt=nxt.prompt)
+                with self._phase("admit.paged"):
+                    ns2 = self.paged.reserve(
+                        s2, len(nxt.prompt), nxt.sampling.max_tokens,
+                        min_blocks=blocks_for(len(nxt.prompt), bs),
+                        prompt=nxt.prompt)
                 if ns2 is None:
                     with self._lock:
                         self._waiting.insert(0, nxt)
@@ -1641,11 +1658,12 @@ class LLMEngine:
         pspan = self._dispatch_span(
             "prefill.batch", [r for r, _, _ in batch],
             bucket=bucket, batch=len(batch))
-        logits, filled = self._prefill(
-            self.params, jnp.asarray(toks), jnp.asarray(lengths))
-        self.cache = self._insert_batch(
-            self.cache, filled["k"], filled["v"], jnp.asarray(blk),
-            jnp.asarray(lengths), jnp.asarray(slots))
+        with self._phase("admit.launch"):
+            logits, filled = self._prefill(
+                self.params, jnp.asarray(toks), jnp.asarray(lengths))
+            self.cache = self._insert_batch(
+                self.cache, filled["k"], filled["v"], jnp.asarray(blk),
+                jnp.asarray(lengths), jnp.asarray(slots))
         tok, lp = self._sample_rows(logits, [r for r, _, _ in batch],
                                     width=width)
         self.obs.end(pspan)
@@ -1662,10 +1680,11 @@ class LLMEngine:
             temp[i] = r.sampling.temperature
             top_k[i] = r.sampling.top_k
             top_p[i] = r.sampling.top_p
-        self._rng, rng = jax.random.split(self._rng)
-        tok, lp = self._first_sample(
-            logits, rng, jnp.asarray(temp), jnp.asarray(top_k),
-            jnp.asarray(top_p))
+        with self._phase("admit.launch"):
+            self._rng, rng = jax.random.split(self._rng)
+            tok, lp = self._first_sample(
+                logits, rng, jnp.asarray(temp), jnp.asarray(top_k),
+                jnp.asarray(top_p))
         # the admission's first host read of a device value: blocks behind
         # the prefill and whatever decode chunk was already in flight
         with self._phase("prefill.wait"):

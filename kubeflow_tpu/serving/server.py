@@ -424,8 +424,8 @@ class ModelServer:
             # tier="prefill"|"decode" on EVERY family it exports (the
             # request histograms included), through the one shared label
             # builder so model= and tier= compose identically everywhere
-            label = obs_expo.format_labels(
-                model=mname, tier=stats.pop("tier", None))
+            tier = stats.pop("tier", None)
+            label = obs_expo.format_labels(model=mname, tier=tier)
             for hname, snap in (stats.pop("request_histograms", None)
                                 or {}).items():
                 hists.setdefault(
@@ -442,6 +442,13 @@ class ModelServer:
                           if fam.endswith(obs_expo.COUNTER_SUFFIXES)
                           else gauges)
                 target.setdefault(fam, []).append((label, float(v)))
+            # the interpreter's collections, one series per generation
+            for gen, fams in (stats.pop("gc", None) or {}).items():
+                glabel = obs_expo.format_labels(
+                    model=mname, tier=tier, generation=gen)
+                for k, v in fams.items():
+                    counters.setdefault(f"kft_model_gc_{k}", []).append(
+                        (glabel, float(v)))
             flat = []
             for k, v in stats.items():
                 if isinstance(v, dict):
