@@ -388,7 +388,9 @@ class LLMEngine:
         self._ctl: list = []
         self._ids = itertools.count()
         self._lock = threading.Lock()
-        self._tokens = np.zeros((max_batch,), np.int32)   # next input token
+        # last committed token, host side (the speculative path drafts and
+        # verifies from it)
+        self._tokens = np.zeros((max_batch,), np.int32)
         self._rng = jax.random.key(0)
         self.steps = 0
         # expert layers only: assignments per (expert layer, expert) since
@@ -410,12 +412,16 @@ class LLMEngine:
         self.decode_chunk = max(1, int(decode_chunk))
         # double-buffered decode: dispatch chunk N+1 BEFORE fetching chunk
         # N's tokens, so device compute overlaps host transfer+bookkeeping.
-        # The next chunk's input token is the DEVICE-side scan carry; host
-        # token writes (fresh admissions) override it through a jitted
-        # merge, so the dispatch never waits on a host read-back.
+        # The decode's input token is a DEVICE array, the token carry: the
+        # last dispatched chunk's scan carry, with each admission's first
+        # token written in at its slot by the sampling program itself, so
+        # neither a dispatch nor an admission waits on a host read-back.
         self.decode_pipeline = bool(decode_pipeline)
         self._inflight: Optional[dict] = None
-        self._fresh = np.ones((max_batch,), bool)   # host token overrides
+        self._carry = jnp.zeros((max_batch,), jnp.int32)
+        # this step's admissions whose first token is still on the device
+        # (_commit_first_tokens reads them back after the decode launch)
+        self._pending: list[dict] = []
         # step scheduler (serving/scheduler.py): per-step prefill token
         # quota, interleaved chunked prefill, adaptive decode-chunk trims,
         # and the counter set /metrics exports
@@ -437,9 +443,13 @@ class LLMEngine:
                     kv_block_size, sizes["pool_bytes"],
                     self.slot_state_bytes, max_batch)
         # the engine thread's timeline (_phase): the open ``engine.step``
-        # span and, inside it, the one open phase (name, span, annotation)
+        # span and, inside it, the one open phase (name, span, annotation);
+        # the last host phase, closed but not yet ended (it runs on to the
+        # next phase), else the instant the last wait ended
         self._step_span: Optional[obs_trace.Span] = None
         self._cur_phase: Optional[tuple] = None
+        self._tail: Optional[obs_trace.Span] = None
+        self._phase_mark = 0.0
         # the interpreter's collections, timed process-wide (gc_ms, host.gc)
         obs_trace.install_gc_hook()
         self.request_hists = {"ttft": Histogram(_REQ_LAT_BUCKETS),
@@ -483,14 +493,17 @@ class LLMEngine:
         # chunk (full-vocab matmul is the expensive part of short chunks)
         self._chunk_lm_head = jax.jit(
             lambda p, x_last: ops.head(p, x_last))
-        # first-token sampling + its logprob in ONE jitted call: computing
-        # log_softmax eagerly per admitted request costs an op-by-op
-        # full-vocab dispatch + transfer
+        # first-token sampling, its logprob and the token carry's update in
+        # ONE jitted call: computing log_softmax eagerly per admitted
+        # request costs an op-by-op full-vocab dispatch + transfer. A pad
+        # row (slot -1) is sent past the carry's end and dropped there.
         self._first_sample = jax.jit(
-            lambda logits, rng, t, k, p: (
+            lambda logits, rng, t, k, p, carry, slots: (
                 (tok := sample_logits(logits, rng, t, k, p)),
                 jnp.take_along_axis(logits, tok[:, None], axis=-1)[:, 0]
-                - jax.nn.logsumexp(logits, axis=-1)))
+                - jax.nn.logsumexp(logits, axis=-1),
+                carry.at[jnp.where(slots < 0, carry.shape[0], slots)].set(
+                    tok, mode="drop")))
         self._decode = jax.jit(
             self._decode_impl, donate_argnums=(2,),
             static_argnames=("greedy_only", "kernel", "chunk_len"))
@@ -530,8 +543,6 @@ class LLMEngine:
         self._set_lens = jax.jit(
             lambda cache, lens: {**cache, "len": lens},
             donate_argnums=(0,))
-        self._merge_tok = jax.jit(
-            lambda carry, upd, mask: jnp.where(mask, upd, carry))
         self._insert_batch = jax.jit(self._insert_batch_impl,
                                      donate_argnums=(0,))
         self._set_len = jax.jit(
@@ -845,7 +856,10 @@ class LLMEngine:
         # tree: a later fully-shared-prefix request can then bypass the
         # prefill tier entirely and admit here at radix-hit cost
         self.paged.publish_prompt_blocks(slot, prompt, L)
-        self._post_admit(req, slot, int(first_token), float(first_lp))
+        self._launch_admitted(req, slot)
+        # the token arrived on the host: the decode's carry takes it here
+        self._carry = self._carry.at[slot].set(int(first_token))
+        self._commit_first(req, slot, int(first_token), float(first_lp))
         return req
 
     # ---------------- observability hooks ----------------
@@ -877,14 +891,35 @@ class LLMEngine:
     def _open_phase(self, name: str, attrs: Optional[dict] = None) -> None:
         ann = jax.profiler.TraceAnnotation(name)
         ann.__enter__()
-        self._cur_phase = (name, self.obs.start(
-            name, parent=self._step_span, attrs=attrs), ann)
+        span = self.obs.start(name, parent=self._step_span, attrs=attrs)
+        span.attrs["gap_us"] = 0.0
+        tail, self._tail = self._tail, None
+        if tail is not None:
+            # the host work between two phases belongs to a host phase:
+            # the last one, if it was one, runs on to this one's start
+            now = span.t0 = time.time()
+            tail.attrs["gap_us"] += 1e6 * (now - tail.t1)
+            tail.t1 = now
+            self.obs.end(tail)
+        elif not name.endswith(".wait"):
+            # ... else this one, back to where the last wait (or the step)
+            # ended; a wait always begins at its own start
+            span.attrs["gap_us"] = 1e6 * (span.t0 - self._phase_mark)
+            span.t0 = self._phase_mark
+        self._cur_phase = (name, span, ann)
 
     def _close_phase(self) -> str:
         name, span, ann = self._cur_phase
         self._cur_phase = None
         ann.__exit__(None, None, None)
-        self.obs.end(span)
+        if name.endswith(".wait"):
+            self.obs.end(span)
+            self._phase_mark = span.t1
+        else:
+            # a host phase ends when the next phase begins (_open_phase)
+            # or the step does
+            span.t1 = time.time()
+            self._tail = span
         return name
 
     @contextlib.contextmanager
@@ -893,11 +928,14 @@ class LLMEngine:
         child span of the open ``engine.step`` plus a profiler annotation
         of the same name. Phases TILE the step — entering one inside
         another closes the outer one and reopens it (a new span of its
-        name) on the way out — so every instant, and every device idle
-        gap, lies in at most one. Rule of the names: in a phase ending in
-        ``.wait`` the host is blocked on the device; every other phase is
-        host work. Yields the span's attrs, for counts only known at the
-        end. Outside ``step()`` there is no timeline: nothing is
+        name) on the way out, and the host's work between two phases (the
+        spans' own bookkeeping, the code between two ``with`` blocks) is
+        charged to the host phase next to it, its ``gap_us`` attr — so
+        every instant, and every device idle gap, lies in one phase. Rule
+        of the names: in a phase ending in ``.wait`` the host is blocked
+        on the device, from its own start to its own end; every other
+        phase is host work. Yields the span's attrs, for counts only known
+        at the end. Outside ``step()`` there is no timeline: nothing is
         recorded."""
         if self._step_span is None:
             yield {}
@@ -996,13 +1034,15 @@ class LLMEngine:
         Returns requests that finished this step.
 
         A step that has anything to do records one ``engine.step`` span
-        whose children (``_phase``) tile it: ``step.admit``,
-        ``admit.paged``, ``admit.launch``, ``prefill.wait``,
-        ``step.dispatch``, ``dispatch.launch``, ``step.wait``,
-        ``step.commit``; what no phase covers (the abort sweep, queued
-        control ops) is its self time. The step's ``gc_ms`` attr is the
-        interpreter's collections inside it; the long pauses since the
-        last look follow as root ``host.gc`` spans."""
+        whose children (``_phase``) tile it from its start to its end:
+        ``step.admit`` (queued control ops, the abort sweep, admission),
+        ``admit.paged``, ``admit.launch``, ``step.dispatch``,
+        ``dispatch.launch``, ``prefill.wait``, ``step.wait``,
+        ``step.commit``. Its ``first_on_device`` attr counts the
+        admissions whose first token went into a decode launch before the
+        host read it; ``gc_ms`` is the interpreter's collections inside
+        the step; the long pauses since the last look follow as root
+        ``host.gc`` spans."""
         with self._lock:
             waiting = len(self._waiting)
             pending = bool(self._ctl or self._aborted)
@@ -1011,62 +1051,51 @@ class LLMEngine:
             return self._step()
         sched = self.sched
         before = (sched.admitted + sched.chunked_started,
-                  sched.admission_stalls)
+                  sched.admission_stalls, sched.first_on_device)
         gc_s = sum(obs_trace.gc_seconds)
         self._step_span = self.obs.start("engine.step", attrs={
             "waiting": waiting, "active": len(self._active),
             "free_slots": len(self._free)})
+        self._phase_mark = self._step_span.t0
         try:
             with jax.profiler.TraceAnnotation("engine.step"):
                 return self._step()
         finally:
             span, self._step_span = self._step_span, None
-            self.obs.end(
-                span,
+            attrs = dict(
                 admitted=sched.admitted + sched.chunked_started - before[0],
                 stalled=int(sched.admission_stalls > before[1]),
+                first_on_device=sched.first_on_device - before[2],
                 gc_ms=1000.0 * (sum(obs_trace.gc_seconds) - gc_s))
+            tail, self._tail = self._tail, None
+            if tail is not None:
+                # the last host phase runs on to the step's end
+                span.t1 = time.time()
+                tail.attrs["gap_us"] += 1e6 * (span.t1 - tail.t1)
+                tail.t1 = span.t1
+                self.obs.end(tail)
+            self.obs.end(span, **attrs)
             self.obs.record_gc_pauses()
 
     def _step(self) -> list[GenRequest]:
         self.sched.note_step()
-        self._drain_ctl()
-        with self._lock:
-            aborted, self._aborted = self._aborted, set()
-        if aborted:
-            for slot, req in list(self._active.items()):
-                if req.id in aborted:
-                    del self._active[slot]
-                    self.paged.release(slot)
-                    self._free.append(slot)
-            # a held prefill whose request aborted mid-migration releases
-            # its side HERE — the prefill half of the "releases on both
-            # sides" contract (the decode half is disagg release/collect)
-            for slot, req in list(self._held.items()):
-                if req.id in aborted:
-                    del self._held[slot]
-                    self.paged.release(slot)
-                    self._free.append(slot)
-            # abort of a request whose chunked prefill is mid-flight is
-            # observed HERE — between chunks — not after the full prompt:
-            # the slot and its private blocks come back immediately (the
-            # blocks it already published stay cached and shareable)
-            for slot, st in list(self._chunked.items()):
-                if st.req.id in aborted:
-                    self._cancel_chunked(slot)
         with self._phase("step.admit"):
+            self._drain_ctl()
+            self._sweep_aborted()
             self._admit()
         finished_pre: list[GenRequest] = []
         if self.spec is not None and self._active:
             if all(r.sampling.temperature == 0
                    for r in self._active.values()):
                 # speculative path: flush any pipelined chunk first (its
-                # tokens are this step's draft context), then one
-                # draft+verify round — synchronous by construction, the
-                # drafter needs the committed tokens back
+                # tokens are this step's draft context) and read back this
+                # step's first tokens, then one draft+verify round —
+                # synchronous by construction, the drafter needs the
+                # committed tokens back
                 if self._inflight is not None:
                     prev, self._inflight = self._inflight, None
                     finished_pre = self._process_chunk(prev)
+                finished_pre += self._commit_first_tokens()
                 if not self._active:
                     return finished_pre
                 spec_finished = self._spec_step()
@@ -1084,58 +1113,98 @@ class LLMEngine:
                 # runs the normal decode path (counted — a quiet
                 # fallback would read as a silent speedup regression)
                 self.sched.note_spec_fallback()
+        if not self.decode_pipeline:
+            # synchronous mode: every token on the host before the launch
+            finished_pre += self._commit_first_tokens()
         new_inflight = None
-        if self._active and self._need_dispatch():
+        if self._active:
             with self._phase("step.dispatch"):
-                new_inflight = self._dispatch_decode()
+                if self._need_dispatch():
+                    new_inflight = self._dispatch_decode()
+        # pipelined: the admissions' first tokens are read back only now,
+        # behind the decode launch that already carries them
+        finished = self._commit_first_tokens(new_inflight)
         prev, self._inflight = self._inflight, new_inflight
-        finished = self._process_chunk(prev) if prev is not None else []
+        if prev is not None:
+            finished += self._process_chunk(prev)
         if not self.decode_pipeline and self._inflight is not None:
             # synchronous mode: flush immediately (no overlap, no lag)
             flush, self._inflight = self._inflight, None
             finished += self._process_chunk(flush)
         return finished_pre + finished
 
+    def _sweep_aborted(self) -> None:
+        """Free the slots of the requests aborted since the last step."""
+        with self._lock:
+            aborted, self._aborted = self._aborted, set()
+        if not aborted:
+            return
+        for slot, req in list(self._active.items()):
+            if req.id in aborted:
+                del self._active[slot]
+                self.paged.release(slot)
+                self._free.append(slot)
+        # a held prefill whose request aborted mid-migration releases
+        # its side HERE — the prefill half of the "releases on both
+        # sides" contract (the decode half is disagg release/collect)
+        for slot, req in list(self._held.items()):
+            if req.id in aborted:
+                del self._held[slot]
+                self.paged.release(slot)
+                self._free.append(slot)
+        # abort of a request whose chunked prefill is mid-flight is
+        # observed HERE — between chunks — not after the full prompt:
+        # the slot and its private blocks come back immediately (the
+        # blocks it already published stay cached and shareable)
+        for slot, st in list(self._chunked.items()):
+            if st.req.id in aborted:
+                self._cancel_chunked(slot)
+
+    def _decoding(self) -> list:
+        """The active (slot, request) pairs a decode chunk runs for: all
+        but this step's admissions whose first token, not read back yet,
+        uses up their budget (``max_tokens`` 1, or a prompt one short of
+        ``max_seq``) — those retire at the read-back."""
+        spent = {id(r) for p in self._pending for r, _ in p["rows"]
+                 if min(r.sampling.max_tokens,
+                        self.max_seq - len(r.prompt)) <= 1}
+        if not spent:
+            return list(self._active.items())
+        return [(slot, req) for slot, req in self._active.items()
+                if id(req) not in spent]
+
     def _dispatch_decode(self) -> dict:
         """Build the dispatch's host arrays and launch one decode chunk
         (asynchronous: returns once the program is enqueued). The
         returned in-flight record is read back by _process_chunk."""
+        rows = self._decoding()
         active_mask = np.zeros((self.max_batch,), bool)
         temp = np.zeros((self.max_batch,), np.float32)
         top_k = np.zeros((self.max_batch,), np.int32)
         top_p = np.ones((self.max_batch,), np.float32)
-        for slot, req in self._active.items():
+        for slot, req in rows:
             active_mask[slot] = True
             temp[slot] = req.sampling.temperature
             top_k[slot] = req.sampling.top_k
             top_p[slot] = req.sampling.top_p
         tab = self._dispatch_tables()
         chunk_len = self.sched.decode_chunk_len(
-            self._min_deterministic_remaining(),
+            self._min_deterministic_remaining(rows),
             pressure=bool(self._waiting))
         self.sched.note_decode_dispatch(chunk_len)
         attrs = {}
         if self.model.state_rows:
             # slots whose recurrent state a step reads and writes
-            attrs["state_slots"] = len(self._active)
+            attrs["state_slots"] = len(rows)
         dspan = self._dispatch_span(
-            "decode.step", [r for _, r in self._active.items()],
-            chunk_len=chunk_len, batch=len(self._active), **attrs)
+            "decode.step", [r for _, r in rows],
+            chunk_len=chunk_len, batch=len(rows), **attrs)
         # static: an all-greedy batch skips the per-step full-vocab
         # sort (two compile variants total)
         greedy_only = not bool((temp > 0).any())
         with self._phase("dispatch.launch"):
-            if self._inflight is None or self._fresh.all():
-                token_in = jnp.asarray(self._tokens)
-            else:
-                # device carry from the in-flight chunk; fresh host tokens
-                # (admissions since that dispatch) override their slots
-                token_in = self._merge_tok(
-                    self._inflight["next"], jnp.asarray(self._tokens),
-                    jnp.asarray(self._fresh))
-            self._fresh[:] = False
             self._rng, step_rng = jax.random.split(self._rng)
-            args = (self.params, token_in, self.cache, jnp.asarray(tab),
+            args = (self.params, self._carry, self.cache, jnp.asarray(tab),
                     jnp.asarray(active_mask), jnp.asarray(temp),
                     jnp.asarray(top_k), jnp.asarray(top_p), step_rng)
             if (self._compiled_decode is not None and greedy_only
@@ -1143,30 +1212,32 @@ class LLMEngine:
                 # the precompile()d executable (depot fast path): same
                 # program as the jitted call below, acquired without a
                 # cold compile on a scale-up replica
-                toks, lps, next_tok, self.cache, stats = \
+                toks, lps, self._carry, self.cache, stats = \
                     self._compiled_decode(*args)
             else:
-                toks, lps, next_tok, self.cache, stats = self._decode(
+                toks, lps, self._carry, self.cache, stats = self._decode(
                     *args, greedy_only=greedy_only,
                     kernel=self.kernel, chunk_len=chunk_len)
         return {
-            "toks": toks, "lps": lps, "next": next_tok, "stats": stats,
+            "toks": toks, "lps": lps, "stats": stats,
             "chunk_len": chunk_len, "span": dspan,
             # snapshot: tokens belong to the requests active at
             # DISPATCH time — a slot may host a new request by the
             # time these arrays are read back
-            "snapshot": list(self._active.items()),
+            "snapshot": rows,
         }
 
     def _need_dispatch(self) -> bool:
         """Skip the next dispatch when the in-flight chunk already covers
         every active request's remaining budget — kills the tail-overshoot
-        chunk for uniform max_tokens batches."""
+        chunk for uniform max_tokens batches. (A request whose first token
+        is not read back yet was admitted after that dispatch.)"""
+        rows = self._decoding()
         if self._inflight is None:
-            return True
+            return bool(rows)
         snapshot_reqs = {id(r) for _, r in self._inflight["snapshot"]}
         chunk = self._inflight["chunk_len"]
-        for _, req in self._active.items():
+        for _, req in rows:
             if id(req) not in snapshot_reqs:
                 return True            # admitted after the dispatch
             if (len(req.generated) + chunk < req.sampling.max_tokens
@@ -1175,10 +1246,11 @@ class LLMEngine:
                 return True            # still needs tokens past the chunk
         return False
 
-    def _min_deterministic_remaining(self) -> Optional[int]:
+    def _min_deterministic_remaining(self, rows: list) -> Optional[int]:
         """Earliest DETERMINISTIC finish (max_tokens / max_seq bound)
-        among active requests, net of tokens the in-flight chunk will
-        already have produced — the boundary the adaptive decode chunk
+        among the requests of a decode dispatch (``rows``), net of tokens
+        the in-flight chunk will already have produced and of a first
+        token not read back yet — the boundary the adaptive decode chunk
         trims to so a freeing slot rejoins mid-chunk, not decode_chunk
         device steps later. EOS finishes are not predictable and don't
         count."""
@@ -1187,10 +1259,12 @@ class LLMEngine:
             if self._inflight is not None else set())
         pending = (self._inflight["chunk_len"]
                    if self._inflight is not None else 0)
+        first = {id(r) for p in self._pending for r, _ in p["rows"]}
         rem = None
-        for _, req in self._active.items():
-            r = min(req.sampling.max_tokens - len(req.generated),
-                    self.max_seq - len(req.prompt) - len(req.generated))
+        for _, req in rows:
+            n = len(req.generated) + (id(req) in first)
+            r = min(req.sampling.max_tokens - n,
+                    self.max_seq - len(req.prompt) - n)
             if id(req) in snapshot_reqs:
                 r -= pending
             r = max(1, r)
@@ -1221,10 +1295,11 @@ class LLMEngine:
         req.t_done = time.time()
         if not req.aborted and req.t_enqueue:
             self.request_hists["e2e"].observe(req.t_done - req.t_enqueue)
-        if self._active.get(slot) is req:
-            del self._active[slot]
-            self.paged.release(slot)
-            self._free.append(slot)
+        for owner in (self._active, self._held):
+            if owner.get(slot) is req:
+                del owner[slot]
+                self.paged.release(slot)
+                self._free.append(slot)
 
     def _dispatch_tables(self):
         """Block tables for a decode/verify dispatch: mid-prefill slots'
@@ -1424,6 +1499,9 @@ class LLMEngine:
                 # committed length only — rejected rows stay beyond it
                 new_len[slot] = len(req.prompt) + len(req.generated) - 1
         self.cache = self._set_lens(self.cache, jnp.asarray(new_len))
+        # the tokens were committed on the host: a plain decode dispatch
+        # after this round takes them from here
+        self._carry = jnp.asarray(self._tokens)
         return finished, committed_total
 
     def generate(self, prompts: Sequence[Sequence[int]],
@@ -1501,20 +1579,19 @@ class LLMEngine:
         if st.offset >= L:
             with self._phase("admit.launch"):
                 logits = self._chunk_lm_head(self.params, st.x_last)
-            tok, lp = self._sample_rows(logits, [req])
-            self._note_expert_stats(st.stats)
-            if st.routing:
-                # chunks of [layers, W, k]; the last one's pad rows cut
-                rows = np.concatenate(
-                    [np.asarray(e) for e in st.routing], axis=1)
-                req.prompt_routing = rows[:, :rows.shape[1] - (st.offset - L)]
-                req.routing.append(req.prompt_routing[:, -1])
+            tok, lp = self._sample_rows(logits, [req], [slot])
             with self._phase("admit.launch"):
                 self.cache = self._set_len(
                     self.cache, jnp.int32(L), jnp.int32(slot))
             del self._chunked[slot]
             self.sched.note_chunked_admitted()
-            self._post_admit(req, slot, int(tok[0]), float(lp[0]))
+            self._launch_admitted(req, slot)
+            # the chunks' expert counts and routing rows are read back
+            # with the token
+            self._pending.append({
+                "tok": tok, "lp": lp, "rows": [(req, slot)], "span": None,
+                "stats": st.stats, "routing": st.routing,
+                "pad_rows": st.offset - L})
         return W
 
     def _chunked_phase(self, interleave: bool, budget: int,
@@ -1665,49 +1742,96 @@ class LLMEngine:
                 self.cache, filled["k"], filled["v"], jnp.asarray(blk),
                 jnp.asarray(lengths), jnp.asarray(slots))
         tok, lp = self._sample_rows(logits, [r for r, _, _ in batch],
-                                    width=width)
-        self.obs.end(pspan)
-        for i, (req, slot, _) in enumerate(batch):
-            self._post_admit(req, slot, int(tok[i]), float(lp[i]))
+                                    slots, width=width)
+        for req, slot, _ in batch:
+            self._launch_admitted(req, slot)
+        # the span ends when the tokens are read back
+        self._pending.append({"tok": tok, "lp": lp, "span": pspan,
+                              "rows": [(r, s) for r, s, _ in batch]})
 
-    def _sample_rows(self, logits, reqs, width: Optional[int] = None):
-        """First-token sampling for admission rows (one jitted call)."""
+    def _sample_rows(self, logits, reqs, slots, width: Optional[int] = None):
+        """First-token sampling for admission rows, one launch: returns
+        the tokens and their logprobs as device arrays, and writes each
+        token into the decode's token carry at its slot (``slots``; -1 for
+        a pad row), so the next decode launch takes it without a read."""
         width = width or len(reqs)
         temp = np.zeros((width,), np.float32)
         top_k = np.zeros((width,), np.int32)
         top_p = np.ones((width,), np.float32)
+        rows = np.full((width,), -1, np.int32)
+        rows[:len(slots)] = slots
         for i, r in enumerate(reqs):
             temp[i] = r.sampling.temperature
             top_k[i] = r.sampling.top_k
             top_p[i] = r.sampling.top_p
         with self._phase("admit.launch"):
             self._rng, rng = jax.random.split(self._rng)
-            tok, lp = self._first_sample(
+            tok, lp, self._carry = self._first_sample(
                 logits, rng, jnp.asarray(temp), jnp.asarray(top_k),
-                jnp.asarray(top_p))
-        # the admission's first host read of a device value: blocks behind
-        # the prefill and whatever decode chunk was already in flight
-        with self._phase("prefill.wait"):
-            return np.asarray(tok), np.asarray(lp)
+                jnp.asarray(top_p), self._carry, jnp.asarray(rows))
+        return tok, lp
 
-    def _post_admit(self, req, slot: int, first_tok: int,
-                    first_lp: float) -> None:
-        """Per-request bookkeeping after its KV is resident: the
-        prefill-sampled token is generation token #1; decode continues
-        from it (or the request finishes instantly on eos/budget —
-        the same _commit_token stop semantics as every other path)."""
+    def _launch_admitted(self, req, slot: int) -> None:
+        """The part of admission that needs no token: the request takes
+        its slot in the decode batch, or, on the disaggregated prefill tier
+        (``hold_after_prefill``), parks there for export_held_kv instead of
+        decoding — its slot stays allocated and its blocks refcount-pinned
+        (PREFILL_OWNED in the handoff state machine) until release_held
+        transfers or drops ownership."""
         req.slot = slot
-        self._fresh[slot] = True       # override any device token carry
-        self._active[slot] = req
-        done = self._commit_token(req, slot, first_tok, first_lp)
+        if req.hold_after_prefill:
+            self._held[slot] = req
+        else:
+            self._active[slot] = req
+
+    def _commit_first(self, req, slot: int, tok: int, lp: float) -> bool:
+        """Commit generation token #1, the prefill-sampled one: the
+        request finishes here on eos/budget (the same _commit_token stop
+        semantics as every other path). Returns whether it did."""
+        done = self._commit_token(req, slot, tok, lp)
         self._note_request_latency(req, 1)       # TTFT closes here
         if done:
             self._retire(req, slot)
-        elif req.hold_after_prefill:
-            # disagg prefill tier: the prefill is complete and token #1
-            # sampled — park the request for export_held_kv instead of
-            # decoding. The slot stays allocated and its blocks stay
-            # refcount-pinned (PREFILL_OWNED in the handoff state machine)
-            # until release_held transfers or drops ownership.
-            del self._active[slot]
-            self._held[slot] = req
+        return done
+
+    def _commit_first_tokens(self, launched: Optional[dict] = None
+                             ) -> list[GenRequest]:
+        """Read back this step's admissions' first tokens (with a chunked
+        prompt's expert counts and routing rows) and commit them. Returns
+        the requests that ended on their first token. ``launched``: the
+        decode dispatch of this step, made before the read; the admissions
+        it carries are counted as ``first_on_device``."""
+        pending, self._pending = self._pending, []
+        if not pending:
+            return []
+        with self._phase("prefill.wait"):
+            got = [(np.asarray(p["tok"]), np.asarray(p["lp"]))
+                   for p in pending]
+            for p in pending:
+                if p.get("stats"):
+                    self._note_expert_stats(p["stats"])
+                if p.get("routing"):
+                    # chunks of [layers, W, k]; the last one's pad rows cut
+                    rows = np.concatenate(
+                        [np.asarray(e) for e in p["routing"]], axis=1)
+                    req = p["rows"][0][0]
+                    req.prompt_routing = \
+                        rows[:, :rows.shape[1] - p["pad_rows"]]
+                    req.routing.append(req.prompt_routing[:, -1])
+        finished = []
+        with self._phase("step.admit"):
+            if launched is not None:
+                carried = {id(r) for _, r in launched["snapshot"]}
+                self.sched.note_first_on_device(sum(
+                    id(req) in carried
+                    for p in pending for req, _ in p["rows"]))
+            for p, (tok, lp) in zip(pending, got):
+                for i, (req, slot) in enumerate(p["rows"]):
+                    if req.done:
+                        continue       # aborted since its admission
+                    if self._commit_first(req, slot, int(tok[i]),
+                                          float(lp[i])):
+                        finished.append(req)
+                if p["span"] is not None:
+                    self.obs.end(p["span"])
+        return finished

@@ -155,6 +155,9 @@ class StepScheduler:
         self.preempts = 0                  # chunked prefills cancelled mid-flight
         self.admission_stalls = 0          # reservation failed under pressure
         self.short_chunks = 0              # adaptive trims under pressure
+        # admissions whose first token went into a decode launch before
+        # the host read it (0 on the speculative and synchronous paths)
+        self.first_on_device = 0
         # speculative decoding (spec_decode=True dispatches)
         self.spec_dispatches = 0           # verify steps dispatched
         self.spec_slot_rounds = 0          # (dispatch, live stream) pairs
@@ -216,6 +219,9 @@ class StepScheduler:
     def note_stall(self) -> None:
         self.admission_stalls += 1
 
+    def note_first_on_device(self, n: int) -> None:
+        self.first_on_device += int(n)
+
     def note_spec_dispatch(self, drafted: int) -> None:
         self.spec_dispatches += 1
         self.spec_draft_tokens += int(drafted)
@@ -256,6 +262,7 @@ class StepScheduler:
             "preempts_total": self.preempts,
             "admission_stalls_total": self.admission_stalls,
             "short_chunks_total": self.short_chunks,
+            "first_on_device_total": self.first_on_device,
             "occupancy_slots": active,
             "occupancy_ratio": round(occ, 4),
             "queue_depth": waiting,

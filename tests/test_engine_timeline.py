@@ -1,15 +1,17 @@
 """The engine thread's timeline: every ``LLMEngine.step()`` that does
 anything records one ``engine.step`` span tiled by phase spans
-(``step.admit`` / ``admit.paged`` / ``admit.launch`` / ``prefill.wait`` /
-``step.dispatch`` / ``dispatch.launch`` / ``step.wait`` / ``step.commit``)
-and carrying the interpreter's collections in ``gc_ms``, long collections
-follow as ``host.gc`` spans, the spans and attrs that were there before are
-unchanged, and the benchmark's readers of the timeline
+(``step.admit`` / ``admit.paged`` / ``admit.launch`` / ``step.dispatch`` /
+``dispatch.launch`` / ``prefill.wait`` / ``step.wait`` / ``step.commit``)
+and carrying the interpreter's collections in ``gc_ms`` and the admissions
+whose first token rode a decode launch in ``first_on_device``, long
+collections follow as ``host.gc`` spans, the spans and attrs that were there
+before are unchanged, and the benchmark's readers of the timeline
 (``benchmarks/readers/quiet.py``, ``engine_host.py``, ``engine_phase.py``)
 give hand-computed values and cut at the profiler's start."""
 
 import gc
 import os
+import statistics
 import sys
 import threading
 import time
@@ -142,6 +144,7 @@ def test_phases_tile_every_engine_step(tiny, scenario):
     for s in spans:
         by_parent.setdefault(s["parent_id"], []).append(s)
     covered = whole = 0.0
+    gaps = {}                      # phase name -> its spans' gap_us
     for st in steps:
         kids = sorted(by_parent.get(st["span_id"], []),
                       key=lambda s: s["t0"])
@@ -152,9 +155,11 @@ def test_phases_tile_every_engine_step(tiny, scenario):
         for a, b in zip(kids, kids[1:]):
             assert a["t1"] <= b["t0"], (a, b)    # no instant in two phases
         assert {"waiting", "active", "free_slots", "admitted",
-                "stalled", "gc_ms"} <= set(st["attrs"])
+                "stalled", "first_on_device", "gc_ms"} <= set(st["attrs"])
         covered += sum(k["t1"] - k["t0"] for k in kids)
         whole += st["t1"] - st["t0"]
+        for k in kids:
+            gaps.setdefault(k["name"], []).append(k["attrs"]["gap_us"])
 
         def inside(t):
             return st["t0"] <= t <= st["t1"]
@@ -172,13 +177,26 @@ def test_phases_tile_every_engine_step(tiny, scenario):
             assert waits and all(k["attrs"]["device_steps"] >= 1
                                  for k in waits)
             assert "step.commit" in names
-    # what no phase covers (the abort sweep, queued control ops) is small
+    # the phases cover the step: the host's work between two phases is
+    # charged to the host phase beside it ...
     assert covered >= 0.999 * whole, (scenario, covered / whole)
+    # ... and is only the phases' own bookkeeping, tens of microseconds:
+    # the median a phase of each name was stretched over stays far under
+    # a millisecond (a median, so that a scheduler slice that lands in
+    # one transition on a loaded host does not decide it); a wait is
+    # never stretched, it runs from its own start to its own end
+    for name, us in gaps.items():
+        assert min(us) >= 0.0, (scenario, name, us)
+        if name.endswith(".wait"):
+            assert max(us) == 0.0, (scenario, name, us)
+        assert statistics.median(us) < 500.0, (scenario, name, us)
     # the phases are the only children: every phase span names a step
     ids = {st["span_id"] for st in steps}
     assert all(s["parent_id"] in ids for s in spans if s["name"] in PHASES)
     # the counts at the boundaries add up to what the requests got: one
-    # token at admission (inside step.admit), the rest in step.commit
+    # token at admission (read back in prefill.wait and committed in the
+    # step.admit that follows it, after the step's decode launch), the
+    # rest in step.commit
     committed = sum(s["attrs"]["tokens_committed"] for s in spans
                     if s["name"] == "step.commit")
     admitted = sum(st["attrs"]["admitted"] for st in steps)
@@ -285,29 +303,122 @@ def test_admission_phases_only_on_admitting_steps(tiny, scenario):
 
 
 def test_launch_phase_ends_where_the_wait_begins(tiny):
-    """On an admitting step the first-token sample's launch closes at the
-    instant prefill.wait opens; a decode dispatch's launch lies inside
+    """On an admitting step the read of the first tokens waits behind the
+    decode launch: prefill.wait opens where the reopened step.dispatch
+    closes, after dispatch.launch (or after the admission's launch on a
+    step with no decode dispatch); a decode dispatch's launch lies inside
     step.dispatch's time, after its host-side preparation."""
     params, cfg = tiny
     col = trace.SpanCollector(capacity=4096)
     _pipelined(params, cfg, col)
     spans = col.snapshot()
-    launches = {}
-    for s in spans:
-        if s["name"] == "admit.launch":
-            launches.setdefault(s["parent_id"], []).append(s)
     waits = [s for s in spans if s["name"] == "prefill.wait"]
     assert waits
-    for w in waits:
-        before = [s for s in launches.get(w["parent_id"], [])
-                  if s["t1"] <= w["t0"]]
-        assert before, w
-        # only the reopened step.admit, closed at once, lies between
-        assert w["t0"] - max(s["t1"] for s in before) < 0.005
+    dispatched = 0
     for st, names in _children(spans):
+        kids = sorted((s for s in spans if s["parent_id"] == st["span_id"]),
+                      key=lambda s: s["t0"])
+        for i, k in enumerate(kids):
+            if k["name"] != "prefill.wait":
+                continue
+            assert "admit.launch" in names[:i], names
+            if "dispatch.launch" in names:
+                dispatched += 1
+                j = names.index("dispatch.launch")
+                assert j < i and names[j + 1:i] == ["step.dispatch"], names
+                assert kids[j]["t1"] <= k["t0"]
+                assert k["t0"] == kids[i - 1]["t1"]     # tiled, no gap
         if "dispatch.launch" in names:
             i = names.index("dispatch.launch")
             assert names[i - 1] == "step.dispatch", names
+    assert dispatched
+
+
+def _events(eng, monkeypatch):
+    """Record, in order, the engine's first-token sample launches
+    (``admit``), its decode launches (``decode``) and every host read of a
+    device array through ``np.asarray`` (``read``), each with the step."""
+    import numpy as np
+
+    log = []
+    real = np.asarray
+
+    def asarray(a, *args, **kw):
+        if isinstance(a, jax.Array):
+            log.append(("read", eng.sched.steps))
+        return real(a, *args, **kw)
+
+    def wrap(name, tag):
+        fn = getattr(eng, name)
+
+        def call(*args, **kw):
+            log.append((tag, eng.sched.steps))
+            return fn(*args, **kw)
+        monkeypatch.setattr(eng, name, call)
+
+    monkeypatch.setattr(np, "asarray", asarray)
+    wrap("_first_sample", "admit")
+    wrap("_decode", "decode")
+    return log
+
+
+def test_no_host_read_between_admission_and_decode_launch(tiny,
+                                                           monkeypatch):
+    """Pipelined: between a step's first admission launch and its decode
+    launch nothing is read back from the device, and the step's
+    prefill.wait begins after its dispatch.launch ends."""
+    params, cfg = tiny
+    col = trace.SpanCollector(capacity=4096)
+    eng = LLMEngine(params, cfg, max_batch=2, max_seq=64,
+                    prefill_buckets=(16,), obs=col)
+    log = _events(eng, monkeypatch)
+    live = eng.add_request([1, 2, 3], SamplingParams(max_tokens=24))
+    eng.step()
+    eng.step()                     # a chunk of ``live`` is in flight
+    late = eng.add_request([4, 5, 6, 7], SamplingParams(max_tokens=6))
+    long = eng.add_request([(5 * i) % 250 + 1 for i in range(40)],
+                           SamplingParams(max_tokens=5))
+    _drain(eng)
+    assert live.done and late.done and long.done
+    checked = 0
+    for step in sorted({n for tag, n in log if tag == "admit"}):
+        seq = [tag for tag, n in log if n == step]
+        first = seq.index("admit")
+        if "decode" not in seq[first:]:
+            continue
+        launch = first + seq[first:].index("decode")
+        assert "read" not in seq[first:launch], seq
+        checked += 1
+    assert checked >= 2            # ``late``'s bucket and ``long``'s chunk
+    spans = col.snapshot()
+    for st, names in _children(spans):
+        if "prefill.wait" in names and "dispatch.launch" in names:
+            kids = sorted((s for s in spans
+                           if s["parent_id"] == st["span_id"]),
+                          key=lambda s: s["t0"])
+            launch = kids[names.index("dispatch.launch")]
+            wait = kids[names.index("prefill.wait")]
+            assert launch["t1"] <= wait["t0"]
+
+
+@pytest.mark.parametrize("scenario", ["pipelined", "chunked_prefill",
+                                      "synchronous", "speculative"])
+def test_first_on_device_counts_admissions_a_launch_carried(tiny, scenario):
+    """``first_on_device`` on the steps, and the scheduler's counter of
+    the same name, equal the admissions on the pipelined path and read 0
+    on the synchronous and speculative paths, which read every first
+    token before they go on."""
+    params, cfg = tiny
+    col = trace.SpanCollector(capacity=4096)
+    eng, reqs = SCENARIOS[scenario](params, cfg, col)
+    steps = [s for s in col.snapshot() if s["name"] == "engine.step"]
+    carried = sum(st["attrs"]["first_on_device"] for st in steps)
+    assert carried == eng.sched.first_on_device
+    assert eng.scheduler_stats()["first_on_device_total"] == carried
+    admitted = eng.sched.admitted + eng.sched.chunked_admitted
+    assert admitted == len(reqs)
+    assert carried == (admitted if scenario in ("pipelined",
+                                                "chunked_prefill") else 0)
 
 
 def test_gc_collect_as_a_control_op_is_timed_on_its_step(tiny):

@@ -638,9 +638,12 @@ def test_burst_admission_batches_prefill(tiny):
 
 def test_pipelined_decode_matches_synchronous(tiny):
     """Double-buffered decode (dispatch chunk N+1 before reading chunk N)
-    must be invisible to outputs: greedy streams identical to synchronous
-    mode, including slot reuse across retire/admit churn and a request
-    joining mid-flight (the device-carry + fresh-token merge path)."""
+    must be invisible to outputs: greedy streams and their logprobs
+    identical to synchronous mode, including slot reuse across
+    retire/admit churn and a request joining mid-flight (its first token
+    written into the device token carry and read back after the decode
+    launch that takes it: every admission, pipelined; none,
+    synchronous)."""
     cfg, params = tiny
     outs = {}
     for pipeline in (False, True):
@@ -657,8 +660,9 @@ def test_pipelined_decode_matches_synchronous(tiny):
         late = eng.add_request([40, 41, 42], SamplingParams(max_tokens=6))
         while eng.has_work():
             eng.step()
-        outs[pipeline] = [r.generated for r in reqs + [late]]
+        outs[pipeline] = [(r.generated, r.logprobs) for r in reqs + [late]]
         assert all(r.done for r in reqs + [late])
+        assert eng.sched.first_on_device == (5 if pipeline else 0)
         for r in reqs + [late]:
             assert_greedy_consistent(params, cfg, r.prompt, r.generated)
     # bf16 ties could in principle differ across batch layouts, but the
@@ -793,3 +797,181 @@ def test_sampled_decode_variant_compiles_and_runs(tiny):
     # top_k=1 keeps only the argmax: identical to greedy token-for-token
     for r in reqs:
         assert_greedy_consistent(params, cfg, r.prompt, r.generated)
+
+
+# ------------------------------------------- the first token on the device --
+
+
+def _first_token_case(tiny, case):
+    """(cfg, params, engine kwargs, [(prompt, max_tokens)], late request,
+    whether the model routes experts) for one pipelined-vs-synchronous
+    comparison."""
+    from kubeflow_tpu.models import cca_moe
+    from kubeflow_tpu.models import qwen3_next as q3
+
+    if case == "chunked":               # three chunks of 16 beside decode
+        cfg, params = tiny
+        kw = dict(prefill_buckets=(16,), decode_chunk=3)
+        reqs = [([3 + i, 4 + i], 5 + (i % 3)) for i in range(4)]
+        late = ([(7 * i) % 250 + 1 for i in range(40)], 6)
+        return cfg, params, kw, reqs, late, False
+    if case == "qwen3_next":
+        cfg = q3.qwen3_next_tiny(dtype=jnp.float32)
+        params = q3.init_params(jax.random.key(3), cfg)
+    else:
+        cfg = cca_moe.cca_moe_tiny(dtype=jnp.float32)
+        params = cca_moe.init_params(jax.random.key(3), cfg)
+    rng = np.random.default_rng(5)
+    reqs = [(rng.integers(1, cfg.vocab_size, n).tolist(), m)
+            for n, m in ((5, 6), (20, 4), (3, 7))]
+    late = (rng.integers(1, cfg.vocab_size, 17).tolist(), 5)
+    kw = dict(prefill_buckets=(16,), kv_block_size=8, decode_chunk=4)
+    return cfg, params, kw, reqs, late, True
+
+
+@pytest.mark.parametrize("case", ["chunked", "qwen3_next", "cca_moe"])
+def test_first_token_on_device_matches_synchronous(tiny, case):
+    """Pipelined, an admission's first token goes to the decode launch on
+    the device and is read back after it; synchronous, it is read before.
+    Greedy tokens, logprobs and routed experts are the same either way
+    (the dense model's case: test_pipelined_decode_matches_synchronous):
+    slots retiring and re-admitting while chunks are in flight, a prompt
+    streamed through chunked prefill, Qwen3-Next's recurrent state rows
+    (its chunks' expert counts read back with the token) and ZAYA1's slot
+    rows. One engine serves both passes, so its programs compile once."""
+    cfg, params, kw, prompts, late, moe_model = _first_token_case(tiny, case)
+    eng = LLMEngine(params, cfg, max_batch=2, max_seq=64, **kw)
+    outs = {}
+    for pipeline in (False, True):
+        eng.decode_pipeline = pipeline
+        carried = eng.sched.first_on_device
+        admitted = eng.sched.admitted + eng.sched.chunked_admitted
+        sp = dict(record_routing=True) if moe_model else {}
+        reqs = [eng.add_request(p, SamplingParams(max_tokens=m, **sp))
+                for p, m in prompts]
+        for _ in range(2):
+            eng.step()
+        reqs.append(eng.add_request(late[0], SamplingParams(
+            max_tokens=late[1], **sp)))
+        while eng.has_work():
+            eng.step()
+        assert all(r.done and len(r.generated) == m
+                   for r, (_, m) in zip(reqs, prompts + [late]))
+        outs[pipeline] = [(r.generated, r.logprobs,
+                           [np.asarray(x).tolist() for x in r.routing],
+                           None if r.prompt_routing is None
+                           else np.asarray(r.prompt_routing).tolist())
+                          for r in reqs]
+        admitted = eng.sched.admitted + eng.sched.chunked_admitted - admitted
+        assert eng.sched.first_on_device - carried == \
+            (admitted if pipeline else 0)
+        if moe_model:
+            assert eng.moe_tokens_per_expert.sum() > 0
+            assert all(len(r.routing) == len(r.generated) for r in reqs)
+    assert outs[True] == outs[False]
+
+
+def test_request_ending_on_its_first_token_frees_its_slot(tiny):
+    """A request that ends on its first token retires at the read-back
+    with exactly that token — ``max_tokens=1`` with no row in the decode
+    chunk launched beside it and no trim of that chunk, its eos after one
+    trimmed row — and its slot is taken again by the next request, which
+    decodes from its own first token."""
+    cfg, params = tiny
+    eng = LLMEngine(params, cfg, max_batch=2, max_seq=64,
+                    prefill_buckets=(8,), decode_chunk=3)
+    free0 = eng.paged.reclaimable_blocks
+    live = eng.add_request([5, 6, 7], SamplingParams(max_tokens=12))
+    eng.step()
+    eng.step()                          # a chunk of ``live`` in flight
+    p1, p2, p3 = [9, 10], [11, 12, 13], [14, 15]
+    first2 = ref_greedy(params, cfg, p2, 1)[0]
+    one = eng.add_request(p1, SamplingParams(max_tokens=1))
+    eos = eng.add_request(p2, SamplingParams(max_tokens=8, eos_id=first2))
+    after = eng.add_request(p3, SamplingParams(max_tokens=3))
+    short = eng.sched.short_chunks
+    eng.step()
+    assert one.done and one.generated == ref_greedy(params, cfg, p1, 1)
+    assert one.finish_reason == "length" and len(one.logprobs) == 1
+    # under queue pressure, yet ``one`` (its budget spent by its first
+    # token) neither trims ``live``'s chunk nor takes a row of it
+    assert eng.sched.short_chunks == short
+    assert [r for _, r in eng._inflight["snapshot"]] == [live]
+    while eng.has_work():
+        eng.step()
+    assert eos.generated == [first2] and eos.finish_reason == "stop"
+    assert after.done and len(after.generated) == 3
+    assert one.slot == eos.slot == after.slot != live.slot
+    for r in (live, after):
+        assert_greedy_consistent(params, cfg, r.prompt, r.generated)
+    assert eng.sched.first_on_device == 3       # all but ``one``
+    assert sorted(eng._free) == [0, 1] and not eng._active
+    assert eng.paged.reclaimable_blocks == free0
+
+
+def test_abort_between_admission_and_read_back(tiny):
+    """An abort that lands after the admission's launch and before its
+    first token is read back: no token is committed, no span is left
+    open, and the slot comes back for the next request."""
+    from kubeflow_tpu.obs.trace import SpanCollector
+
+    cfg, params = tiny
+    col = SpanCollector(capacity=1024)
+    eng = LLMEngine(params, cfg, max_batch=2, max_seq=64,
+                    prefill_buckets=(8,), obs=col)
+    free0 = eng.paged.reclaimable_blocks
+    keep = eng.add_request([5, 6, 7], SamplingParams(max_tokens=12))
+    eng.step()
+    drop = eng.add_request([9, 10], SamplingParams(max_tokens=12))
+    read_back = eng._commit_first_tokens
+
+    def abort_first(*args, **kw):
+        if eng._pending and not drop.aborted:
+            assert drop.slot is not None and not drop.generated
+            eng.abort([drop])          # another thread, between the two
+        return read_back(*args, **kw)
+
+    eng._commit_first_tokens = abort_first
+    eng.step()
+    assert drop.aborted and drop.generated == []
+    while eng.has_work():
+        eng.step()
+    assert keep.done and len(keep.generated) == 12
+    assert drop.finish_reason == "abort" and drop.generated == []
+    assert col.open_count == 0
+    assert sorted(eng._free) == [0, 1] and not eng._active
+    assert eng.paged.reclaimable_blocks == free0
+    again = eng.add_request([9, 10], SamplingParams(max_tokens=4))
+    while eng.has_work():
+        eng.step()
+    assert again.generated == ref_greedy(params, cfg, [9, 10], 4)
+
+
+def test_held_request_parks_with_its_first_token(tiny):
+    """``hold_after_prefill``: the request parks with token #1 committed
+    and never joins a decode batch; one that ends on that token is not
+    held and frees its slot."""
+    cfg, params = tiny
+    eng = LLMEngine(params, cfg, max_batch=3, max_seq=64,
+                    prefill_buckets=(8,))
+    live = eng.add_request([5, 6, 7], SamplingParams(max_tokens=6))
+    eng.step()                          # ``live`` decoding
+    held = eng.add_request([9, 10, 11], SamplingParams(max_tokens=8),
+                           hold_after_prefill=True)
+    short = eng.add_request([12, 13], SamplingParams(max_tokens=1),
+                            hold_after_prefill=True)
+    eng.step()
+    assert held in eng.held_requests() and not held.done
+    assert held.generated == ref_greedy(params, cfg, [9, 10, 11], 1)
+    assert held.t_first_token > 0 and len(held.logprobs) == 1
+    assert held not in eng._active.values()
+    assert short.done and short not in eng.held_requests()
+    assert short.generated == ref_greedy(params, cfg, [12, 13], 1)
+    while not live.done:
+        eng.step()
+    assert len(held.generated) == 1
+    assert_greedy_consistent(params, cfg, live.prompt, live.generated)
+    assert eng.sched.first_on_device == 1     # ``live`` alone rode a launch
+    assert eng.export_held_kv(held)["first_token"] == held.generated[0]
+    assert eng.release_held(held)
+    assert sorted(eng._free) == [0, 1, 2]
